@@ -36,7 +36,7 @@ from repro.core.chaos import ChaosSpec
 from repro.streams import nexmark
 from repro.streams.chaos_sweep import sweep_configs
 from repro.streams.engine import FailoverConfig
-from repro.streams.jax_engine import (_Lowered, _build_run, _enable_x64,
+from repro.streams.jax_engine import (_Lowered, _build_run,
                                       build_unrolled_run)
 
 FAILOVER = FailoverConfig(mode="region", region_restart_s=20.0)
@@ -73,9 +73,9 @@ def _measure(run_fn, arrays, state, xs) -> dict:
     (`hlo_op_counts`) — so each lowering's record carries *what XLA
     actually emitted*, not just how long it took."""
     from repro.launch.hlo_stats import cost_stats, hlo_op_counts
-    from repro.launch.roofline import kernel_roofline
+    from repro.launch.roofline import V5E, kernel_roofline
 
-    with _enable_x64():
+    with jax.enable_x64(True):
         t0 = time.perf_counter()
         jaxpr = jax.make_jaxpr(run_fn)(arrays, state, xs)
         trace_s = time.perf_counter() - t0
@@ -83,7 +83,7 @@ def _measure(run_fn, arrays, state, xs) -> dict:
         compiled = jax.jit(run_fn).lower(arrays, state, xs).compile()
         compile_s = time.perf_counter() - t0
         cost = cost_stats(compiled)
-        roof = kernel_roofline(cost["flops"], cost["bytes_accessed"])
+        roof = kernel_roofline(cost["flops"], cost["bytes_accessed"], V5E)
         ops = hlo_op_counts(compiled.as_text())
         del compiled
     top_ops = dict(sorted(ops.items(), key=lambda kv: -kv[1])[:12])

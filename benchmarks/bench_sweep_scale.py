@@ -11,8 +11,8 @@ Three studies:
   host-replay baseline (per-(config, seed) `build_chaos_timeline`)
   timed on the same grid; records the `timeline_build_count` delta,
   which MUST be zero on the batched path.
-* **shard** — the same config grid on 1 vs N forced host devices
-  (subprocess — the parent jax process is pinned to one device).
+* **shard** — the same config grid on 1 vs all N local devices, in
+  this process (skipped where fewer than 2 devices exist).
 
 Emits CSV rows through benchmarks/run.py and writes
 ``results/bench_sweep_scale.json`` plus the cross-PR aggregate
@@ -22,12 +22,10 @@ never overwrites the tracked JSONs.
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
 import time
 
+import jax
 import numpy as np
 
 try:
@@ -39,8 +37,7 @@ from repro.core.chaos import ChaosSpec, timeline_build_count
 from repro.streams import nexmark
 from repro.streams.chaos_sweep import sweep_configs
 from repro.streams.engine import CheckpointConfig, FailoverConfig
-from repro.streams.jax_engine import (_Lowered, _enable_x64,
-                                      get_cached_run_fns)
+from repro.streams.jax_engine import _Lowered, get_cached_run_fns
 
 SPEC = ChaosSpec(host_kill_prob_per_s=0.004, straggler_frac=0.2)
 FAILOVER = FailoverConfig(mode="region", region_restart_s=20.0)
@@ -58,7 +55,7 @@ def tick_study(arena, label: str, n_ticks: int = 64,
                        phase_mode=mode)
         rec["n_phases"] = low.tensor.n_phases
         run_fn, _ = get_cached_run_fns(low.desc)
-        with _enable_x64():
+        with jax.enable_x64(True):
             state, xs, _ = low.prepare(SPEC, n_ticks)
             t0 = time.perf_counter()
             out = run_fn(low.arrays, state, xs)
@@ -130,7 +127,7 @@ def ckpt_grid_study(n_restarts: int, n_intervals: int, n_seeds: int,
         rows = []
         for cfg in grid:
             codes, det, rst_s, rst_r = per_task_failover(
-                cfg["failover"], low.plan.n_tasks, low.job_of_task)
+                cfg["failover"], low.plan.n_tasks, low.job_of_task)[:4]
             ck = cfg["ckpt"]
             rows.append(dict(failover_mode=codes, detect_s=det,
                              region_restart_s=rst_r,
@@ -168,56 +165,33 @@ def ckpt_grid_study(n_restarts: int, n_intervals: int, n_seeds: int,
     return rec
 
 
-_SHARD_CODE = """
-import json
-import numpy as np
-from repro.core.chaos import ChaosSpec
-from repro.streams import nexmark
-from repro.streams.chaos_sweep import sweep_configs
-from repro.streams.engine import CheckpointConfig, FailoverConfig
-
-grid = [{{"failover": FailoverConfig(mode="region",
-                                     region_restart_s=float(r)),
-          "ckpt": CheckpointConfig(interval_s=30.0, mode="region")}}
-        for r in np.linspace(10.0, 60.0, {nc})]
-spec = ChaosSpec(host_kill_prob_per_s=0.002, straggler_frac=0.2,
-                 storage_slow_prob=0.2, storage_slow_factor=12)
-arena = nexmark.q12_arena(n_tasks={nt}, parallelism=8, n_hosts=32)
-kw = dict(base_spec=spec, duration_s={dur}, n_hosts=32)
-res = sweep_configs(arena, grid, range({ns}), devices={dev}, **kw)  # warm
-res = sweep_configs(arena, grid, range({ns}), devices={dev}, **kw)
-print(json.dumps({{"devices": {dev} or 1, "wall_s": round(res.wall_s, 2),
-                   "scenarios_per_s": round(res.scenarios_per_s, 1)}}))
-"""
-
-
 def shard_study(n_configs: int, n_seeds: int, duration: float,
-                n_tasks: int, n_devices: int = 2) -> dict:
-    """1-vs-N-device sharded (C, S) grid over a packed arena
-    (subprocess: host devices must be forced before jax initializes;
-    N defaults to 2 — pick <= physical cores, host CPU devices share
-    the machine)."""
-    rec = {"C": n_configs, "S": n_seeds, "n_tasks": n_tasks}
-    root = pathlib.Path(__file__).resolve().parent.parent
-    for dev in (1, n_devices):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = str(root / "src")
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                            f"{n_devices}")
-        code = _SHARD_CODE.format(nc=n_configs, nt=n_tasks, ns=n_seeds,
-                                  dur=duration,
-                                  dev=(dev if dev > 1 else None))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=900)
-        if out.returncode != 0:
-            rec[f"devices_{dev}"] = {"error": out.stderr[-500:]}
-            continue
-        rec[f"devices_{dev}"] = json.loads(out.stdout.strip()
-                                           .splitlines()[-1])
-    one = rec.get("devices_1", {})
-    n = rec.get(f"devices_{n_devices}", {})
-    if "wall_s" in one and "wall_s" in n:
-        rec["shard_speedup"] = round(one["wall_s"] / n["wall_s"], 2)
+                n_tasks: int) -> dict | None:
+    """1-vs-N-device sharded (C, S) grid over a packed arena, in this
+    process over all N local devices (`devices=` splits the seed axis
+    through `jax.shard_map`). None where fewer than 2 devices exist; a
+    failing sweep raises and fails the run."""
+    n_dev = jax.local_device_count()
+    if n_dev < 2:
+        return None
+    grid = [{"failover": FailoverConfig(mode="region",
+                                        region_restart_s=float(r)),
+             "ckpt": CheckpointConfig(interval_s=30.0, mode="region")}
+            for r in np.linspace(10.0, 60.0, n_configs)]
+    spec = ChaosSpec(host_kill_prob_per_s=0.002, straggler_frac=0.2,
+                     storage_slow_prob=0.2, storage_slow_factor=12)
+    arena = nexmark.q12_arena(n_tasks=n_tasks, parallelism=8, n_hosts=32)
+    kw = dict(base_spec=spec, duration_s=duration, n_hosts=32)
+    rec = {"C": n_configs, "S": n_seeds, "n_tasks": n_tasks,
+           "platform": jax.devices()[0].platform}
+    for dev in (None, n_dev):
+        sweep_configs(arena, grid, range(n_seeds), devices=dev, **kw)
+        res = sweep_configs(arena, grid, range(n_seeds), devices=dev, **kw)
+        rec[f"devices_{dev or 1}"] = {
+            "wall_s": round(res.wall_s, 2),
+            "scenarios_per_s": round(res.scenarios_per_s, 1)}
+    rec["shard_speedup"] = round(rec["devices_1"]["wall_s"]
+                                 / rec[f"devices_{n_dev}"]["wall_s"], 2)
     return rec
 
 
@@ -302,9 +276,11 @@ def run():
     shard_rec = None
     if not quick:
         shard_rec = shard_study(4, 64, 120.0, 1008)
-        if "shard_speedup" in shard_rec:
-            yield ("config_shard_2dev", shard_rec["devices_2"]["wall_s"]
-                   * 1e6, f"speedup={shard_rec['shard_speedup']}x")
+        if shard_rec is not None:
+            n_dev = jax.local_device_count()
+            yield (f"config_shard_{n_dev}dev",
+                   shard_rec[f"devices_{n_dev}"]["wall_s"] * 1e6,
+                   f"speedup={shard_rec['shard_speedup']}x")
         RESULTS.mkdir(exist_ok=True)
         payload = {"tick": ticks, "ckpt_grid": grid_rec,
                    "shard": shard_rec,
@@ -314,9 +290,8 @@ def run():
                             "draw stream per seed, per-config refits), "
                             "baseline = per-(config,seed) "
                             "build_chaos_timeline host replays; shard: "
-                            "forced host CPU devices share the "
-                            "machine's cores, so gains cap at the "
-                            "physical core count")}
+                            "1 vs all local devices in one process "
+                            "(null with fewer than 2 devices)")}
         (RESULTS / "bench_sweep_scale.json").write_text(
             json.dumps(payload, indent=2))
         write_summary()

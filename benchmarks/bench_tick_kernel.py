@@ -42,7 +42,7 @@ except ImportError:      # standalone: sys.path[0] is benchmarks/
 from repro.core.chaos import ChaosSpec
 from repro.streams import nexmark
 from repro.streams.engine import FailoverConfig
-from repro.streams.jax_engine import (_Lowered, _enable_x64, run_batch,
+from repro.streams.jax_engine import (_Lowered, run_batch,
                                       run_config_batch)
 
 SPEC = ChaosSpec(host_kill_prob_per_s=0.004, straggler_frac=0.2)
@@ -73,7 +73,7 @@ def kernel_study(n_tasks: int, n_seeds: int, reps: int = 3) -> dict:
                    failover=FAILOVER, ckpt=None, seed=0,
                    phase_mode="pallas")
     fi, ph = max(enumerate(low.tensor.phases), key=lambda p: p[1].D)
-    with _enable_x64():
+    with jax.enable_x64(True):
         tb = pack_phase_tables(low.arrays["edges"][fi],
                                low.arrays["qcap"],
                                low.arrays["mode_single"])

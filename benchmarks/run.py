@@ -62,6 +62,9 @@ QUICK_MODULES = [
 def main() -> None:
     import importlib
 
+    from repro.core.hotupdate import enable_persistent_cache
+
+    enable_persistent_cache()
     quick = "--quick" in sys.argv[1:]
     if quick:
         os.environ["REPRO_BENCH_QUICK"] = "1"

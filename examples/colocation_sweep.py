@@ -9,7 +9,7 @@ co-located job's recovery, and the sweep reports per-job breakdowns.
 
 ``--devices N`` (> 1) forces N host devices (must be set before jax
 initializes, which this script handles) and splits the seed batch across
-them via the version-gated `repro.dist.sharding` shim.
+them via `jax.shard_map` (`repro.dist.sharding`).
 """
 import argparse
 import os

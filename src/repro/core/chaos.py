@@ -332,15 +332,15 @@ def failover_recovery_entries(t: float, mode: str, hit: np.ndarray,
         return [{"t": t, "mode": mode, "tasks": int(hit.sum()),
                  "downtime": d}]
 
-    def _dt(j):
-        if dt_arr.ndim == 0:
-            return float(dt_arr)
-        return float(dt_arr[hit & (job_of_task == j)][0])
-
-    return [{"t": t, "mode": mode,
-             "tasks": int((hit & (job_of_task == j)).sum()),
-             "downtime": _dt(j), "job": int(j)}
-            for j in np.unique(job_of_task[hit])]
+    # one grouping pass over the hit tasks (in task order): each job's
+    # entry counts its hit tasks and reports its first hit task's downtime
+    jobs, first, counts = np.unique(job_of_task[hit], return_index=True,
+                                    return_counts=True)
+    d_hit = dt_arr[hit] if dt_arr.ndim else None
+    return [{"t": t, "mode": mode, "tasks": int(c),
+             "downtime": float(dt_arr) if d_hit is None else float(d_hit[i]),
+             "job": int(j)}
+            for j, i, c in zip(jobs, first, counts)]
 
 
 _MODE_CODE = {"none": 0, "region": 1, "single_task": 2, "hot_standby": 3}

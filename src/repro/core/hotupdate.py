@@ -15,15 +15,35 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import os
+import pathlib
 import time
 from typing import Any, Callable
 
 import jax
 
+#: the persistent compile cache's home when `JAX_COMPILATION_CACHE_DIR`
+#: is unset: a fixed, git-ignored directory of the checkout (the path is
+#: part of the cache key, so it must not move between runs)
+REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def enable_persistent_cache(path: str = "/tmp/repro-xla-cache") -> None:
-    jax.config.update("jax_compilation_cache_dir", path)
+
+def enable_persistent_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache for an accelerator
+    backend and return its directory. Where `JAX_COMPILATION_CACHE_DIR`
+    is set, JAX already reads it and no other path is set here;
+    otherwise the cache lives in `REPO_CACHE_DIR`. Every compile is
+    cached, however short. On the CPU backend (tests, rehearsals) it
+    does nothing and returns None: CPU compiles are cheap, and XLA:CPU
+    executables read back from the cache log host-feature mismatches."""
+    if jax.default_backend() == "cpu":
+        return None
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def _fingerprint(*parts: Any) -> str:

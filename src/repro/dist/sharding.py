@@ -29,7 +29,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # Logical axis name → tuple of mesh axis names (applied left to right).
 # "embed" over the data axis = FSDP; tensor-parallel dims over "model";
@@ -146,21 +147,8 @@ NO_SHARDING = ShardingCtx(mesh=None)
 
 
 # ----------------------------------------------------------------------
-# Seed-batch device sharding (chaos sweeps) — version-gated shim
+# Seed-batch device sharding (chaos sweeps)
 # ----------------------------------------------------------------------
-def jax_version() -> tuple[int, int]:
-    major, minor = jax.__version__.split(".")[:2]
-    return (int(major), int(minor))
-
-
-def shard_map_available() -> bool:
-    """True when the top-level `jax.shard_map` API exists (jax >= 0.6).
-    The container ships 0.4.x, where `pmap` is the sharding vehicle; the
-    gate keeps one call site working across both toolchains (ROADMAP's
-    version-gated `repro/dist` shim item)."""
-    return jax_version() >= (0, 6) and hasattr(jax, "shard_map")
-
-
 def local_shard_count(requested: int | str | None) -> int:
     """Resolve a device-shard request against the local device count.
     ``None`` → 1 (no sharding), ``"auto"`` → all local devices, an int is
@@ -173,53 +161,29 @@ def local_shard_count(requested: int | str | None) -> int:
     return max(1, min(int(requested), n_local))
 
 
+def _seed_mesh(n_shards: int) -> Mesh:
+    return Mesh(np.array(jax.local_devices()[:n_shards]), ("seeds",))
+
+
 def sharded_seed_fn(run, *, xs_axes, n_shards: int, donate_state=True):
     """Device-sharded twin of ``jit(vmap(run))`` over a seed batch.
 
     ``run(pa, state, xs)`` is the per-seed scan; the returned callable
     takes a FLAT seed batch (leading axis ``S``, a multiple of
-    ``n_shards``) and splits it across local devices. ``pa`` is
-    replicated; ``state`` leaves and the seed-indexed ``xs`` leaves (axis
-    0 in `xs_axes`) carry the seed axis. The per-seed scan is
-    embarrassingly parallel, so the split maps straight onto local
-    devices: `pmap` on jax 0.4.x (shard axis folded out / back in around
-    the call), `jax.shard_map` on >= 0.6. The state argument is donated —
-    each call's arena state buffers are consumed in place instead of
-    being copied."""
-    donate = (1,) if donate_state else ()
-    if shard_map_available():  # pragma: no cover - requires jax >= 0.6
-        import numpy as np
-        from jax.sharding import Mesh
-
-        inner = jax.vmap(run, in_axes=(None, 0, xs_axes))
-        mesh = Mesh(np.array(jax.local_devices()[:n_shards]), ("seeds",))
-        seeded = lambda a: P("seeds") if a == 0 else P()  # noqa: E731
-        fn = jax.shard_map(
-            inner, mesh=mesh,
-            in_specs=(P(), P("seeds"),
-                      {k: seeded(a) for k, a in xs_axes.items()}),
-            out_specs=P("seeds"))
-        return jax.jit(fn, donate_argnums=donate)
-
+    ``n_shards``) and splits it across local devices with
+    `jax.shard_map`. ``pa`` is replicated; ``state`` leaves and the
+    seed-indexed ``xs`` leaves (axis 0 in `xs_axes`) carry the seed axis.
+    The per-seed scan is embarrassingly parallel, so the split needs no
+    collective. The state argument is donated — each call's arena state
+    buffers are consumed in place instead of being copied."""
     inner = jax.vmap(run, in_axes=(None, 0, xs_axes))
-    shard_axes = {k: (0 if a == 0 else None) for k, a in xs_axes.items()}
-    pfn = jax.pmap(inner, in_axes=(None, 0, shard_axes),
-                   donate_argnums=donate)
-
-    def call(pa, state, xs):
-        def split(x):
-            x = jnp.asarray(x)
-            return x.reshape((n_shards, x.shape[0] // n_shards)
-                             + x.shape[1:])
-
-        state_s = jax.tree.map(split, state)
-        xs_s = {k: (split(v) if shard_axes[k] == 0 else v)
-                for k, v in xs.items()}
-        out = pfn(pa, state_s, xs_s)
-        return jax.tree.map(
-            lambda x: x.reshape((-1,) + x.shape[2:]), out)
-
-    return call
+    seeded = lambda a: P("seeds") if a == 0 else P()  # noqa: E731
+    fn = jax.shard_map(
+        inner, mesh=_seed_mesh(n_shards),
+        in_specs=(P(), P("seeds"),
+                  {k: seeded(a) for k, a in xs_axes.items()}),
+        out_specs=P("seeds"))
+    return jax.jit(fn, donate_argnums=(1,) if donate_state else ())
 
 
 def sharded_grid_fn(run, *, pa_axes, xs_axes, cfg_xs_axes, seed_axes,
@@ -230,61 +194,28 @@ def sharded_grid_fn(run, *, pa_axes, xs_axes, cfg_xs_axes, seed_axes,
     ``run(pa, state, xs)`` is the per-seed scan. The inner function
     vmaps seeds (``xs_axes``) then configs (``pa_axes`` over the traced
     resiliency leaves, ``cfg_xs_axes`` over the per-config xs leaves);
-    the outer layer splits the flat seed axis — ``state`` leaves on axis
+    `jax.shard_map` splits the flat seed axis — ``state`` leaves on axis
     0, each xs leaf on ``seed_axes[k]`` (None = replicated: the tick
     times, and the per-config ckpt schedules which carry no seed axis)
     — across local devices. Each (config, seed) chain is embarrassingly
     parallel, so outputs merge back to ``(C, S, ...)`` bit-for-bit with
-    the single-device grid. `pmap` on jax 0.4.x, `jax.shard_map` on
-    >= 0.6. State is NOT donated: grid outputs carry an extra config
-    axis, so the per-shard input buffers are never reusable."""
+    the single-device grid. State is NOT donated: grid outputs carry an
+    extra config axis, so the per-shard input buffers are never
+    reusable."""
     inner = jax.vmap(jax.vmap(run, in_axes=(None, 0, xs_axes)),
                      in_axes=(pa_axes, None, cfg_xs_axes))
-    seed_axis = dict(seed_axes)
 
-    if shard_map_available():  # pragma: no cover - requires jax >= 0.6
-        import numpy as np
-        from jax.sharding import Mesh
+    def spec_of(ax):
+        if ax is None:
+            return P()
+        return P(*((None,) * ax + ("seeds",)))
 
-        mesh = Mesh(np.array(jax.local_devices()[:n_shards]), ("seeds",))
-
-        def spec_of(ax):
-            if ax is None:
-                return P()
-            return P(*((None,) * ax + ("seeds",)))
-
-        fn = jax.shard_map(
-            inner, mesh=mesh,
-            in_specs=(P(), P("seeds"),
-                      {k: spec_of(a) for k, a in seed_axis.items()}),
-            out_specs=P(None, "seeds"))
-        return jax.jit(fn)
-
-    pfn = jax.pmap(inner,
-                   in_axes=(None, 0, {k: (None if a is None else 0)
-                                      for k, a in seed_axis.items()}))
-
-    def call(pa, state, xs):
-        def split(x, axis):
-            x = jnp.asarray(x)
-            shp = x.shape
-            x = x.reshape(shp[:axis]
-                          + (n_shards, shp[axis] // n_shards)
-                          + shp[axis + 1:])
-            return jnp.moveaxis(x, axis, 0)
-
-        state_s = jax.tree.map(lambda v: split(v, 0), state)
-        xs_s = {k: (v if seed_axis[k] is None
-                    else split(v, seed_axis[k]))
-                for k, v in xs.items()}
-        out = pfn(pa, state_s, xs_s)
-        # (shard, C, S_local, ...) -> (C, shard*S_local, ...)
-        return jax.tree.map(
-            lambda x: jnp.moveaxis(x, 0, 1).reshape(
-                (x.shape[1], x.shape[0] * x.shape[2]) + x.shape[3:]),
-            out)
-
-    return call
+    fn = jax.shard_map(
+        inner, mesh=_seed_mesh(n_shards),
+        in_specs=(P(), P("seeds"),
+                  {k: spec_of(a) for k, a in seed_axes.items()}),
+        out_specs=P(None, "seeds"))
+    return jax.jit(fn)
 
 
 def batch_axes_for(mesh, batch: int) -> tuple[str, ...]:

@@ -140,12 +140,8 @@ def _fwd(q, k, v, *, causal, window, scale, block_q, block_k, interpret):
 
 
 def pltpu_scratch(shape, dtype):
-    from jax.experimental import pallas as pl  # noqa
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.VMEM(shape, dtype)
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.VMEM(shape, dtype)
 
 
 # ----------------------------------------------------------------------

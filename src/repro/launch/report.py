@@ -35,7 +35,7 @@ def dryrun_table(dirpath="results/dryrun") -> str:
 
         s_ok = st(single)
         if s_ok != "✓":  # extrapolated cells still count via anchors
-            a = roofline.analyse(recs, arch, shape_name)
+            a = roofline.analyse(recs, arch, shape_name, roofline.V5E)
             if a and a.get("status") == "ok":
                 s_ok = "✓ (l8 extrapolation)"
         if s_ok.startswith("✓"):
@@ -62,7 +62,7 @@ def splice(md_path="EXPERIMENTS.md"):
     p = pathlib.Path(md_path)
     text = p.read_text()
     dr = dryrun_table()
-    rf = roofline.table()
+    rf = roofline.table(roofline.V5E)
     text = _replace_block(text, "DRYRUN-TABLE", dr)
     text = _replace_block(text, "ROOFLINE-TABLE", rf)
     p.write_text(text)

@@ -1,11 +1,11 @@
 """Roofline analysis over the dry-run records (§Roofline deliverable).
 
 Per (arch × shape) on the single-pod mesh, three terms in seconds-per-step
-per chip (TPU v5e constants):
+per chip, from the peaks of the chip the caller names (`CHIP_PEAKS`):
 
-  compute    = HLO_FLOPs / 197e12        (bf16 peak per chip)
-  memory     = HLO_bytes / 819e9         (HBM bandwidth)
-  collective = effective ICI bytes / 50e9 (per-link bandwidth)
+  compute    = HLO_FLOPs / bf16 peak
+  memory     = HLO_bytes / HBM bandwidth
+  collective = effective ICI bytes / per-link ICI bandwidth
 
 plus MODEL_FLOPS (6·N·D dense / 6·N_active·D MoE + attention term), the
 useful-compute ratio MODEL_FLOPS/HLO_FLOPs, the dominant term, and the
@@ -26,9 +26,19 @@ import pathlib
 from repro.configs import SHAPES, registry
 from repro.configs.base import Family, ModelConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s
-ICI_BW = 50e9            # bytes/s/link
+#: Published per-chip peaks, keyed by `jax.Device.device_kind`.
+#: Source: Google Cloud documentation, "TPU v5e" (chip specifications:
+#: 197 TFLOP/s bf16, 16 GiB HBM at 819 GB/s, 1,600 Gbit/s interchip
+#: interconnect over 4 links).
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,    # FLOP/s
+        "hbm_bytes": 16 * 2**30,
+        "hbm_bw": 819e9,         # bytes/s
+        "ici_link_bw": 50e9,     # bytes/s per link (1,600 Gbit/s / 4)
+    },
+}
+V5E = "TPU v5 lite"
 CHIPS = 256              # single pod
 VMEM_BYTES = 16 * 2**20  # usable VMEM per core (conservative)
 
@@ -47,19 +57,30 @@ def choose_block_rows(row_bytes: float, fixed_bytes: float = 0.0,
     return rows
 
 
-def kernel_roofline(flops: float, hbm_bytes: float) -> dict:
+def chip_peaks(kind: str) -> dict:
+    """Published peaks of the chip whose `device_kind` is `kind`. A chip
+    missing from `CHIP_PEAKS` is an error, never a default."""
+    try:
+        return CHIP_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None
+
+
+def kernel_roofline(flops: float, hbm_bytes: float, kind: str) -> dict:
     """Roofline terms of one compiled function / kernel launch from its
-    HLO cost analysis (`launch.hlo_stats.cost_stats`): compute and
-    memory seconds under the chip constants above, arithmetic
+    HLO cost analysis (`launch.hlo_stats.cost_stats`) on the chip
+    `kind`: compute and memory seconds under its peaks, arithmetic
     intensity vs the machine balance, and which side bounds it. Used by
-    benchmarks/bench_compile.py and bench_tick_kernel.py to report
-    per-lowering FLOP/byte alongside jaxpr eqn counts."""
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm_bytes / HBM_BW
+    benchmarks/bench_compile.py to report per-lowering FLOP/byte
+    alongside jaxpr eqn counts."""
+    peaks = chip_peaks(kind)
+    compute_s = flops / peaks["bf16_flops"]
+    memory_s = hbm_bytes / peaks["hbm_bw"]
     return {"flops": flops, "hbm_bytes": hbm_bytes,
             "compute_s": compute_s, "memory_s": memory_s,
             "intensity_flops_per_byte": flops / max(hbm_bytes, 1.0),
-            "machine_balance": PEAK_FLOPS / HBM_BW,
+            "machine_balance": peaks["bf16_flops"] / peaks["hbm_bw"],
             "bound": "compute" if compute_s >= memory_s else "memory"}
 
 
@@ -135,7 +156,8 @@ def load(dirpath: pathlib.Path):
     return recs
 
 
-def analyse(recs, arch: str, shape_name: str):
+def analyse(recs, arch: str, shape_name: str, kind: str):
+    peaks = chip_peaks(kind)
     cfg = registry.get_arch(arch)
     shape = SHAPES[shape_name]
     main = recs.get((arch, shape_name, "single_pod", "main"))
@@ -179,16 +201,16 @@ def analyse(recs, arch: str, shape_name: str):
     bytes_dev = main["cost"]["bytes_accessed"]
     ici_dev = main["collectives"]["total"]["ici_bytes"]
 
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    coll_s = ici_dev / ICI_BW
+    compute_s = flops_dev / peaks["bf16_flops"]
+    memory_s = bytes_dev / peaks["hbm_bw"]
+    coll_s = ici_dev / peaks["ici_link_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
 
     mf = model_flops(cfg, shape, "block") / CHIPS
     ratio = mf / flops_dev if flops_dev else 0.0
     bound = max(terms.values())
-    frac = (mf / PEAK_FLOPS) / bound if bound else 0.0
+    frac = (mf / peaks["bf16_flops"]) / bound if bound else 0.0
     peak_gib = (mem_rec["memory"]["peak_bytes"]
                 if mem_rec.get("status") == "ok" else
                 main["memory"]["peak_bytes"]) / 2 ** 30
@@ -198,13 +220,13 @@ def analyse(recs, arch: str, shape_name: str):
         "model_flops_dev": mf, "hlo_flops_dev": flops_dev,
         "useful_ratio": ratio, "roofline_fraction": frac,
         "peak_gib": peak_gib,
-        "fits_16g": peak_gib <= 16.0,
+        "fits_hbm": peak_gib * 2 ** 30 <= peaks["hbm_bytes"],
         "compile_s": main.get("compile_s"),
         "extrapolated": extrapolated,
     }
 
 
-def table(dirpath: str = "results/dryrun") -> str:
+def table(kind: str, dirpath: str = "results/dryrun") -> str:
     recs = load(pathlib.Path(dirpath))
     lines = ["| arch | shape | compute s | memory s | coll s | dominant | "
              "MODEL/HLO | roofline frac | peak GiB |",
@@ -214,7 +236,7 @@ def table(dirpath: str = "results/dryrun") -> str:
             lines.append(f"| {arch} | {shape_name} | — | — | — | skipped | "
                          f"— | — | — |")
             continue
-        a = analyse(recs, arch, shape_name)
+        a = analyse(recs, arch, shape_name, kind)
         if not a or a.get("status") != "ok":
             lines.append(f"| {arch} | {shape_name} | ? | ? | ? | "
                          f"{(a or {}).get('status')} | ? | ? | ? |")
@@ -224,7 +246,7 @@ def table(dirpath: str = "results/dryrun") -> str:
             f"{a['memory_s']:.4f} | {a['collective_s']:.4f} | "
             f"{a['dominant']} | {a['useful_ratio']:.2f} | "
             f"{a['roofline_fraction']:.3f} | {a['peak_gib']:.1f}"
-            f"{'' if a['fits_16g'] else ' ⚠'} |")
+            f"{'' if a['fits_hbm'] else ' ⚠'} |")
     return "\n".join(lines)
 
 
@@ -233,15 +255,17 @@ def main():
     ap.add_argument("--dir", default="results/dryrun")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    # the dry-run's single-pod mesh models a v5e pod
     if args.json:
         recs = load(pathlib.Path(args.dir))
         out = {}
         for arch, shape_name, runs, _ in registry.all_cells():
             if runs:
-                out[f"{arch}/{shape_name}"] = analyse(recs, arch, shape_name)
+                out[f"{arch}/{shape_name}"] = analyse(recs, arch,
+                                                      shape_name, V5E)
         print(json.dumps(out, indent=1, default=str))
     else:
-        print(table(args.dir))
+        print(table(V5E, args.dir))
 
 
 if __name__ == "__main__":

@@ -35,7 +35,11 @@ not re-trace. `SweepService` provides exactly that on top of
 * **Pallas downgrade.** ``phase_mode="pallas"`` + ``devices=`` has no
   sharded lowering; instead of surfacing the boundary error the service
   routes the request to a single-device *chunked* plan up front and
-  records the downgrade reason in ``stats["downgrade"]``.
+  records the downgrade reason in ``stats["downgrade"]``. On a TPU the
+  pallas tick is refused at `submit` (`jax_engine.check_phase_mode`).
+* **Persistent compile cache.** Start-up enables JAX's persistent
+  compilation cache (`core.hotupdate.enable_persistent_cache`), so a
+  restarted service reads back the traces an earlier process compiled.
 
 Example::
 
@@ -62,8 +66,10 @@ import queue
 import threading
 import time
 
+from repro.core.hotupdate import enable_persistent_cache
 from repro.streams import chaos_sweep
-from repro.streams.jax_engine import scoped_cache_stats, trace_cache_stats
+from repro.streams.jax_engine import (check_phase_mode, scoped_cache_stats,
+                                      trace_cache_stats)
 
 #: request kind → driver. Every driver has signature
 #: ``fn(graph, seeds, *, ..., seed_chunk=None, on_chunk=None)`` (the
@@ -107,8 +113,9 @@ class SweepJob:
     have not landed yet. `result()` blocks until the driver returns and
     re-raises the driver's exception on failure. `stats` carries the
     service-side telemetry: state, queue/run/total wall, time-to-first-
-    result, prep/device split, per-request trace-cache hits/misses and
-    any pallas downgrade reason."""
+    result, prep/device split, the resolved tick lowering
+    (``phase_mode``), per-request trace-cache hits/misses and any pallas
+    downgrade reason."""
 
     def __init__(self, job_id: int, request: SweepRequest):
         self.id = job_id
@@ -198,6 +205,7 @@ class SweepService:
 
     def __init__(self, workers: int = 2,
                  default_seed_chunk: int | None = None):
+        self.cache_dir = enable_persistent_cache()
         self.default_seed_chunk = default_seed_chunk
         self._queue: queue.Queue = queue.Queue()
         self._jobs: dict[int, SweepJob] = {}
@@ -225,6 +233,9 @@ class SweepService:
             label=label))
 
     def submit_request(self, request: SweepRequest) -> SweepJob:
+        """Enqueue `request`. A phase mode the chip refuses raises here,
+        before anything is queued."""
+        check_phase_mode(request.kwargs.get("phase_mode", "auto"))
         with self._lock:
             job = SweepJob(next(self._ids), request)
             self._jobs[job.id] = job
@@ -303,6 +314,7 @@ class SweepService:
                     is not None else wall),
             prep_s=getattr(grid, "prep_s", 0.0),
             device_s=getattr(grid, "device_s", 0.0),
+            phase_mode=getattr(grid, "phase_mode", ""),
             cache_hits=counts["hits"], cache_misses=counts["misses"])
         job._finish(result=result)
 

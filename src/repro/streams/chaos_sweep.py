@@ -22,9 +22,9 @@ sweeps in the same device call — `SweepResult.job_results` then carries
 per-job recovery/SLO breakdowns next to the fleet-level combined
 summaries, with shared-host kills coupling the co-located jobs'
 recoveries. ``devices=`` shards the seed batch across local devices
-(version-gated `repro.dist.sharding` shim: pmap on jax 0.4.x, shard_map
-on >= 0.6); seed batches are padded to the next power of two so varying
-S reuses one jit trace per bucket. The numpy-engine baseline replay is
+(`jax.shard_map` through `repro.dist.sharding`); seed batches are
+padded to the next power of two so varying S reuses one jit trace per
+bucket. The numpy-engine baseline replay is
 opt-in via ``compare_numpy=True`` — production-size sweeps never pay
 the single-core replay by default.
 
@@ -93,6 +93,8 @@ class SweepResult:
     # per-request trace-cache traffic of this sweep's jit-fn lookups
     cache_hits: int = 0
     cache_misses: int = 0
+    # tick lowering the engine resolved ("dense" | "compact" | "pallas")
+    phase_mode: str = ""
 
     @property
     def total_s(self) -> float:
@@ -142,8 +144,13 @@ def _recovery_time(ts: np.ndarray, lag: np.ndarray, down_bk: np.ndarray,
     outage_end = max(r["t"] + r["downtime"] for r in recs)
     pre = ts < t_fail
     dlag = np.diff(lag, prepend=lag[:1])
+    # lag growth back at its pre-failure level must not read as growth
+    # through rounding: lag reaches ~3e9 records on a 10k-task fleet,
+    # where differences of lags carry ~1e-6 of f64 rounding (~1e-3 where
+    # a TPU's emulated f64 departs from IEEE f64 by ~3e-13 relative), so
+    # the margin scales with the lag
     grow_thr = (float(np.percentile(dlag[pre], 95)) if pre.any()
-                else 0.0) + 1e-9
+                else 0.0) + 1e-9 + 1e-12 * float(np.abs(lag).max())
     bk_thr = max(2.0 * (float(np.median(down_bk[pre])) if pre.any()
                         else 0.0), 1.0)
     breach = (ts < outage_end) | (dlag > grow_thr) | (down_bk > bk_thr)
@@ -348,6 +355,7 @@ def sweep(graph: LogicalGraph | PackedArena, seeds, *,
     res.device_s = timing.get("device_s", 0.0)
     res.cache_hits = timing.get("cache_hits", 0)
     res.cache_misses = timing.get("cache_misses", 0)
+    res.phase_mode = timing.get("phase_mode", "")
     if isinstance(graph, PackedArena) and batch.jobs:
         res.job_results = {
             job.name: summarize(batch.job_view(job), seeds,
@@ -432,6 +440,7 @@ class ConfigSweepResult:
     device_s: float = 0.0
     cache_hits: int = 0
     cache_misses: int = 0
+    phase_mode: str = ""           # resolved tick lowering
 
     @property
     def total_s(self) -> float:
@@ -579,7 +588,8 @@ def sweep_configs(graph: LogicalGraph | PackedArena, configs, seeds, *,
                              prep_s=timing.get("prep_s", 0.0),
                              device_s=timing.get("device_s", 0.0),
                              cache_hits=timing.get("cache_hits", 0),
-                             cache_misses=timing.get("cache_misses", 0))
+                             cache_misses=timing.get("cache_misses", 0),
+                             phase_mode=timing.get("phase_mode", ""))
 
 
 # ----------------------------------------------------------------------

@@ -76,7 +76,9 @@ engine/sweep entry point takes ``phase_mode`` ("dense" | "compact" |
   Pallas interpreter (jit/scan/vmap-traceable — CI's pallas smoke runs
   it). The trace cache keys on (bucket signature, resolved impl).
   Parity with dense/compact holds at 1e-12 (tests/test_pallas_tick.py);
-  ``devices=`` sharding is not wired for this mode.
+  ``devices=`` sharding is not wired for this mode. On a TPU backend
+  the chip's compiler refuses the kernel, so `check_phase_mode` rejects
+  ``phase_mode="pallas"`` at every entry point before any trace.
 
 "auto" picks compact exactly when the eliminated arena-wide reductions
 dominate the row-gather cost (deep packed arenas), scaled by the
@@ -282,14 +284,13 @@ per-job recovery attribution riding the shared-host chaos timeline.
 `run_batch` pads the seed axis to the next power of two (retrace-free
 batching: one trace per pow2 bucket, pad rows sliced off before
 metrics) and can split the padded batch across local devices
-(``devices=``) through the version-gated `repro.dist.sharding` shim —
-`pmap` on jax 0.4.x, `jax.shard_map` on >= 0.6. `run_mix_batch` adds a
-second vmap axis over job-mix configs (per-job source-rate
-multipliers); `run_config_batch` adds a third over resiliency-config
-grids (`FailoverConfig`/`CheckpointConfig` per grid row, optionally
-per job), so a (mixes × configs × seeds) scenario cube runs as one
-device call on one trace. `run_config_batch(devices=...)` splits the
-grid's flat seed axis across local devices too
+(``devices=``) through `jax.shard_map` (`repro.dist.sharding`).
+`run_mix_batch` adds a second vmap axis over job-mix configs (per-job
+source-rate multipliers); `run_config_batch` adds a third over
+resiliency-config grids (`FailoverConfig`/`CheckpointConfig` per grid
+row, optionally per job), so a (mixes × configs × seeds) scenario cube
+runs as one device call on one trace. `run_config_batch(devices=...)`
+splits the grid's flat seed axis across local devices too
 (`dist.sharding.sharded_grid_fn`, rows bit-identical to the
 single-device grid), and checkpoint-bearing grids refit each config's
 attempt schedule onto per-seed draw streams
@@ -341,8 +342,13 @@ genuinely overlap). The chunking contract:
   deep `NotImplementedError`; `SweepService` performs that downgrade
   automatically and records the reason.
 
-Everything runs in float64 (scoped `jax.experimental.enable_x64`, no
-global config flip) to hold parity with the float64 numpy engine.
+Everything runs in float64 to hold parity with the float64 numpy
+engine, under the thread-local ``jax.enable_x64(True)`` context and never
+a global config flip: the caller's ``jax_enable_x64`` is untouched. Each
+thread that calls a cached run fn enters the context itself (the
+`run_chunks` lane and the `launch.serve.SweepService` workers run
+`run_chunk`, which does), since a fn traced under x64 and called outside
+it would retrace in float32.
 """
 from __future__ import annotations
 
@@ -374,17 +380,6 @@ from repro.streams.engine import (AUTOSCALE_KEYS, AutoscaleConfig,
                                   lower_tensor_plan, lower_upgrade,
                                   per_task_failover)
 from repro.streams.graph import LogicalGraph, PhysicalGraph, expand
-
-try:  # scoped x64 — keeps the rest of the process on default f32
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:  # pragma: no cover - old/new jax without the ctx
-    import contextlib
-
-    @contextlib.contextmanager
-    def _enable_x64():
-        jax.config.update("jax_enable_x64", True)
-        yield
-
 
 class EngineState(NamedTuple):
     """All mutable arena state of one scenario (see module docstring).
@@ -1359,6 +1354,23 @@ _PA_CFG_AXES = {"qcap": 0, "src_row": None, "cap_base": None, "sel": 0,
                 **dict.fromkeys(AUTOSCALE_KEYS, 0)}
 
 
+PALLAS_TPU_REFUSAL = (
+    "phase_mode='pallas' is refused on a TPU: the chip's compiler "
+    "(Mosaic) rejects the fused tick kernel's gather at "
+    "kernels/tick_phase/kernel.py (alive[:, dst]: 'Shape mismatch in "
+    "input, indices and output'; ROADMAP.md, Speed 2). Use "
+    "phase_mode='compact' or 'auto'.")
+
+
+def check_phase_mode(phase_mode: str) -> None:
+    """Entry-point guard, run before any lowering or trace: on a TPU
+    backend the fused Pallas tick cannot compile, so ``"pallas"`` fails
+    here with `PALLAS_TPU_REFUSAL` instead of giving way to the jnp
+    reference or failing mid-request at its first trace."""
+    if phase_mode == "pallas" and jax.default_backend() == "tpu":
+        raise NotImplementedError(PALLAS_TPU_REFUSAL)
+
+
 def _tick_impl() -> str:
     """Resolved fused-kernel impl for pallas-mode traces. It is part of
     every pallas cache key: flipping ``REPRO_KERNEL_IMPL`` changes the
@@ -1416,9 +1428,8 @@ def get_cached_run_fns(desc: TickDesc):
 
 def get_sharded_run_fn(desc: TickDesc, n_shards: int):
     """Device-sharded batch run fn (flat seed axis, a multiple of
-    `n_shards`) — `pmap` on jax 0.4.x, `jax.shard_map` on >= 0.6 via the
-    version-gated `repro.dist.sharding` shim. Cached per (plan shape,
-    shard count)."""
+    `n_shards`) through `repro.dist.sharding.sharded_seed_fn`. Cached
+    per (plan shape, shard count)."""
     if desc.tensor.mode == "pallas":
         raise NotImplementedError(
             "devices= sharding is not wired for the pallas phase mode "
@@ -1558,6 +1569,7 @@ class _Lowered:
                  upgrade: UpgradeConfig | None = None,
                  upgrade_spec=None,
                  autoscale: AutoscaleConfig | None = None):
+        check_phase_mode(phase_mode)
         self.arena = graph if isinstance(graph, PackedArena) else None
         if self.arena is not None:
             graph = self.arena.graph
@@ -2082,7 +2094,7 @@ class JaxStreamEngine:
         n_ticks = int(round(duration_s / self.dt))
         state, xs, tl = low.prepare(self.spec, n_ticks, self._override)
         run_fn, _ = get_cached_run_fns(low.desc)
-        with _enable_x64():
+        with jax.enable_x64(True):
             final, ys = run_fn(low.arrays, state, xs)
             qps = np.asarray(ys["qps"])
             backlog = np.asarray(ys["backlog"])
@@ -2288,8 +2300,10 @@ def concat_batches(parts: list[JaxBatchMetrics]) -> JaxBatchMetrics:
 
 
 def _fill_timing(timing: dict, chunks: list[ChunkResult], plan) -> None:
-    """Record the prep/device wall split + per-request cache traffic of
-    a chunked run into the caller-supplied `timing` dict."""
+    """Record the prep/device wall split, per-request cache traffic and
+    the resolved tick lowering of a chunked run into the caller-supplied
+    `timing` dict."""
+    timing["phase_mode"] = plan.low.tensor.mode
     timing["prep_s"] = sum(c.prep_s for c in chunks)
     timing["device_s"] = sum(c.device_s for c in chunks)
     timing["chunks"] = len(chunks)
@@ -2347,7 +2361,7 @@ class SeedBatchPlan:
         lo, hi, batch_state, xs, tls = prepped
         n = hi - lo
         low = self.low
-        with _enable_x64():
+        with jax.enable_x64(True):
             final, ys = self.fn(low.arrays, batch_state, xs)
             qps = np.asarray(ys["qps"])[:n]
             backlog = np.asarray(ys["backlog"])[:n]
@@ -2399,7 +2413,7 @@ def run_batch(graph: LogicalGraph | PackedArena, seeds, *,
     per pow2 bucket instead of recompiling per batch size; pad rows are
     sliced off before the metrics object is built, so no aggregate ever
     sees them. ``devices`` splits the padded batch across local devices
-    through the version-gated `repro.dist.sharding` shim (``"auto"`` =
+    through `repro.dist.sharding.sharded_seed_fn` (``"auto"`` =
     all local devices).
 
     ``seed_chunk`` streams the batch through fixed-size seed chunks on
@@ -2467,7 +2481,7 @@ def run_mix_batch(graph: LogicalGraph | PackedArena, mixes, seeds, *,
     src_rows = low.arrays["src_row"][None, :] * mixes[:, job_of_task]
     pa = dict(low.arrays, src_row=src_rows)
     mix_fn = get_cached_mix_fn(low.desc)
-    with _enable_x64():
+    with jax.enable_x64(True):
         final, ys = mix_fn(pa, batch_state, xs)
         qps = np.asarray(ys["qps"])[:, :n_seeds]
         backlog = np.asarray(ys["backlog"])[:, :n_seeds]
@@ -2844,7 +2858,7 @@ class ConfigGridPlan:
         lo, hi, batch_state, xs, tls = prepped
         n = hi - lo
         low, mixes = self.low, self.mixes
-        with _enable_x64():
+        with jax.enable_x64(True):
             final, ys = self.fn(self.pa, batch_state, xs)
             sl = (slice(None),) * (1 if mixes is None else 2)
             qps = np.asarray(ys["qps"])[sl + (slice(None, n),)]

@@ -23,68 +23,3 @@ def run_multidevice(code: str, n_devices: int = 8,
 def assert_ok(r: subprocess.CompletedProcess):
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
 
-
-# ----------------------------------------------------------------------
-# Tiny hypothesis fallback: when the real library is absent, @given runs
-# the test over seeded random draws (enough for the two property tests
-# here; install `hypothesis` for real shrinking/edge-case search).
-# ----------------------------------------------------------------------
-try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-except ImportError:  # pragma: no cover - exercised only without hypothesis
-    import numpy as _np
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    class _FallbackStrategies:
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(
-                lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: bool(rng.integers(0, 2)))
-
-        @staticmethod
-        def tuples(*ss):
-            return _Strategy(lambda rng: tuple(s.draw(rng) for s in ss))
-
-        @staticmethod
-        def floats(min_value, max_value):
-            return _Strategy(
-                lambda rng: float(rng.uniform(min_value, max_value)))
-
-        @staticmethod
-        def lists(elem, min_size=0, max_size=None):
-            hi = max_size if max_size is not None else min_size + 10
-
-            def draw(rng):
-                n = int(rng.integers(min_size, hi + 1))
-                return [elem.draw(rng) for _ in range(n)]
-            return _Strategy(draw)
-
-    st = _FallbackStrategies()
-
-    def settings(**kw):
-        def deco(fn):
-            fn._max_examples = kw.get("max_examples", 25)
-            return fn
-        return deco
-
-    def given(*strats):
-        def deco(fn):
-            # NOTE: deliberately no functools.wraps — pytest must see the
-            # zero-arg signature, not the original one (whose parameters it
-            # would try to resolve as fixtures).
-            def wrapper():
-                rng = _np.random.default_rng(0)
-                n = getattr(wrapper, "_max_examples", 25)
-                for _ in range(n):
-                    fn(*(s.draw(rng) for s in strats))
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco

@@ -4,8 +4,10 @@ reproducible bit-for-bit) plus the pregenerated-event-tensor contract:
 as the live engine does, so a timeline is interchangeable with
 sequential draws."""
 import numpy as np
+import pytest
 
-from repro.core.chaos import ChaosEngine, ChaosSpec, build_chaos_timeline
+from repro.core.chaos import (ChaosEngine, ChaosSpec, build_chaos_timeline,
+                              failover_recovery_entries)
 from repro.streams import nexmark
 from repro.streams.engine import (CheckpointConfig, FailoverConfig,
                                   StreamEngine)
@@ -107,3 +109,24 @@ def test_timeline_matches_live_engine_run():
     assert (tl.ckpt_attempts, tl.ckpt_success, tl.ckpt_failed) == \
         (m.ckpt_attempts, m.ckpt_success, m.ckpt_failed)
     np.testing.assert_array_equal(tl.ts, np.array(m.t))
+
+
+@pytest.mark.parametrize("per_task", [False, True])
+def test_recovery_entries_match_per_job_loop(per_task):
+    """The one-pass grouping of a kill's hit tasks into per-job entries
+    equals the plain loop over jobs: one entry per hit job, ascending,
+    counting its hit tasks, with its first hit task's downtime."""
+    rng = np.random.default_rng(0)
+    job_of_task = rng.integers(0, 40, 600)
+    for _ in range(20):
+        hit = rng.random(600) < 0.1
+        down = rng.uniform(1.0, 9.0, 600) if per_task else 4.5
+        ref = [{"t": 7.5, "mode": "region",
+                "tasks": int((hit & (job_of_task == j)).sum()),
+                "downtime": float(np.asarray(down, float)[
+                    hit & (job_of_task == j)][0]) if per_task
+                else float(down),
+                "job": int(j)}
+               for j in np.unique(job_of_task[hit])]
+        assert failover_recovery_entries(7.5, "region", hit, down,
+                                         job_of_task) == ref

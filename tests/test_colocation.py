@@ -214,8 +214,8 @@ def test_mix_batch_rejects_bad_mix_width():
 
 
 def test_sharded_batch_matches_unsharded():
-    """devices= routes through the repro.dist shim (pmap on this jax);
-    with one local device the shard axis is 1 but the full pmap path and
+    """devices= routes through the repro.dist shard_map path; with one
+    local device the shard axis is 1 but the full sharded path and
     result reassembly run — results must be identical."""
     g = nexmark.q2(parallelism=4, partitioner="weakhash", n_groups=2)
     spec = ChaosSpec(host_kill_prob_per_s=0.004, straggler_frac=0.2)

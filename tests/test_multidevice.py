@@ -165,8 +165,8 @@ print("localsgd ok")
 
 
 def test_sharded_chaos_sweep_matches_unsharded():
-    """Seed-batch device sharding (repro.dist.sharding shim — pmap on
-    this jax, shard_map on >= 0.6): a 16-seed sweep split across 4
+    """Seed-batch device sharding (repro.dist.sharding, shard_map):
+    a 16-seed sweep split across 4
     forced host devices must reproduce the single-device vmapped sweep
     to reassociation tolerance, on a packed 2-job arena."""
     code = """

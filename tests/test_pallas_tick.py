@@ -21,6 +21,7 @@ The autouse fixture pins ``REPRO_KERNEL_IMPL=interpret`` so every
 engine-level test here exercises the real kernel body, not just the
 reference lowering.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -29,8 +30,8 @@ from repro.streams import nexmark
 from repro.streams.engine import (FailoverConfig, build_plan,
                                   select_phase_mode)
 from repro.streams.jax_engine import (JaxStreamEngine, _FN_CACHE,
-                                      _Lowered, _enable_x64,
-                                      get_cached_run_fns, run_batch)
+                                      _Lowered, get_cached_run_fns,
+                                      run_batch)
 
 TOL = dict(rtol=1e-12, atol=1e-9)
 
@@ -100,7 +101,7 @@ def test_pallas_matches_dense_2k_arena():
         low = _Lowered(arena, n_hosts=32, dt=0.5, queue_cap=256.0,
                        failover=fo, ckpt=None, seed=0, phase_mode=mode)
         run_fn, _ = get_cached_run_fns(low.desc)
-        with _enable_x64():
+        with jax.enable_x64(True):
             st, xs, _ = low.prepare(spec, 32)
             _, ys = run_fn(low.arrays, st, xs)
             outs[mode] = {k: np.asarray(v) for k, v in ys.items()}
@@ -136,7 +137,7 @@ def test_tick_phase_interpret_matches_ref():
     low = _Lowered(arena, n_hosts=8, dt=0.5, queue_cap=256.0,
                    failover=None, ckpt=None, seed=0, phase_mode="pallas")
     rng = np.random.default_rng(7)
-    with _enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
         S, T = 8, low.plan.n_tasks
         produced = jnp.asarray(rng.uniform(0, 50.0, (S, T)))

@@ -8,7 +8,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from helpers import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.chaos import (ChaosEngine, ChaosSpec, brownout_curve,
                               brownout_factor_at, ckpt_age_curve,
                               timeline_build_count)
@@ -303,3 +303,26 @@ def test_storage_and_leader_outage_drill():
     assert rec.leader_id == "jm-host-7"
     assert svc.fallback_reads == 1
     assert svc.terminations == 0
+
+
+@pytest.mark.parametrize("extra_growth, recovered", [(0.0, True),
+                                                     (1e-6, False)])
+def test_recovery_time_ignores_f64_rounding_of_large_lags(extra_growth,
+                                                          recovered):
+    """A 10k-task fleet retains ~3e9 records of source lag, growing by a
+    steady ~1.8e7 per tick. Rounding at the 3e-13 relative level (the
+    spread between a TPU's emulated f64 and the CPU's f64) must not read
+    as lag growth after a 1 s hot-standby failover, while growth one
+    part in a million above the pre-failure rate still does."""
+    from repro.streams.chaos_sweep import _recovery_time
+
+    rng = np.random.default_rng(0)
+    ts = np.arange(360) * 0.5
+    growth = np.where(ts < 60.0, 1.8e7, 1.8e7 * (1.0 + extra_growth))
+    lag = 1.4e9 + np.cumsum(growth)
+    lag *= 1.0 + rng.uniform(-3e-13, 3e-13, lag.shape)
+    recs = [{"t": 60.0, "downtime": 1.05}]
+    rt = _recovery_time(ts, lag, np.zeros_like(ts), recs)
+    assert np.isfinite(rt) == recovered
+    if recovered:
+        assert rt == 1.5

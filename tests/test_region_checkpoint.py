@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from helpers import given, settings, st  # hypothesis, or seeded fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.ckpt.manifest import Manifest, RegionSnapshot
 from repro.ckpt.storage import LocalFS, ObjectStoreSim, SimHDFS, FallbackStorage

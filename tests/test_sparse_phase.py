@@ -16,6 +16,7 @@ Pillars:
   arenas), dense for small/shallow graphs, and the
   ``REPRO_REQUIRE_PHASE_MODE`` guard refuses silent fallbacks.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -24,8 +25,7 @@ from repro.streams import nexmark
 from repro.streams.engine import (FailoverConfig, build_plan, pack_arena,
                                   select_phase_mode)
 from repro.streams.jax_engine import (JaxStreamEngine, _FN_CACHE,
-                                      _Lowered, get_cached_run_fns,
-                                      _enable_x64)
+                                      _Lowered, get_cached_run_fns)
 
 TOL = dict(rtol=1e-12, atol=1e-9)
 
@@ -104,7 +104,7 @@ def test_compact_matches_dense_10k_arena():
         low = _Lowered(arena, n_hosts=32, dt=0.5, queue_cap=256.0,
                        failover=fo, ckpt=None, seed=0, phase_mode=mode)
         run_fn, _ = get_cached_run_fns(low.desc)
-        with _enable_x64():
+        with jax.enable_x64(True):
             st, xs, _ = low.prepare(spec, 32)
             _, ys = run_fn(low.arrays, st, xs)
             outs[mode] = {k: np.asarray(v) for k, v in ys.items()}

@@ -3,7 +3,7 @@ ZK→HDFS leader fallback, termination on double failure (paper §IV-B)."""
 import numpy as np
 import pytest
 
-from helpers import given, settings, st  # hypothesis, or seeded fallback
+from hypothesis import given, settings, strategies as st
 
 from repro.ckpt.storage import (FallbackStorage, LocalFS, ObjectStoreSim,
                                 SimHDFS, StorageUnavailable)

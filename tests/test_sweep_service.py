@@ -265,3 +265,66 @@ def test_concurrent_subscribers_one_job(mono):
             t.join(600)
         job.result(1.0)
     assert out[0] == out[1] == [0, 1, 2]
+
+
+# ----------------------------------------------------------------------
+# scoped x64 and the persistent compile cache
+# ----------------------------------------------------------------------
+def test_engine_calls_leave_caller_x64_unchanged():
+    """The engine runs in f64 under the thread-local ``jax.enable_x64``
+    context: the caller's flag, and the dtype of its own arrays, are
+    the same after every entry point as before."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.streams.chaos_sweep import sweep_configs
+
+    flag = jax.config.jax_enable_x64
+    g = nexmark.q2(parallelism=2)
+    kw = dict(base_spec=SPEC, duration_s=10.0, n_hosts=4)
+    res = run_config_batch(g, [FO, (FO, CKPT)], range(3), **kw)
+    assert res[0].source_lag.dtype == np.float64
+    assert jax.config.jax_enable_x64 == flag
+    sweep_configs(g, [FO, (FO, CKPT)], range(3), seed_chunk=2, **kw)
+    assert jax.config.jax_enable_x64 == flag
+    with SweepService(workers=1) as svc:
+        job = svc.submit("sweep_configs", g, range(3), seed_chunk=2,
+                         configs=[FO, (FO, CKPT)], **kw)
+        cube = job.result(600.0)
+    assert cube.backlog_surface.dtype == np.float64
+    assert jax.config.jax_enable_x64 == flag
+    assert jnp.zeros(2).dtype == (jnp.float64 if flag else jnp.float32)
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_persistent_cache_dir(monkeypatch, tmp_path, env_dir):
+    """`JAX_COMPILATION_CACHE_DIR` wins and no other path is set;
+    otherwise the cache sits at the checkout's fixed `.jax_cache`. The
+    CPU backend never turns the cache on."""
+    import jax
+
+    from repro.core import hotupdate
+
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    assert hotupdate.enable_persistent_cache() is None      # CPU backend
+    assert jax.config.jax_compilation_cache_dir == prev[0]
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        path = hotupdate.enable_persistent_cache()
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        if env_dir:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev[0]
+        else:
+            assert path == str(hotupdate.REPO_CACHE_DIR)
+            assert hotupdate.REPO_CACHE_DIR.name == ".jax_cache"
+            assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])

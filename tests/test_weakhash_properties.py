@@ -1,5 +1,4 @@
-"""Property-based tests for `weakhash_assign` invariants (via the
-hypothesis shim in tests/helpers.py — real hypothesis when installed):
+"""Property-based tests for `weakhash_assign` invariants (hypothesis):
 
 * counts sum to N and every key stays inside its candidate group
   (bounded candidate set — the WeakHash §III-A contract);
@@ -12,7 +11,7 @@ hypothesis shim in tests/helpers.py — real hypothesis when installed):
 """
 import numpy as np
 
-from helpers import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.weakhash import candidate_group, load_cv, weakhash_assign
 
 
