@@ -1,0 +1,6 @@
+"""90th percentile of due time to the whole result, host clock (s)."""
+from bench.harness.readers import latency_p90
+
+
+def read(run):
+    return latency_p90(run, 1)
