@@ -111,7 +111,8 @@ def main() -> None:
                 first_chunk_s.setdefault(job.id, now)
                 print(f"  [{job.request.label}] chunk {chunk.index}: "
                       f"seeds [{chunk.seed_lo}, {chunk.seed_hi}) "
-                      f"device={chunk.device_s * 1e3:.0f}ms  t={now:.2f}s")
+                      f"device={chunk.device_s * 1e3:.0f}ms "
+                      f"fetch={chunk.fetch_s * 1e3:.0f}ms  t={now:.2f}s")
             done_s[job.id] = time.perf_counter() - t0
 
         watchers = [threading.Thread(target=watch, args=(j,))
@@ -150,6 +151,7 @@ def main() -> None:
               f"chunks={js['chunks']} ttfr={js['ttfr_s']:.2f}s "
               f"wall={js['wall_s']:.2f}s prep={js['prep_s'] * 1e3:.0f}ms "
               f"device={js['device_s'] * 1e3:.0f}ms "
+              f"fetch={js['fetch_s'] * 1e3:.0f}ms "
               f"hits={js['cache_hits']} misses={js['cache_misses']}")
 
     print(json.dumps({"trace_cache": stats["trace_cache"],

@@ -31,7 +31,16 @@ not re-trace. `SweepService` provides exactly that on top of
   tests/test_sweep_service.py.
 * **Pipelined prep.** Host-side timeline prep for chunk k+1 overlaps
   device compute for chunk k (`jax_engine.run_chunks`' double-buffered
-  lane); the measured split rides each job's ``prep_s`` / ``device_s``.
+  lane); the measured split rides each job's ``prep_s`` / ``device_s`` /
+  ``fetch_s``.
+* **Spans.** Each job records its spans into ``SweepJob.spans`` (a
+  `streams.spans.SpanLog` passed to every thread that serves it): the
+  root ``sweep.request`` on the worker, then ``sweep.plan``,
+  ``sweep.prep``, ``sweep.device``, ``sweep.fetch``,
+  ``sweep.summarize`` and ``sweep.assemble``. Each is also a profiler
+  trace annotation, so a trace names the host work between device
+  passes. Every time in ``stats`` is read from them; ``queued_s``,
+  ``ttfr_s`` and ``wall_s`` run from submission.
 * **Pallas downgrade.** ``phase_mode="pallas"`` + ``devices=`` has no
   sharded lowering; instead of surfacing the boundary error the service
   routes the request to a single-device *chunked* plan up front and
@@ -70,10 +79,11 @@ from repro.core.hotupdate import enable_persistent_cache
 from repro.streams import chaos_sweep
 from repro.streams.jax_engine import (check_phase_mode, scoped_cache_stats,
                                       trace_cache_stats)
+from repro.streams.spans import SpanLog
 
 #: request kind → driver. Every driver has signature
-#: ``fn(graph, seeds, *, ..., seed_chunk=None, on_chunk=None)`` (the
-#: cube wrappers forward both through ``**sweep_kw``).
+#: ``fn(graph, seeds, *, ..., seed_chunk=None, on_chunk=None,
+#: spans=None)`` (the cube wrappers forward them through ``**sweep_kw``).
 KINDS = {
     "sweep": chaos_sweep.sweep,
     "sweep_configs": chaos_sweep.sweep_configs,
@@ -112,10 +122,14 @@ class SweepJob:
     from chunk 0) and blocks on the job's condition for chunks that
     have not landed yet. `result()` blocks until the driver returns and
     re-raises the driver's exception on failure. `stats` carries the
-    service-side telemetry: state, queue/run/total wall, time-to-first-
-    result, prep/device split, the resolved tick lowering
-    (``phase_mode``), per-request trace-cache hits/misses and any pallas
-    downgrade reason."""
+    service-side telemetry, every time read from the request's `spans`:
+    state, queue wait (``queued_s``), time to the first result
+    (``ttfr_s``) and to the whole result (``wall_s``), both from
+    submission, the summed prep / device-wait / history-copy split
+    (``prep_s`` / ``device_s`` / ``fetch_s``), the final assembly
+    (``assemble_s``), the resolved tick lowering (``phase_mode``),
+    per-request trace-cache hits/misses and any pallas downgrade
+    reason."""
 
     def __init__(self, job_id: int, request: SweepRequest):
         self.id = job_id
@@ -125,6 +139,7 @@ class SweepJob:
         self._done = False
         self._error: BaseException | None = None
         self._result = None
+        self.spans = SpanLog(request=job_id)
         self.stats: dict = {"state": "queued", "chunks": 0,
                             "ttfr_s": None, "wall_s": None,
                             "downgrade": None}
@@ -285,35 +300,48 @@ class SweepService:
             kwargs["devices"] = None
 
         job.stats["state"] = "running"
-        t0 = time.perf_counter()
-        job.stats["queued_s"] = t0 - job.stats.pop("submitted_s", t0)
+        submitted = job.stats.pop("submitted_s")
+        log = job.spans
 
         def publish(chunk):
             if job.stats["ttfr_s"] is None:
-                job.stats["ttfr_s"] = time.perf_counter() - t0
+                # the chunk's summary span has just closed
+                job.stats["ttfr_s"] = (log.of("sweep.summarize")[0].end
+                                       - submitted)
             job._publish(chunk)
 
-        try:
-            # sweep_configs is the one driver with a second positional
-            # (the config grid) — accept it as the `configs` kwarg
-            args = (req.graph, seeds)
-            if req.kind == "sweep_configs":
-                args = (req.graph, kwargs.pop("configs"), seeds)
-            with scoped_cache_stats() as counts:
-                result = KINDS[req.kind](*args, seed_chunk=seed_chunk,
-                                         on_chunk=publish, **kwargs)
-        except BaseException as exc:              # noqa: BLE001
-            job.stats["wall_s"] = time.perf_counter() - t0
-            job._finish(error=exc)
+        result = error = None
+        with log.span("sweep.request", seeds=len(seeds)) as root:
+            job.stats["queued_s"] = root.start - submitted
+            try:
+                # sweep_configs is the one driver with a second
+                # positional (the config grid) — accept it as the
+                # `configs` kwarg
+                args = (req.graph, seeds)
+                if req.kind == "sweep_configs":
+                    args = (req.graph, kwargs.pop("configs"), seeds)
+                with scoped_cache_stats() as counts:
+                    result = KINDS[req.kind](*args, seed_chunk=seed_chunk,
+                                             on_chunk=publish, spans=log,
+                                             **kwargs)
+            except BaseException as exc:          # noqa: BLE001
+                error = exc
+            root.count(chunks=job.stats["chunks"])
+            if error is None:
+                root.count(configs=len(getattr(_grid_of(result),
+                                               "configs", (None,))))
+        job.stats["wall_s"] = wall = root.end - submitted
+        if error is not None:
+            job._finish(error=error)
             return
-        wall = time.perf_counter() - t0
         grid = _grid_of(result)
         job.stats.update(
-            wall_s=wall,
             ttfr_s=(job.stats["ttfr_s"] if job.stats["ttfr_s"]
                     is not None else wall),
-            prep_s=getattr(grid, "prep_s", 0.0),
-            device_s=getattr(grid, "device_s", 0.0),
+            prep_s=log.total("sweep.prep"),
+            device_s=log.total("sweep.device"),
+            fetch_s=log.total("sweep.fetch"),
+            assemble_s=log.total("sweep.assemble"),
             phase_mode=getattr(grid, "phase_mode", ""),
             cache_hits=counts["hits"], cache_misses=counts["misses"])
         job._finish(result=result)
@@ -387,7 +415,8 @@ def main() -> None:
             print(f"chunk {chunk.index}: seeds "
                   f"[{chunk.seed_lo},{chunk.seed_hi}) "
                   f"prep={chunk.prep_s:.3f}s "
-                  f"device={chunk.device_s:.3f}s", flush=True)
+                  f"device={chunk.device_s:.3f}s "
+                  f"fetch={chunk.fetch_s:.3f}s", flush=True)
         cube = job.result()
         print(json.dumps({"rollback_frac":
                           cube.rollback_frac.mean(axis=-1).tolist(),

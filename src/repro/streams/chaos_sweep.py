@@ -34,8 +34,16 @@ then streams through the engine's double-buffered prep/compute pipeline
 and `SweepChunk` partial surfaces are published as each chunk lands
 (the `launch.serve.SweepService` incremental-result path), with the
 concatenated result bit-identical to the monolithic call. Results carry
-the ``prep_s`` / ``device_s`` wall split and per-request trace-cache
-hit/miss counts next to the compat total-derived ``scenarios_per_s``.
+the ``prep_s`` / ``device_s`` split and per-request trace-cache hit/miss
+counts next to the compat total-derived ``scenarios_per_s``.
+
+Spans: every driver takes ``spans=`` (a `streams.spans.SpanLog`, one per
+request; a fresh one when None) and records ``sweep.plan`` (the plan's
+construction, with its trace-cache hits and misses), the engine's
+per-chunk ``sweep.prep`` / ``sweep.device`` / ``sweep.fetch``,
+``sweep.summarize`` per published chunk and ``sweep.assemble`` (chunk
+concatenation and the whole-cube summary) into it; every time a result
+or chunk carries is read from those spans.
 """
 from __future__ import annotations
 
@@ -50,8 +58,11 @@ from repro.streams.engine import (AutoscaleConfig, CheckpointConfig,
                                   FailoverConfig, PackedArena,
                                   UpgradeConfig)
 from repro.streams.graph import LogicalGraph
-from repro.streams.jax_engine import (JaxBatchMetrics, normalize_config,
-                                      run_batch, run_config_batch)
+from repro.streams.jax_engine import (ConfigGridPlan, JaxBatchMetrics,
+                                      SeedBatchPlan, concat_batches,
+                                      concat_config_batches,
+                                      normalize_config, run_chunks)
+from repro.streams.spans import SpanLog
 
 
 @dataclasses.dataclass
@@ -84,10 +95,10 @@ class SweepResult:
     # opt-in numpy cross-check (see sweep(compare_numpy=...)); None unless
     # requested — production sweeps never pay the single-core replay
     numpy_check: dict | None = None
-    # wall-time split of the chunked pipeline: host-side timeline prep vs
-    # device compute (their sum can exceed `wall_s` when the
-    # double-buffered pipeline overlaps them — that gap IS the overlap
-    # win). Zero for legacy callers that bypass the timing plumb.
+    # time split of the chunked pipeline, summed over chunks from their
+    # spans: host-side timeline prep vs the device wait (their sum can
+    # exceed `wall_s` when the double-buffered pipeline overlaps them —
+    # that gap IS the overlap win). Zero for `summarize`'s own results.
     prep_s: float = 0.0
     device_s: float = 0.0
     # per-request trace-cache traffic of this sweep's jit-fn lookups
@@ -225,8 +236,11 @@ class SweepChunk:
     seed_lo: int
     seed_hi: int                   # half-open [seed_lo, seed_hi)
     seeds: list
-    prep_s: float                  # host timeline prep for this chunk
-    device_s: float                # device pass for this chunk
+    prep_s: float                  # host timeline prep (sweep.prep)
+    device_s: float                # device wait alone (sweep.device)
+    fetch_s: float                 # device→host copy (sweep.fetch)
+    summarize_s: float             # summaries + surfaces (sweep.summarize)
+    history_bytes: int             # bytes the copy fetched
     summaries: list[list[ScenarioSummary]]   # [C][S_chunk]
     recovery_surface: np.ndarray   # (C, S_chunk)
     slo_surface: np.ndarray
@@ -243,7 +257,7 @@ class SweepChunk:
 
     @property
     def total_s(self) -> float:
-        return self.prep_s + self.device_s
+        return self.prep_s + self.device_s + self.fetch_s
 
 
 def _chunk_surfaces(batches, results) -> dict:
@@ -280,20 +294,45 @@ def _chunk_surfaces(batches, results) -> dict:
 
 
 def _publish_chunk(on_chunk, index: int, cr, seeds, *, graph, slo_lag,
-                   duration_s) -> None:
-    """Summarize one engine `ChunkResult` into a `SweepChunk` and hand
-    it to the caller's `on_chunk` subscriber."""
+                   duration_s, spans: SpanLog) -> None:
+    """Summarize one engine `ChunkResult` into a `SweepChunk` (in a
+    ``sweep.summarize`` span) and hand it to the caller's `on_chunk`
+    subscriber."""
     batches = (cr.batches if isinstance(cr.batches, list)
                else [cr.batches])
     chunk_seeds = seeds[cr.seed_lo:cr.seed_hi]
-    results = [summarize(bm, chunk_seeds, graph=graph, slo_lag=slo_lag,
-                         wall_s=cr.device_s, graph_name=graph.name,
-                         duration_s=duration_s) for bm in batches]
+    with spans.span("sweep.summarize", chunk=index,
+                    scenarios=len(batches) * len(chunk_seeds)) as sp:
+        results = [summarize(bm, chunk_seeds, graph=graph,
+                             slo_lag=slo_lag,
+                             wall_s=cr.device_s + cr.fetch_s,
+                             graph_name=graph.name,
+                             duration_s=duration_s) for bm in batches]
+        surfaces = _chunk_surfaces(batches, results)
     on_chunk(SweepChunk(index=index, seed_lo=cr.seed_lo,
                         seed_hi=cr.seed_hi, seeds=chunk_seeds,
                         prep_s=cr.prep_s, device_s=cr.device_s,
+                        fetch_s=cr.fetch_s, summarize_s=sp.seconds,
+                        history_bytes=cr.history_bytes,
                         summaries=[r.summaries for r in results],
-                        **_chunk_surfaces(batches, results)))
+                        **surfaces))
+
+
+def _run_plan(make_plan, seeds, seed_chunk, on_chunk, spans: SpanLog, *,
+              graph, slo_lag, duration_s):
+    """Build a chunk plan (in a ``sweep.plan`` span) and run its chunks,
+    publishing each to `on_chunk`; returns the plan, its `ChunkResult`s
+    and the plan span."""
+    publish = None
+    if on_chunk is not None:
+        counter = iter(range(len(seeds) + 1))
+        publish = lambda cr: _publish_chunk(                 # noqa: E731
+            on_chunk, next(counter), cr, seeds, graph=graph,
+            slo_lag=slo_lag, duration_s=duration_s, spans=spans)
+    with spans.span("sweep.plan") as planned:
+        plan = make_plan()
+        planned.count(**plan.cache_info)
+    return plan, run_chunks(plan, seed_chunk, publish, spans), planned
 
 
 def sweep(graph: LogicalGraph | PackedArena, seeds, *,
@@ -309,6 +348,7 @@ def sweep(graph: LogicalGraph | PackedArena, seeds, *,
           phase_mode: str = "auto",
           seed_chunk: int | None = None,
           on_chunk=None,
+          spans: SpanLog | None = None,
           compare_numpy: bool = False) -> SweepResult:
     """Sweep `seeds` chaos scenarios over `graph` in one vmapped jit call
     (one call per device shard when `devices` is set).
@@ -322,7 +362,9 @@ def sweep(graph: LogicalGraph | PackedArena, seeds, *,
     the engine's double-buffered pipeline (bit-identical result, see
     `jax_engine.run_batch`); ``on_chunk`` receives a `SweepChunk` with
     the partial surfaces as each chunk lands. The result's ``prep_s`` /
-    ``device_s`` carry the host-prep vs device wall split either way.
+    ``device_s`` carry the host-prep vs device-wait split either way,
+    and ``wall_s`` runs from the plan's start to the last chunk's
+    landing; ``spans`` receives the request's spans (module docstring).
 
     ``compare_numpy`` is OPT-IN (default False): the numpy-engine
     baseline replay costs a single-core scenario per checked seed, which
@@ -332,37 +374,35 @@ def sweep(graph: LogicalGraph | PackedArena, seeds, *,
     """
     seeds = list(seeds)
     logical = graph.graph if isinstance(graph, PackedArena) else graph
-    timing: dict = {}
-    publish = None
-    if on_chunk is not None:
-        counter = iter(range(len(seeds) + 1))
-        publish = lambda cr: _publish_chunk(
-            on_chunk, next(counter), cr, seeds, graph=logical,
-            slo_lag=slo_lag, duration_s=duration_s)
-    t0 = time.perf_counter()
-    batch = run_batch(graph, seeds, base_spec=base_spec,
-                      duration_s=duration_s, n_hosts=n_hosts, dt=dt,
-                      queue_cap=queue_cap, failover=failover, ckpt=ckpt,
-                      task_speed_override=task_speed_override, seed=seed,
-                      pad_seeds=pad_seeds, devices=devices,
-                      phase_mode=phase_mode, seed_chunk=seed_chunk,
-                      on_chunk=publish, timing=timing)
-    wall = time.perf_counter() - t0
-    res = summarize(batch, seeds, graph=logical, slo_lag=slo_lag,
-                    wall_s=wall, graph_name=logical.name,
-                    duration_s=duration_s)
-    res.prep_s = timing.get("prep_s", 0.0)
-    res.device_s = timing.get("device_s", 0.0)
-    res.cache_hits = timing.get("cache_hits", 0)
-    res.cache_misses = timing.get("cache_misses", 0)
-    res.phase_mode = timing.get("phase_mode", "")
-    if isinstance(graph, PackedArena) and batch.jobs:
-        res.job_results = {
-            job.name: summarize(batch.job_view(job), seeds,
-                                graph=job.graph, slo_lag=slo_lag,
-                                wall_s=wall, graph_name=job.name,
-                                duration_s=duration_s)
-            for job in batch.jobs}
+    spans = SpanLog() if spans is None else spans
+    plan, chunks, planned = _run_plan(
+        lambda: SeedBatchPlan(graph, seeds, base_spec=base_spec,
+                              duration_s=duration_s, n_hosts=n_hosts,
+                              dt=dt, queue_cap=queue_cap,
+                              failover=failover, ckpt=ckpt,
+                              task_speed_override=task_speed_override,
+                              seed=seed, pad_seeds=pad_seeds,
+                              devices=devices, phase_mode=phase_mode),
+        seeds, seed_chunk, on_chunk, spans, graph=logical,
+        slo_lag=slo_lag, duration_s=duration_s)
+    with spans.span("sweep.assemble", scenarios=len(seeds)) as asm:
+        wall = asm.start - planned.start
+        batch = concat_batches([c.batches for c in chunks])
+        res = summarize(batch, seeds, graph=logical, slo_lag=slo_lag,
+                        wall_s=wall, graph_name=logical.name,
+                        duration_s=duration_s)
+        if isinstance(graph, PackedArena) and batch.jobs:
+            res.job_results = {
+                job.name: summarize(batch.job_view(job), seeds,
+                                    graph=job.graph, slo_lag=slo_lag,
+                                    wall_s=wall, graph_name=job.name,
+                                    duration_s=duration_s)
+                for job in batch.jobs}
+    res.prep_s = sum(c.prep_s for c in chunks)
+    res.device_s = sum(c.device_s for c in chunks)
+    res.cache_hits = plan.cache_info["hits"]
+    res.cache_misses = plan.cache_info["misses"]
+    res.phase_mode = plan.low.tensor.mode
     if compare_numpy:
         res.numpy_check = _numpy_check(graph, seeds, batch,
                                        base_spec=base_spec,
@@ -520,7 +560,8 @@ def sweep_configs(graph: LogicalGraph | PackedArena, configs, seeds, *,
                   devices: int | str | None = None,
                   phase_mode: str = "auto",
                   seed_chunk: int | None = None,
-                  on_chunk=None) -> ConfigSweepResult:
+                  on_chunk=None,
+                  spans: SpanLog | None = None) -> ConfigSweepResult:
     """Sweep a ``(C, S)`` grid of resiliency configs × chaos seeds over
     `graph` in ONE doubly-vmapped jit call (`jax_engine.run_config_batch`
     — the engine's third vmap axis) and summarize each config row.
@@ -547,34 +588,36 @@ def sweep_configs(graph: LogicalGraph | PackedArena, configs, seeds, *,
     `SweepChunk` with each partial ``(C, S_chunk)`` surface as it lands
     (the service layer's time-to-first-result path). The result's
     ``prep_s`` / ``device_s`` / ``cache_hits`` / ``cache_misses`` carry
-    the wall split + per-request trace-cache traffic either way."""
+    the prep / device-wait split + per-request trace-cache traffic
+    either way, and ``wall_s`` runs from the plan's start to the last
+    chunk's landing; ``spans`` receives the request's spans (module
+    docstring)."""
     seeds = list(seeds)
     norm = [normalize_config(c) for c in configs]
     logical = graph.graph if isinstance(graph, PackedArena) else graph
-    timing: dict = {}
-    publish = None
-    if on_chunk is not None:
-        counter = iter(range(len(seeds) + 1))
-        publish = lambda cr: _publish_chunk(
-            on_chunk, next(counter), cr, seeds, graph=logical,
-            slo_lag=slo_lag, duration_s=duration_s)
-    t0 = time.perf_counter()
-    batches = run_config_batch(graph, norm, seeds, base_spec=base_spec,
+    spans = SpanLog() if spans is None else spans
+    plan, chunks, planned = _run_plan(
+        lambda: ConfigGridPlan(graph, norm, seeds, base_spec=base_spec,
                                duration_s=duration_s, n_hosts=n_hosts,
                                dt=dt, queue_cap=queue_cap,
                                task_speed_override=task_speed_override,
                                seed=seed, pad_seeds=pad_seeds,
-                               devices=devices, phase_mode=phase_mode,
-                               seed_chunk=seed_chunk, on_chunk=publish,
-                               timing=timing)
-    wall = time.perf_counter() - t0
-    # each config row gets its share of the one-call wall time, so a
-    # row's scenarios_per_s stays comparable with a standalone sweep()
-    results = [summarize(bm, seeds, graph=logical, slo_lag=slo_lag,
-                         wall_s=wall / len(norm),
-                         graph_name=logical.name, duration_s=duration_s)
-               for bm in batches]
-    surf = _chunk_surfaces(batches, results)
+                               devices=devices, phase_mode=phase_mode),
+        seeds, seed_chunk, on_chunk, spans, graph=logical,
+        slo_lag=slo_lag, duration_s=duration_s)
+    with spans.span("sweep.assemble",
+                    scenarios=len(norm) * len(seeds)) as asm:
+        wall = asm.start - planned.start
+        batches = concat_config_batches([c.batches for c in chunks])
+        # each config row gets its share of the one-call wall time, so
+        # a row's scenarios_per_s stays comparable with a standalone
+        # sweep()
+        results = [summarize(bm, seeds, graph=logical, slo_lag=slo_lag,
+                             wall_s=wall / len(norm),
+                             graph_name=logical.name,
+                             duration_s=duration_s)
+                   for bm in batches]
+        surf = _chunk_surfaces(batches, results)
     labels = [_config_label(i, c) for i, c in enumerate(norm)]
     return ConfigSweepResult(logical.name, duration_s, norm, labels,
                              results, surf["recovery_surface"],
@@ -585,11 +628,11 @@ def sweep_configs(graph: LogicalGraph | PackedArena, configs, seeds, *,
                              thrash_surface=surf["thrash_surface"],
                              rescale_surface=surf["rescale_surface"],
                              cost_surface=surf["cost_surface"],
-                             prep_s=timing.get("prep_s", 0.0),
-                             device_s=timing.get("device_s", 0.0),
-                             cache_hits=timing.get("cache_hits", 0),
-                             cache_misses=timing.get("cache_misses", 0),
-                             phase_mode=timing.get("phase_mode", ""))
+                             prep_s=sum(c.prep_s for c in chunks),
+                             device_s=sum(c.device_s for c in chunks),
+                             cache_hits=plan.cache_info["hits"],
+                             cache_misses=plan.cache_info["misses"],
+                             phase_mode=plan.low.tensor.mode)
 
 
 # ----------------------------------------------------------------------

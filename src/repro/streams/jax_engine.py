@@ -305,11 +305,13 @@ Chunked execution + shared trace-cache keying (sweep-as-a-service)
 Every batch entry point decomposes into a *plan* (`SeedBatchPlan` /
 `ConfigGridPlan`: lowering, per-config traced params, timeline-path
 selection, trace-cache lookup — all seed-count-independent) plus
-`prep_chunk(lo, hi)` / `run_chunk` over half-open seed slices, driven
-by `run_chunks`' double-buffered pipeline: host timeline prep for
-chunk k+1 runs on the caller thread while chunk k's device pass blocks
-on a one-slot executor lane (XLA releases the GIL, so prep and compute
-genuinely overlap). The chunking contract:
+`prep_chunk(lo, hi)` / `dispatch` / `fetch` over half-open seed
+slices, driven by `run_chunks`' double-buffered pipeline: host timeline
+prep for chunk k+1 runs on the caller thread while chunk k's device
+pass blocks on a one-slot executor lane (XLA releases the GIL, so prep
+and compute genuinely overlap), then copies its history to the host.
+Each step is a span of the request's `streams.spans.SpanLog`. The
+chunking contract:
 
 * **Bit-parity.** All per-seed grid state is seed-separable (one
   `_SeedStream` per seed, per-seed curves, no cross-seed reductions
@@ -347,15 +349,14 @@ engine, under the thread-local ``jax.enable_x64(True)`` context and never
 a global config flip: the caller's ``jax_enable_x64`` is untouched. Each
 thread that calls a cached run fn enters the context itself (the
 `run_chunks` lane and the `launch.serve.SweepService` workers run
-`run_chunk`, which does), since a fn traced under x64 and called outside
-it would retrace in float32.
+`dispatch` and `fetch`, which do), since a fn traced under x64 and
+called outside it would retrace in float32.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
@@ -380,6 +381,7 @@ from repro.streams.engine import (AUTOSCALE_KEYS, AutoscaleConfig,
                                   lower_tensor_plan, lower_upgrade,
                                   per_task_failover)
 from repro.streams.graph import LogicalGraph, PhysicalGraph, expand
+from repro.streams.spans import SpanLog
 
 class EngineState(NamedTuple):
     """All mutable arena state of one scenario (see module docstring).
@@ -438,6 +440,15 @@ class TickDesc(NamedTuple):
 # ----------------------------------------------------------------------
 # tensorized tick: constant number of segment passes per phase
 # ----------------------------------------------------------------------
+#: named scopes of the dense and compact ticks' steps, in tick order:
+#: alive/capacity set-up, per phase its consumption, routing of the
+#: produced records and their acceptance downstream, then
+#: `_finish_tick`'s failover (with the checkpoint counter), drill
+#: controller, autoscaler and metric rows
+TICK_STEPS = ("tick_setup", "tick_consume", "tick_route", "tick_accept",
+              "tick_failover", "tick_drill", "tick_autoscale", "tick_rows")
+
+
 def _build_compact_run(desc: TickDesc):
     """Sparse-phase twin of `_build_run`: every arena-sized segment
     reduction of the dense tick becomes a row-table gather+reduce over
@@ -447,7 +458,13 @@ def _build_compact_run(desc: TickDesc):
     share one compiled trace. Numerics are pinned to the dense tick:
     consumption stays arena-wide elementwise (bit-identical), rows
     preserve each segment's member order, and pads contribute exact
-    +0.0 to sums and +inf to head-of-line minima."""
+    +0.0 to sums and +inf to head-of-line minima.
+
+    Each step of the tick runs under a `jax.named_scope` (`TICK_STEPS`;
+    the per-phase steps carry the phase index, ``tick_route1``). Scopes
+    are op metadata only, so a device trace can split the scan's time
+    by step while the ops and numerics stay those of the unscoped
+    tick."""
     tp, n_regions = desc.tensor, desc.n_regions
     n_ops, n_jobs = tp.n_ops, tp.n_jobs
 
@@ -458,133 +475,137 @@ def _build_compact_run(desc: TickDesc):
         return jnp.where(mask > 0.5, vals[idx], jnp.inf).min(-1)
 
     def tick(pa, state: EngineState, x):
-        t = x["t"]
-        q = state.queue
-        alive_f = ((state.down_until <= t)
-                   & (state.up_until <= t)).astype(q.dtype)
-        # canary-config activation: upgrade wave done, rollback wave (if
-        # fired) not yet begun — inert leaves make this identically zero
-        act = pa["up_cmask"] * ((t >= pa["up_start"] + pa["up_down"])
-                                & (t < state.rb_t + pa["up_rstag"])
-                                ).astype(q.dtype)
-        free = jnp.maximum(pa["qcap"] - q, 0.0)
-        # breaker-open load shed (graceful degradation): ×1.0 exactly
-        # while every breaker is closed — the autoscale-free no-op
-        shed_t = jnp.where(t < state.brk_until, pa["as_shed"], 1.0)
-        sel_t = (pa["sel"][pa["op_of_task"]] + act * pa["d_sel"]) * shed_t
-        ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
-        cap_t = pa["cap_base"] * state.speed * alive_f
-        emitted, dropped = state.emitted, state.dropped
-        produced = jnp.zeros_like(q)
-        qps_acc = jnp.zeros((n_ops,), q.dtype)
-        take_all = jnp.zeros_like(q)
+        with jax.named_scope("tick_setup"):
+            t = x["t"]
+            q = state.queue
+            alive_f = ((state.down_until <= t)
+                       & (state.up_until <= t)).astype(q.dtype)
+            # canary-config activation: upgrade wave done, rollback wave (if
+            # fired) not yet begun — inert leaves make this identically zero
+            act = pa["up_cmask"] * ((t >= pa["up_start"] + pa["up_down"])
+                                    & (t < state.rb_t + pa["up_rstag"])
+                                    ).astype(q.dtype)
+            free = jnp.maximum(pa["qcap"] - q, 0.0)
+            # breaker-open load shed (graceful degradation): ×1.0 exactly
+            # while every breaker is closed — the autoscale-free no-op
+            shed_t = jnp.where(t < state.brk_until, pa["as_shed"], 1.0)
+            sel_t = (pa["sel"][pa["op_of_task"]] + act * pa["d_sel"]) * shed_t
+            ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
+            cap_t = pa["cap_base"] * state.speed * alive_f
+            emitted, dropped = state.emitted, state.dropped
+            produced = jnp.zeros_like(q)
+            qps_acc = jnp.zeros((n_ops,), q.dtype)
+            take_all = jnp.zeros_like(q)
 
-        gate_t = x["gate"][pa["job_of_task"]]  # MQ source gate (0/1)
-        rfac_t = x["rfac"][pa["job_of_task"]]  # traffic-rate factor
+            gate_t = x["gate"][pa["job_of_task"]]  # MQ source gate (0/1)
+            rfac_t = x["rfac"][pa["job_of_task"]]  # traffic-rate factor
         for fi, ph in enumerate(tp.phases):
             eph = pa["edges"][fi]
             if ph.consumes:
-                take = jnp.minimum(q, cap_t * eph["cons_mask"])
-                q = q - take
-                take_all = take_all + take
-                src_emit = (pa["src_row"] * alive_f * eph["cons_mask"]
-                            * gate_t * rfac_t)
-                produced = produced + (src_emit + take * sel_t)
-                if len(ph.e_jobs):
-                    emitted = emitted.at[eph["e_jobs"]].add(
-                        rsum(src_emit, eph["e_idx"], eph["e_mask"]))
-                qps_acc = qps_acc.at[eph["q_ops"]].add(
-                    rsum(take, eph["q_idx"], eph["q_mask"]))
+                with jax.named_scope(f"tick_consume{fi}"):
+                    take = jnp.minimum(q, cap_t * eph["cons_mask"])
+                    q = q - take
+                    take_all = take_all + take
+                    src_emit = (pa["src_row"] * alive_f * eph["cons_mask"]
+                                * gate_t * rfac_t)
+                    produced = produced + (src_emit + take * sel_t)
+                    if len(ph.e_jobs):
+                        emitted = emitted.at[eph["e_jobs"]].add(
+                            rsum(src_emit, eph["e_idx"], eph["e_mask"]))
+                    qps_acc = qps_acc.at[eph["q_ops"]].add(
+                        rsum(take, eph["q_idx"], eph["q_mask"]))
             if not ph.D:
                 continue
-            dst = eph["dst_task"]
-            edge_of = eph["edge_of"]
-            alive_d = alive_f[dst]
-            free_d = free[dst]
-            # per-source-op slot totals — O(live src tasks)
-            tot_slot = rsum(produced, eph["s_idx"], eph["s_mask"])
-            tot_e = tot_slot[eph["slot_of_edge"]]
-            tot_d = tot_e[edge_of]
-            # forward: pointwise src task → dst task
-            arr_fwd = produced[eph["fwd_src"]] * alive_d
-            # rescale family: per-block rate over alive destinations
-            if ph.B:
-                prod_blk = rsum(produced, eph["bs_idx"], eph["bs_mask"])
-                alive_blk = rsum(alive_d * eph["dst_in_blk"],
-                                 eph["br_idx"], eph["br_mask"])
-                has = alive_blk > 0.0
-                rate_blk = jnp.where(
-                    has, prod_blk / jnp.where(has, alive_blk, 1.0), 0.0)
-                arr_blk = jnp.where(eph["dst_in_blk"] > 0.0,
-                                    rate_blk[eph["blk_of"]] * alive_d,
-                                    0.0)
-            else:
-                arr_blk = jnp.zeros_like(alive_d)
-            # weakhash: group mass spread ∝ free capacity (fallback to
-            # alive-uniform when a whole group is down)
-            if ph.G:
-                wh = eph["m_weakhash"] > 0.5
-                grp_of = eph["grp_of"]
-                cap_w = jnp.maximum(free_d, 1e-9) * alive_d
-                alive_eps = alive_d + 1e-9
-                capsum = rsum(jnp.where(wh, cap_w, 0.0), eph["gr_idx"],
-                              eph["gr_mask"])
-                capsum_fb = rsum(jnp.where(wh, alive_eps, 0.0),
-                                 eph["gr_idx"], eph["gr_mask"])
-                fall = capsum <= 0.0
-                cap2 = jnp.where(fall[grp_of], alive_eps, cap_w) * alive_d
-                capsum2 = jnp.where(fall, capsum_fb, capsum)
-                val_wh = cap2 * eph["mass"] / capsum2[grp_of]
-            else:
-                val_wh = jnp.zeros_like(alive_d)
-            # backlog: divert away from congested channels
-            open_ = (free_d > pa["qcap"][dst] * 0.25).astype(q.dtype)
-            val_bk = (jnp.maximum(free_d, 1e-9) * alive_d
-                      * jnp.maximum(open_, 0.05))
-            val_nrm = jnp.where(eph["m_weakhash"] > 0.5, val_wh,
-                                jnp.where(eph["m_backlog"] > 0.5, val_bk,
-                                          alive_d)) * eph["is_norm"]
-            rs = rsum(val_nrm, eph["er_idx"], eph["er_mask"])
-            ratio_e = jnp.where(rs > 0.0, tot_e / rs, 0.0)
-            arr_nrm = val_nrm * ratio_e[edge_of]
-            arriving = jnp.where(
-                eph["m_fwd"] > 0.5, arr_fwd,
-                jnp.where(eph["m_blk"] > 0.5, arr_blk,
-                          jnp.where(eph["m_hash"] > 0.5,
-                                    tot_d * eph["share"], arr_nrm)))
-            dead_s = (alive_d <= 0.0) & (ms_eff[dst] > 0.5)
-            dropped = dropped.at[eph["dj_jobs"]].add(
-                rsum(jnp.where(dead_s, arriving, 0.0), eph["dj_idx"],
-                     eph["dj_mask"]))
-            arriving = jnp.where(dead_s, 0.0, arriving)
-            # acceptance: head-of-line / per-block / adaptive credits
-            live = arriving > 1e-9
-            ratio = jnp.where(live,
-                              free_d / jnp.maximum(arriving, 1e-300),
-                              jnp.inf)
-            lam_e = jnp.minimum(rmin(ratio, eph["er_idx"],
-                                     eph["er_mask"]), 1.0)
-            if ph.B:
-                lam_b = jnp.minimum(rmin(ratio, eph["br_idx"],
-                                         eph["br_mask"]), 1.0)
-                acc_blk = arriving * lam_b[eph["blk_of"]]
-            else:
-                acc_blk = arriving
-            accepted = jnp.where(
-                eph["m_acc_static"] > 0.5, arriving * lam_e[edge_of],
-                jnp.where(eph["m_acc_block"] > 0.5, acc_blk,
-                          jnp.minimum(arriving, free_d)))
-            # overflow re-queues uniformly at each source op (dense-style
-            # broadcast through a small per-slot scatter)
-            ovf_e = rsum(arriving - accepted, eph["er_idx"],
-                         eph["er_mask"])
-            ovf_slot = jax.ops.segment_sum(ovf_e, eph["slot_of_edge"],
-                                           num_segments=len(ph.slot_ops))
-            ovf_op = jnp.zeros((n_ops,), q.dtype).at[eph["slot_ops"]].add(
-                ovf_slot)
-            q = q + (ovf_op / pa["par_of_op"])[pa["op_of_task"]]
-            q = q.at[dst].add(accepted)
-            free = jnp.maximum(free.at[dst].add(-accepted), 0.0)
+            with jax.named_scope(f"tick_route{fi}"):
+                dst = eph["dst_task"]
+                edge_of = eph["edge_of"]
+                alive_d = alive_f[dst]
+                free_d = free[dst]
+                # per-source-op slot totals — O(live src tasks)
+                tot_slot = rsum(produced, eph["s_idx"], eph["s_mask"])
+                tot_e = tot_slot[eph["slot_of_edge"]]
+                tot_d = tot_e[edge_of]
+                # forward: pointwise src task → dst task
+                arr_fwd = produced[eph["fwd_src"]] * alive_d
+                # rescale family: per-block rate over alive destinations
+                if ph.B:
+                    prod_blk = rsum(produced, eph["bs_idx"], eph["bs_mask"])
+                    alive_blk = rsum(alive_d * eph["dst_in_blk"],
+                                     eph["br_idx"], eph["br_mask"])
+                    has = alive_blk > 0.0
+                    rate_blk = jnp.where(
+                        has, prod_blk / jnp.where(has, alive_blk, 1.0), 0.0)
+                    arr_blk = jnp.where(eph["dst_in_blk"] > 0.0,
+                                        rate_blk[eph["blk_of"]] * alive_d,
+                                        0.0)
+                else:
+                    arr_blk = jnp.zeros_like(alive_d)
+                # weakhash: group mass spread ∝ free capacity (fallback to
+                # alive-uniform when a whole group is down)
+                if ph.G:
+                    wh = eph["m_weakhash"] > 0.5
+                    grp_of = eph["grp_of"]
+                    cap_w = jnp.maximum(free_d, 1e-9) * alive_d
+                    alive_eps = alive_d + 1e-9
+                    capsum = rsum(jnp.where(wh, cap_w, 0.0), eph["gr_idx"],
+                                  eph["gr_mask"])
+                    capsum_fb = rsum(jnp.where(wh, alive_eps, 0.0),
+                                     eph["gr_idx"], eph["gr_mask"])
+                    fall = capsum <= 0.0
+                    cap2 = jnp.where(fall[grp_of], alive_eps, cap_w) * alive_d
+                    capsum2 = jnp.where(fall, capsum_fb, capsum)
+                    val_wh = cap2 * eph["mass"] / capsum2[grp_of]
+                else:
+                    val_wh = jnp.zeros_like(alive_d)
+                # backlog: divert away from congested channels
+                open_ = (free_d > pa["qcap"][dst] * 0.25).astype(q.dtype)
+                val_bk = (jnp.maximum(free_d, 1e-9) * alive_d
+                          * jnp.maximum(open_, 0.05))
+                val_nrm = jnp.where(eph["m_weakhash"] > 0.5, val_wh,
+                                    jnp.where(eph["m_backlog"] > 0.5, val_bk,
+                                              alive_d)) * eph["is_norm"]
+                rs = rsum(val_nrm, eph["er_idx"], eph["er_mask"])
+                ratio_e = jnp.where(rs > 0.0, tot_e / rs, 0.0)
+                arr_nrm = val_nrm * ratio_e[edge_of]
+                arriving = jnp.where(
+                    eph["m_fwd"] > 0.5, arr_fwd,
+                    jnp.where(eph["m_blk"] > 0.5, arr_blk,
+                              jnp.where(eph["m_hash"] > 0.5,
+                                        tot_d * eph["share"], arr_nrm)))
+                dead_s = (alive_d <= 0.0) & (ms_eff[dst] > 0.5)
+                dropped = dropped.at[eph["dj_jobs"]].add(
+                    rsum(jnp.where(dead_s, arriving, 0.0), eph["dj_idx"],
+                         eph["dj_mask"]))
+                arriving = jnp.where(dead_s, 0.0, arriving)
+            with jax.named_scope(f"tick_accept{fi}"):
+                # acceptance: head-of-line / per-block / adaptive credits
+                live = arriving > 1e-9
+                ratio = jnp.where(live,
+                                  free_d / jnp.maximum(arriving, 1e-300),
+                                  jnp.inf)
+                lam_e = jnp.minimum(rmin(ratio, eph["er_idx"],
+                                         eph["er_mask"]), 1.0)
+                if ph.B:
+                    lam_b = jnp.minimum(rmin(ratio, eph["br_idx"],
+                                             eph["br_mask"]), 1.0)
+                    acc_blk = arriving * lam_b[eph["blk_of"]]
+                else:
+                    acc_blk = arriving
+                accepted = jnp.where(
+                    eph["m_acc_static"] > 0.5, arriving * lam_e[edge_of],
+                    jnp.where(eph["m_acc_block"] > 0.5, acc_blk,
+                              jnp.minimum(arriving, free_d)))
+                # overflow re-queues uniformly at each source op (dense-style
+                # broadcast through a small per-slot scatter)
+                ovf_e = rsum(arriving - accepted, eph["er_idx"],
+                             eph["er_mask"])
+                ovf_slot = jax.ops.segment_sum(ovf_e, eph["slot_of_edge"],
+                                               num_segments=len(ph.slot_ops))
+                ovf_op = jnp.zeros((n_ops,), q.dtype).at[eph["slot_ops"]].add(
+                    ovf_slot)
+                q = q + (ovf_op / pa["par_of_op"])[pa["op_of_task"]]
+                q = q.at[dst].add(accepted)
+                free = jnp.maximum(free.at[dst].add(-accepted), 0.0)
 
         return _finish_tick(pa, state, x, q, emitted, dropped,
                             qps_acc, n_regions, n_ops, act, take_all)
@@ -608,121 +629,125 @@ def _finish_tick(pa, state, x, q, emitted, dropped, qps_acc,
     hot-standby victims pay switch + staleness replay instead and never
     touch checkpoint storage. Zero vectors reduce to the historical
     region/single downtimes bit-for-bit."""
-    t = x["t"]
-    vict = x["kills"][pa["task_host"]]
-    # active canary slices crash under the canary config: mode masks and
-    # downtimes apply their ``act``-gated deltas (exact no-ops when
-    # inert — adding act * 0.0 and comparing 0/1 masks against 0.5)
-    ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
-    mr_eff = pa["mode_region"] + act * pa["d_mode_r"]
-    mh_eff = pa["mode_hot"] + act * pa["d_mode_h"]
-    hit_s = (vict > 0.0).astype(q.dtype) * (ms_eff > 0.5)
-    reg_hit = jax.ops.segment_max(vict * (mr_eff > 0.5),
-                                  pa["task_region"],
-                                  num_segments=n_regions)
-    hit_r = (reg_hit[pa["task_region"]] > 0.0).astype(q.dtype)
-    hit_h = (vict > 0.0).astype(q.dtype) * (mh_eff > 0.5)
-    extra = ((pa["restore_base"] + act * pa["d_restore"])
-             * x["bfac"][pa["job_of_task"]]
-             + x["ckage"][pa["job_of_task"]] * (1.0 + act * pa["d_ck"])
-             * (pa["replay_rate"] + act * pa["d_replay"])
-             + pa["lazy_extra"])
-    until_s = t + (pa["detect"] + pa["restart_single"]
-                   + act * pa["d_down_s"] + extra)
-    until_r = t + (pa["detect"] + pa["restart_region"]
-                   + act * pa["d_down_r"] + extra)
-    until_h = t + (pa["detect"] + pa["standby_switch"]
-                   + pa["standby_stale"] + act * pa["d_down_h"])
-    down_until = jnp.where(hit_r > 0.0, until_r,
-                           jnp.where(hit_s > 0.0, until_s,
-                                     jnp.where(hit_h > 0.0, until_h,
-                                               state.down_until)))
-    hit_any = jnp.maximum(jnp.maximum(hit_r, hit_s), hit_h)
-    q = jnp.where(hit_any > 0.0, 0.0, q)
+    with jax.named_scope("tick_failover"):
+        t = x["t"]
+        vict = x["kills"][pa["task_host"]]
+        # active canary slices crash under the canary config: mode masks and
+        # downtimes apply their ``act``-gated deltas (exact no-ops when
+        # inert — adding act * 0.0 and comparing 0/1 masks against 0.5)
+        ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
+        mr_eff = pa["mode_region"] + act * pa["d_mode_r"]
+        mh_eff = pa["mode_hot"] + act * pa["d_mode_h"]
+        hit_s = (vict > 0.0).astype(q.dtype) * (ms_eff > 0.5)
+        reg_hit = jax.ops.segment_max(vict * (mr_eff > 0.5),
+                                      pa["task_region"],
+                                      num_segments=n_regions)
+        hit_r = (reg_hit[pa["task_region"]] > 0.0).astype(q.dtype)
+        hit_h = (vict > 0.0).astype(q.dtype) * (mh_eff > 0.5)
+        extra = ((pa["restore_base"] + act * pa["d_restore"])
+                 * x["bfac"][pa["job_of_task"]]
+                 + x["ckage"][pa["job_of_task"]] * (1.0 + act * pa["d_ck"])
+                 * (pa["replay_rate"] + act * pa["d_replay"])
+                 + pa["lazy_extra"])
+        until_s = t + (pa["detect"] + pa["restart_single"]
+                       + act * pa["d_down_s"] + extra)
+        until_r = t + (pa["detect"] + pa["restart_region"]
+                       + act * pa["d_down_r"] + extra)
+        until_h = t + (pa["detect"] + pa["standby_switch"]
+                       + pa["standby_stale"] + act * pa["d_down_h"])
+        down_until = jnp.where(hit_r > 0.0, until_r,
+                               jnp.where(hit_s > 0.0, until_s,
+                                         jnp.where(hit_h > 0.0, until_h,
+                                                   state.down_until)))
+        hit_any = jnp.maximum(jnp.maximum(hit_r, hit_s), hit_h)
+        q = jnp.where(hit_any > 0.0, 0.0, q)
 
-    ckpt_epoch = state.ckpt_epoch + x["ckpt"].astype(jnp.int32)
+        ckpt_epoch = state.ckpt_epoch + x["ckpt"].astype(jnp.int32)
 
-    # drill controller + wave scheduler (same order as the numpy tick:
-    # EWMA update → rollback decision on the UPDATED accumulator → wave
-    # triggers on the UPDATED rollback time). up_rstag is +inf off the
-    # canary slice, so a fired rollback never restarts stable tasks.
-    delta = q @ pa["up_wdelta"]
-    g = (t >= pa["up_t0"]).astype(q.dtype)
-    dacc = state.dacc + g * pa["up_alpha"] * (delta - state.dacc)
-    fire = ((t >= pa["up_t0"]) & (dacc > pa["up_thresh"])
-            & jnp.isinf(state.rb_t))
-    rb_t = jnp.where(fire, t + pa["dt"], state.rb_t)
-    trig_up = ((t <= pa["up_start"])
-               & (pa["up_start"] < t + pa["dt"]))
-    up_until = jnp.maximum(
-        state.up_until,
-        jnp.where(trig_up, pa["up_start"] + pa["up_down"], 0.0))
-    rb_start = rb_t + pa["up_rstag"]
-    trig_rb = (t <= rb_start) & (rb_start < t + pa["dt"])
-    up_until = jnp.maximum(
-        up_until, jnp.where(trig_rb, rb_start + pa["up_down"], 0.0))
+    with jax.named_scope("tick_drill"):
+        # drill controller + wave scheduler (same order as the numpy tick:
+        # EWMA update → rollback decision on the UPDATED accumulator → wave
+        # triggers on the UPDATED rollback time). up_rstag is +inf off the
+        # canary slice, so a fired rollback never restarts stable tasks.
+        delta = q @ pa["up_wdelta"]
+        g = (t >= pa["up_t0"]).astype(q.dtype)
+        dacc = state.dacc + g * pa["up_alpha"] * (delta - state.dacc)
+        fire = ((t >= pa["up_t0"]) & (dacc > pa["up_thresh"])
+                & jnp.isinf(state.rb_t))
+        rb_t = jnp.where(fire, t + pa["dt"], state.rb_t)
+        trig_up = ((t <= pa["up_start"])
+                   & (pa["up_start"] < t + pa["dt"]))
+        up_until = jnp.maximum(
+            state.up_until,
+            jnp.where(trig_up, pa["up_start"] + pa["up_down"], 0.0))
+        rb_start = rb_t + pa["up_rstag"]
+        trig_rb = (t <= rb_start) & (rb_start < t + pa["dt"])
+        up_until = jnp.maximum(
+            up_until, jnp.where(trig_rb, rb_start + pa["up_down"], 0.0))
 
-    # in-trace DS2 autoscaler (end-of-tick, AFTER kills/ckpt/drill —
-    # same order as the numpy tick): utilization EWMA first, breaker
-    # update on this tick's failover hits, then the decision reads the
-    # UPDATED accumulator and UPDATED breaker. Inert autoscale leaves
-    # make every update an exact arithmetic no-op.
-    dt_ = pa["dt"]
-    cap_now = pa["cap_base"] * state.speed
-    need = ((take_all + q * (dt_ / pa["as_drain"]))
-            / jnp.maximum(cap_now, 1e-9))
-    rew = state.rew + pa["as_alpha"] * (need - state.rew)
-    recent = (t - state.lact) <= pa["as_fw"]
-    failev = (hit_any > 0.0) & recent
-    crossed = (((t - state.lact) > pa["as_fw"])
-               & ((t - dt_ - state.lact) <= pa["as_fw"]))
-    failcnt = jnp.where(
-        failev, state.failcnt + 1.0,
-        jnp.where(crossed & (hit_any <= 0.0), 0.0, state.failcnt))
-    brk_fire = failcnt >= pa["as_bfail"]
-    brk_until = jnp.where(brk_fire, t + pa["as_brs"], state.brk_until)
-    failcnt = jnp.where(brk_fire, 0.0, failcnt)
-    boundary = (jnp.floor((t + dt_ - pa["as_t0"]) / pa["as_int"])
-                > jnp.floor((t - pa["as_t0"]) / pa["as_int"]))
-    want = jnp.clip(state.speed * rew / pa["as_tgt"],
-                    pa["as_lo"], pa["as_hi"])
-    rel = jnp.abs(want - state.speed) / jnp.maximum(state.speed, 1e-9)
-    as_fire = (boundary & (pa["as_on"] > 0.0) & (pa["as_mask"] > 0.0)
-               & (rel >= pa["as_hyst"])
-               & ((t - state.lact) >= pa["as_cool"])
-               & (t >= brk_until) & (state.used < pa["as_amax"])
-               & jnp.isinf(state.thrash_t))
-    fire_f = as_fire.astype(q.dtype)
-    speed = jnp.where(as_fire, want, state.speed)
-    lact = jnp.where(as_fire, t, state.lact)
-    # graceful rescale: queues persist, the task pays deploy downtime +
-    # state-move seconds on the up_until leaf
-    downt = pa["as_down"] + pa["as_move"] * jnp.abs(want - state.speed)
-    up_until = jnp.maximum(up_until,
-                           jnp.where(as_fire, t + downt, 0.0))
-    any_fire = (fire_f.sum() > 0.0).astype(q.dtype)
-    used = state.used * pa["as_adec"] + any_fire
-    dirn = jnp.sign(want - state.speed)
-    flip = as_fire & (dirn * state.dirp < 0.0)
-    dirp = jnp.where(as_fire, dirn, state.dirp)
-    flip_acc = (state.flip_acc * pa["as_tdec"]
-                + flip.astype(q.dtype).sum())
-    # thrash latch: freezes the controller from the NEXT tick on (the
-    # fire gate above read the PRE-latch thrash_t)
-    thrash_t = jnp.where((flip_acc >= pa["as_tflip"])
-                         & jnp.isinf(state.thrash_t),
-                         t + dt_, state.thrash_t)
-    nact = state.nact + fire_f.sum()
-    rsec = state.rsec + speed.sum() * dt_
+    with jax.named_scope("tick_autoscale"):
+        # in-trace DS2 autoscaler (end-of-tick, AFTER kills/ckpt/drill —
+        # same order as the numpy tick): utilization EWMA first, breaker
+        # update on this tick's failover hits, then the decision reads the
+        # UPDATED accumulator and UPDATED breaker. Inert autoscale leaves
+        # make every update an exact arithmetic no-op.
+        dt_ = pa["dt"]
+        cap_now = pa["cap_base"] * state.speed
+        need = ((take_all + q * (dt_ / pa["as_drain"]))
+                / jnp.maximum(cap_now, 1e-9))
+        rew = state.rew + pa["as_alpha"] * (need - state.rew)
+        recent = (t - state.lact) <= pa["as_fw"]
+        failev = (hit_any > 0.0) & recent
+        crossed = (((t - state.lact) > pa["as_fw"])
+                   & ((t - dt_ - state.lact) <= pa["as_fw"]))
+        failcnt = jnp.where(
+            failev, state.failcnt + 1.0,
+            jnp.where(crossed & (hit_any <= 0.0), 0.0, state.failcnt))
+        brk_fire = failcnt >= pa["as_bfail"]
+        brk_until = jnp.where(brk_fire, t + pa["as_brs"], state.brk_until)
+        failcnt = jnp.where(brk_fire, 0.0, failcnt)
+        boundary = (jnp.floor((t + dt_ - pa["as_t0"]) / pa["as_int"])
+                    > jnp.floor((t - pa["as_t0"]) / pa["as_int"]))
+        want = jnp.clip(state.speed * rew / pa["as_tgt"],
+                        pa["as_lo"], pa["as_hi"])
+        rel = jnp.abs(want - state.speed) / jnp.maximum(state.speed, 1e-9)
+        as_fire = (boundary & (pa["as_on"] > 0.0) & (pa["as_mask"] > 0.0)
+                   & (rel >= pa["as_hyst"])
+                   & ((t - state.lact) >= pa["as_cool"])
+                   & (t >= brk_until) & (state.used < pa["as_amax"])
+                   & jnp.isinf(state.thrash_t))
+        fire_f = as_fire.astype(q.dtype)
+        speed = jnp.where(as_fire, want, state.speed)
+        lact = jnp.where(as_fire, t, state.lact)
+        # graceful rescale: queues persist, the task pays deploy downtime +
+        # state-move seconds on the up_until leaf
+        downt = pa["as_down"] + pa["as_move"] * jnp.abs(want - state.speed)
+        up_until = jnp.maximum(up_until,
+                               jnp.where(as_fire, t + downt, 0.0))
+        any_fire = (fire_f.sum() > 0.0).astype(q.dtype)
+        used = state.used * pa["as_adec"] + any_fire
+        dirn = jnp.sign(want - state.speed)
+        flip = as_fire & (dirn * state.dirp < 0.0)
+        dirp = jnp.where(as_fire, dirn, state.dirp)
+        flip_acc = (state.flip_acc * pa["as_tdec"]
+                    + flip.astype(q.dtype).sum())
+        # thrash latch: freezes the controller from the NEXT tick on (the
+        # fire gate above read the PRE-latch thrash_t)
+        thrash_t = jnp.where((flip_acc >= pa["as_tflip"])
+                             & jnp.isinf(state.thrash_t),
+                             t + dt_, state.thrash_t)
+        nact = state.nact + fire_f.sum()
+        rsec = state.rsec + speed.sum() * dt_
 
-    backlog_row = jax.ops.segment_sum(q, pa["op_of_task"],
-                                      num_segments=n_ops)
-    qps_row = qps_acc / pa["dt"]
-    lag = jnp.dot(backlog_row, pa["src_mask_ops"])
-    new_state = EngineState(q, down_until, speed, ckpt_epoch,
-                            emitted, dropped, up_until, rb_t, dacc,
-                            rew, lact, dirp, failcnt, brk_until, used,
-                            flip_acc, thrash_t, nact, rsec)
+    with jax.named_scope("tick_rows"):
+        backlog_row = jax.ops.segment_sum(q, pa["op_of_task"],
+                                          num_segments=n_ops)
+        qps_row = qps_acc / pa["dt"]
+        lag = jnp.dot(backlog_row, pa["src_mask_ops"])
+        new_state = EngineState(q, down_until, speed, ckpt_epoch,
+                                emitted, dropped, up_until, rb_t, dacc,
+                                rew, lact, dirp, failcnt, brk_until, used,
+                                flip_acc, thrash_t, nact, rsec)
     return new_state, {"qps": qps_row, "backlog": backlog_row,
                        "lag": lag}
 
@@ -960,119 +985,123 @@ def _build_run(desc: TickDesc):
     seg = jax.ops.segment_sum
 
     def tick(pa, state: EngineState, x):
-        t = x["t"]
-        q = state.queue
-        alive_f = ((state.down_until <= t)
-                   & (state.up_until <= t)).astype(q.dtype)
-        act = pa["up_cmask"] * ((t >= pa["up_start"] + pa["up_down"])
-                                & (t < state.rb_t + pa["up_rstag"])
-                                ).astype(q.dtype)
-        free = jnp.maximum(pa["qcap"] - q, 0.0)
-        shed_t = jnp.where(t < state.brk_until, pa["as_shed"], 1.0)
-        sel_t = (pa["sel"][op_of_task] + act * pa["d_sel"]) * shed_t
-        ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
-        cap_t = pa["cap_base"] * state.speed * alive_f
-        emitted, dropped = state.emitted, state.dropped
-        produced = jnp.zeros_like(q)
-        qps_acc = jnp.zeros((n_ops,), q.dtype)
-        take_all = jnp.zeros_like(q)
+        with jax.named_scope("tick_setup"):
+            t = x["t"]
+            q = state.queue
+            alive_f = ((state.down_until <= t)
+                       & (state.up_until <= t)).astype(q.dtype)
+            act = pa["up_cmask"] * ((t >= pa["up_start"] + pa["up_down"])
+                                    & (t < state.rb_t + pa["up_rstag"])
+                                    ).astype(q.dtype)
+            free = jnp.maximum(pa["qcap"] - q, 0.0)
+            shed_t = jnp.where(t < state.brk_until, pa["as_shed"], 1.0)
+            sel_t = (pa["sel"][op_of_task] + act * pa["d_sel"]) * shed_t
+            ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
+            cap_t = pa["cap_base"] * state.speed * alive_f
+            emitted, dropped = state.emitted, state.dropped
+            produced = jnp.zeros_like(q)
+            qps_acc = jnp.zeros((n_ops,), q.dtype)
+            take_all = jnp.zeros_like(q)
 
-        gate_t = x["gate"][job_of_task]  # MQ source gate (0/1)
-        rfac_t = x["rfac"][job_of_task]  # traffic-rate factor
+            gate_t = x["gate"][job_of_task]  # MQ source gate (0/1)
+            rfac_t = x["rfac"][job_of_task]  # traffic-rate factor
         for fi, ph in enumerate(tp.phases):
             if ph.consumes:
-                take = jnp.minimum(q, cap_t * ph.cons_mask)
-                q = q - take
-                take_all = take_all + take
-                src_emit = (pa["src_row"] * alive_f * ph.cons_mask * is_src
-                            * gate_t * rfac_t)
-                produced = produced + (src_emit + take * sel_t)
-                emitted = emitted + seg(src_emit, job_of_task,
-                                        num_segments=n_jobs)
-                qps_acc = qps_acc + seg(take, op_of_task,
-                                        num_segments=n_ops)
+                with jax.named_scope(f"tick_consume{fi}"):
+                    take = jnp.minimum(q, cap_t * ph.cons_mask)
+                    q = q - take
+                    take_all = take_all + take
+                    src_emit = (pa["src_row"] * alive_f * ph.cons_mask * is_src
+                                * gate_t * rfac_t)
+                    produced = produced + (src_emit + take * sel_t)
+                    emitted = emitted + seg(src_emit, job_of_task,
+                                            num_segments=n_jobs)
+                    qps_acc = qps_acc + seg(take, op_of_task,
+                                            num_segments=n_ops)
             if not ph.D:
                 continue
-            eph = pa["edges"][fi]
-            dst = ph.dst_task
-            alive_d = alive_f[dst]
-            free_d = free[dst]
-            tot_op = seg(produced, op_of_task, num_segments=n_ops)
-            tot_e = tot_op[ph.src_op_of_edge]
-            tot_d = tot_e[ph.edge_of]
-            # forward: pointwise src task → dst task
-            arr_fwd = produced[ph.fwd_src] * alive_d
-            # rescale family: per-block rate = block production over the
-            # block's alive destinations
-            prod_blk = seg(produced[ph.bsrc_task], ph.bsrc_blk,
-                           num_segments=ph.B + 1)
-            alive_blk = seg(alive_d * ph.dst_in_blk, ph.blk_of,
-                            num_segments=ph.B + 1)
-            has = alive_blk > 0.0
-            rate_blk = jnp.where(has,
-                                 prod_blk / jnp.where(has, alive_blk, 1.0),
-                                 0.0)
-            arr_blk = jnp.where(ph.dst_in_blk > 0.0,
-                                rate_blk[ph.blk_of] * alive_d, 0.0)
-            # weakhash: key-group mass spread ∝ free capacity; groups with
-            # zero capacity fall back to alive-uniform spread
-            cap_w = jnp.maximum(free_d, 1e-9) * alive_d
-            alive_eps = alive_d + 1e-9
-            capsum = seg(jnp.where(ph.is_weakhash, cap_w, 0.0), ph.grp_of,
-                         num_segments=ph.G + 1)
-            capsum_fb = seg(jnp.where(ph.is_weakhash, alive_eps, 0.0),
-                            ph.grp_of, num_segments=ph.G + 1)
-            fall = capsum <= 0.0
-            cap2 = jnp.where(fall[ph.grp_of], alive_eps, cap_w) * alive_d
-            capsum2 = jnp.where(fall, capsum_fb, capsum)
-            val_wh = cap2 * eph["mass"] / capsum2[ph.grp_of]
-            # backlog: divert away from congested channels
-            open_ = (free_d > pa["qcap"][dst] * 0.25).astype(q.dtype)
-            val_bk = (jnp.maximum(free_d, 1e-9) * alive_d
-                      * jnp.maximum(open_, 0.05))
-            # normalized all-to-all family (rebalance/weakhash/backlog):
-            # identical weight rows → scale one row to the edge total
-            val_nrm = jnp.where(ph.is_weakhash, val_wh,
-                                jnp.where(ph.is_backlog, val_bk,
-                                          alive_d)) * ph.is_norm
-            rs = seg(val_nrm, ph.edge_of, num_segments=ph.n_edges)
-            ratio_e = jnp.where(rs > 0.0, tot_e / rs, 0.0)
-            arr_nrm = val_nrm * ratio_e[ph.edge_of]
-            arriving = jnp.where(
-                ph.is_fwd, arr_fwd,
-                jnp.where(ph.is_blk, arr_blk,
-                          jnp.where(ph.is_hash, tot_d * eph["share"],
-                                    arr_nrm)))
-            # records routed to a dead single_task-mode task drop
-            # (γ=partial); edges never cross jobs, so the dst job segment
-            # owns the drop
-            dead_s = (alive_d <= 0.0) & (ms_eff[dst] > 0.5)
-            dropped = dropped + seg(jnp.where(dead_s, arriving, 0.0),
-                                    ph.job_of_entry, num_segments=n_jobs)
-            arriving = jnp.where(dead_s, 0.0, arriving)
-            # acceptance: head-of-line (per edge), per block
-            # (group_rescale), or adaptive credits (weakhash/backlog)
-            live = arriving > 1e-9
-            ratio = jnp.where(live,
-                              free_d / jnp.maximum(arriving, 1e-300),
-                              jnp.inf)
-            lam_e = jnp.minimum(
-                jax.ops.segment_min(ratio, ph.edge_of,
-                                    num_segments=ph.n_edges), 1.0)
-            lam_b = jnp.minimum(
-                jax.ops.segment_min(ratio, ph.blk_of,
-                                    num_segments=ph.B + 1), 1.0)
-            accepted = jnp.where(
-                ph.acc_static, arriving * lam_e[ph.edge_of],
-                jnp.where(ph.acc_block, arriving * lam_b[ph.blk_of],
-                          jnp.minimum(arriving, free_d)))
-            # overflow re-queues uniformly at the source op
-            ovf_e = seg(arriving - accepted, ph.edge_of,
-                        num_segments=ph.n_edges)
-            ovf_op = seg(ovf_e, ph.src_op_of_edge, num_segments=n_ops)
-            q = q + (ovf_op / par_of_op)[op_of_task]
-            q = q.at[dst].add(accepted)
-            free = jnp.maximum(free.at[dst].add(-accepted), 0.0)
+            with jax.named_scope(f"tick_route{fi}"):
+                eph = pa["edges"][fi]
+                dst = ph.dst_task
+                alive_d = alive_f[dst]
+                free_d = free[dst]
+                tot_op = seg(produced, op_of_task, num_segments=n_ops)
+                tot_e = tot_op[ph.src_op_of_edge]
+                tot_d = tot_e[ph.edge_of]
+                # forward: pointwise src task → dst task
+                arr_fwd = produced[ph.fwd_src] * alive_d
+                # rescale family: per-block rate = block production over the
+                # block's alive destinations
+                prod_blk = seg(produced[ph.bsrc_task], ph.bsrc_blk,
+                               num_segments=ph.B + 1)
+                alive_blk = seg(alive_d * ph.dst_in_blk, ph.blk_of,
+                                num_segments=ph.B + 1)
+                has = alive_blk > 0.0
+                rate_blk = jnp.where(
+                    has, prod_blk / jnp.where(has, alive_blk, 1.0), 0.0)
+                arr_blk = jnp.where(ph.dst_in_blk > 0.0,
+                                    rate_blk[ph.blk_of] * alive_d, 0.0)
+                # weakhash: key-group mass spread ∝ free capacity;
+                # groups with zero capacity fall back to alive-uniform
+                # spread
+                cap_w = jnp.maximum(free_d, 1e-9) * alive_d
+                alive_eps = alive_d + 1e-9
+                capsum = seg(jnp.where(ph.is_weakhash, cap_w, 0.0), ph.grp_of,
+                             num_segments=ph.G + 1)
+                capsum_fb = seg(jnp.where(ph.is_weakhash, alive_eps, 0.0),
+                                ph.grp_of, num_segments=ph.G + 1)
+                fall = capsum <= 0.0
+                cap2 = jnp.where(fall[ph.grp_of], alive_eps, cap_w) * alive_d
+                capsum2 = jnp.where(fall, capsum_fb, capsum)
+                val_wh = cap2 * eph["mass"] / capsum2[ph.grp_of]
+                # backlog: divert away from congested channels
+                open_ = (free_d > pa["qcap"][dst] * 0.25).astype(q.dtype)
+                val_bk = (jnp.maximum(free_d, 1e-9) * alive_d
+                          * jnp.maximum(open_, 0.05))
+                # normalized all-to-all family (rebalance/weakhash/backlog):
+                # identical weight rows → scale one row to the edge total
+                val_nrm = jnp.where(ph.is_weakhash, val_wh,
+                                    jnp.where(ph.is_backlog, val_bk,
+                                              alive_d)) * ph.is_norm
+                rs = seg(val_nrm, ph.edge_of, num_segments=ph.n_edges)
+                ratio_e = jnp.where(rs > 0.0, tot_e / rs, 0.0)
+                arr_nrm = val_nrm * ratio_e[ph.edge_of]
+                arriving = jnp.where(
+                    ph.is_fwd, arr_fwd,
+                    jnp.where(ph.is_blk, arr_blk,
+                              jnp.where(ph.is_hash, tot_d * eph["share"],
+                                        arr_nrm)))
+                # records routed to a dead single_task-mode task drop
+                # (γ=partial); edges never cross jobs, so the dst job segment
+                # owns the drop
+                dead_s = (alive_d <= 0.0) & (ms_eff[dst] > 0.5)
+                dropped = dropped + seg(jnp.where(dead_s, arriving, 0.0),
+                                        ph.job_of_entry, num_segments=n_jobs)
+                arriving = jnp.where(dead_s, 0.0, arriving)
+            with jax.named_scope(f"tick_accept{fi}"):
+                # acceptance: head-of-line (per edge), per block
+                # (group_rescale), or adaptive credits (weakhash/backlog)
+                live = arriving > 1e-9
+                ratio = jnp.where(live,
+                                  free_d / jnp.maximum(arriving, 1e-300),
+                                  jnp.inf)
+                lam_e = jnp.minimum(
+                    jax.ops.segment_min(ratio, ph.edge_of,
+                                        num_segments=ph.n_edges), 1.0)
+                lam_b = jnp.minimum(
+                    jax.ops.segment_min(ratio, ph.blk_of,
+                                        num_segments=ph.B + 1), 1.0)
+                accepted = jnp.where(
+                    ph.acc_static, arriving * lam_e[ph.edge_of],
+                    jnp.where(ph.acc_block, arriving * lam_b[ph.blk_of],
+                              jnp.minimum(arriving, free_d)))
+                # overflow re-queues uniformly at the source op
+                ovf_e = seg(arriving - accepted, ph.edge_of,
+                            num_segments=ph.n_edges)
+                ovf_op = seg(ovf_e, ph.src_op_of_edge, num_segments=n_ops)
+                q = q + (ovf_op / par_of_op)[op_of_task]
+                q = q.at[dst].add(accepted)
+                free = jnp.maximum(free.at[dst].add(-accepted), 0.0)
 
         # pregenerated chaos host kills → failover, ckpt counter, metric
         # rows (shared with the compact tick)
@@ -2219,37 +2248,54 @@ class ChunkResult:
     """One seed-chunk's worth of a chunked run: the half-open seed range
     ``[seed_lo, seed_hi)``, its metrics (`JaxBatchMetrics` for seed
     plans; a per-config list — or mixes×configs nest — for grid plans),
-    and the host-prep / device wall split."""
+    and its times from its spans: host prep (``prep_s``), the device
+    wait alone (``device_s``), the device→host copy of the history and
+    final state (``fetch_s``), and the bytes that copy fetched
+    (``history_bytes``)."""
 
-    __slots__ = ("seed_lo", "seed_hi", "batches", "prep_s", "device_s")
+    __slots__ = ("seed_lo", "seed_hi", "batches", "prep_s", "device_s",
+                 "fetch_s", "history_bytes")
 
-    def __init__(self, seed_lo, seed_hi, batches, prep_s, device_s):
+    def __init__(self, seed_lo, seed_hi, batches, prep_s, device_s,
+                 fetch_s, history_bytes):
         self.seed_lo = seed_lo
         self.seed_hi = seed_hi
         self.batches = batches
         self.prep_s = prep_s
         self.device_s = device_s
+        self.fetch_s = fetch_s
+        self.history_bytes = history_bytes
 
 
-def run_chunks(plan, chunk_size: int | None = None, on_chunk=None
-               ) -> list[ChunkResult]:
+def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
+               spans: SpanLog | None = None) -> list[ChunkResult]:
     """Execute a `SeedBatchPlan`/`ConfigGridPlan` in seed chunks on a
     double-buffered pipeline: host-side timeline prep for chunk k+1 runs
     on the caller thread WHILE chunk k computes on a one-slot device
     lane (XLA releases the GIL for the blocking device call, so the two
     genuinely overlap). `on_chunk` fires with each `ChunkResult` as it
     lands, in seed order — incremental consumers see partial surfaces
-    at time-to-first-chunk instead of time-to-last."""
+    at time-to-first-chunk instead of time-to-last.
+
+    Each chunk records three spans into `spans` (a fresh `SpanLog` when
+    None), and its `ChunkResult` times are theirs: ``sweep.prep`` on the
+    caller thread, ``sweep.device`` (dispatch and the wait for the
+    result) and ``sweep.fetch`` (the copy to the host, with its bytes)
+    on the lane."""
+    spans = SpanLog() if spans is None else spans
     n_seeds = plan.n_seeds
     size = n_seeds if not chunk_size else max(1, int(chunk_size))
     bounds = [(lo, min(lo + size, n_seeds))
               for lo in range(0, n_seeds, size)]
 
-    def _run(prepped, prep_s):
-        t0 = time.perf_counter()
-        batches = plan.run_chunk(prepped)
+    def _run(k, prepped, prep_s):
+        with spans.span("sweep.device", chunk=k) as dev:
+            out = plan.dispatch(prepped)
+        with spans.span("sweep.fetch", chunk=k) as fetch:
+            batches, nbytes = plan.fetch(prepped, out)
+            fetch.count(bytes=nbytes)
         return ChunkResult(prepped[0], prepped[1], batches, prep_s,
-                           time.perf_counter() - t0)
+                           dev.seconds, fetch.seconds, nbytes)
 
     out: list[ChunkResult] = []
 
@@ -2261,15 +2307,31 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None
 
     with ThreadPoolExecutor(max_workers=1) as lane:
         fut = None
-        for lo, hi in bounds:
-            t0 = time.perf_counter()
-            prepped = plan.prep_chunk(lo, hi)
-            prep_s = time.perf_counter() - t0
+        for k, (lo, hi) in enumerate(bounds):
+            with spans.span("sweep.prep", chunk=k) as prep:
+                prepped = plan.prep_chunk(lo, hi)
             if fut is not None:
                 _land(fut)          # chunk k lands while k+1 is prepped
-            fut = lane.submit(_run, prepped, prep_s)
+            fut = lane.submit(_run, k, prepped, prep.seconds)
         _land(fut)
     return out
+
+
+#: final-state leaves a chunk copies back beside its (qps, backlog,
+#: lag) history
+_FETCHED_FINAL = ("emitted", "dropped", "ckpt_epoch", "rb_t",
+                  "thrash_t", "nact", "rsec")
+
+
+def _fetch(out) -> tuple[dict, int]:
+    """Copy one device pass's history and fetched final-state leaves to
+    the host; returns them by name with the bytes copied."""
+    final, ys = out
+    with jax.enable_x64(True):
+        host = {k: np.asarray(ys[k]) for k in ("qps", "backlog", "lag")}
+        host.update((k, np.asarray(getattr(final, k)))
+                    for k in _FETCHED_FINAL)
+    return host, sum(a.nbytes for a in host.values())
 
 
 def concat_batches(parts: list[JaxBatchMetrics]) -> JaxBatchMetrics:
@@ -2299,24 +2361,13 @@ def concat_batches(parts: list[JaxBatchMetrics]) -> JaxBatchMetrics:
         n_rescale=cat("n_rescale"), resource_s=cat("resource_s"))
 
 
-def _fill_timing(timing: dict, chunks: list[ChunkResult], plan) -> None:
-    """Record the prep/device wall split, per-request cache traffic and
-    the resolved tick lowering of a chunked run into the caller-supplied
-    `timing` dict."""
-    timing["phase_mode"] = plan.low.tensor.mode
-    timing["prep_s"] = sum(c.prep_s for c in chunks)
-    timing["device_s"] = sum(c.device_s for c in chunks)
-    timing["chunks"] = len(chunks)
-    timing["cache_hits"] = plan.cache_info["hits"]
-    timing["cache_misses"] = plan.cache_info["misses"]
-
-
 class SeedBatchPlan:
     """Chunk-friendly decomposition of `run_batch`: `__init__` does all
     seed-count-independent work (lowering, trace-cache lookup — cache
     traffic lands in `cache_info`), `prep_chunk(lo, hi)` builds the
-    host-side tensors for a seed slice, `run_chunk` runs one device
-    pass. Driven by `run_chunks`."""
+    host-side tensors for a seed slice, `dispatch` runs one device pass
+    and `fetch` copies its result to the host. Driven by
+    `run_chunks`."""
 
     def __init__(self, graph: LogicalGraph | PackedArena, seeds, *,
                  duration_s: float, base_spec: ChaosSpec | None = None,
@@ -2357,29 +2408,26 @@ class SeedBatchPlan:
                                      self.pad_seeds, self.n_shards)
         return (lo, hi, batch_state, xs, tls)
 
-    def run_chunk(self, prepped) -> JaxBatchMetrics:
-        lo, hi, batch_state, xs, tls = prepped
-        n = hi - lo
-        low = self.low
+    def dispatch(self, prepped):
+        _, _, batch_state, xs, _ = prepped
         with jax.enable_x64(True):
-            final, ys = self.fn(low.arrays, batch_state, xs)
-            qps = np.asarray(ys["qps"])[:n]
-            backlog = np.asarray(ys["backlog"])[:n]
-            lag = np.asarray(ys["lag"])[:n]
-            emitted = np.asarray(final.emitted)[:n]
-            dropped = np.asarray(final.dropped)[:n]
-            ckpt_epoch = np.asarray(final.ckpt_epoch)[:n]
-            rollback_t = np.asarray(final.rb_t)[:n]
-            thrash_t = np.asarray(final.thrash_t)[:n]
-            n_rescale = np.asarray(final.nact)[:n]
-            resource_s = np.asarray(final.rsec)[:n]
-        return JaxBatchMetrics(low.op_names, tls[0].ts, lag, qps, backlog,
-                               emitted, dropped, tls,
-                               ckpt_epoch=ckpt_epoch,
+            return jax.block_until_ready(
+                self.fn(self.low.arrays, batch_state, xs))
+
+    def fetch(self, prepped, out) -> tuple[JaxBatchMetrics, int]:
+        lo, hi, _, _, tls = prepped
+        h, nbytes = _fetch(out)
+        h = {k: v[:hi - lo] for k, v in h.items()}
+        low = self.low
+        return JaxBatchMetrics(low.op_names, tls[0].ts, h["lag"], h["qps"],
+                               h["backlog"], h["emitted"], h["dropped"],
+                               tls, ckpt_epoch=h["ckpt_epoch"],
                                jobs=(low.arena.jobs
                                      if low.arena is not None else None),
-                               rollback_t=rollback_t, thrash_t=thrash_t,
-                               n_rescale=n_rescale, resource_s=resource_s)
+                               rollback_t=h["rb_t"],
+                               thrash_t=h["thrash_t"],
+                               n_rescale=h["nact"],
+                               resource_s=h["rsec"]), nbytes
 
 
 def run_batch(graph: LogicalGraph | PackedArena, seeds, *,
@@ -2395,8 +2443,7 @@ def run_batch(graph: LogicalGraph | PackedArena, seeds, *,
               upgrade: UpgradeConfig | None = None,
               autoscale: AutoscaleConfig | None = None,
               seed_chunk: int | None = None,
-              on_chunk=None,
-              timing: dict | None = None
+              on_chunk=None
               ) -> JaxBatchMetrics:
     """Run a ``(S,)`` batch of chaos scenarios as ONE vmapped `jit` call
     (one call *per device shard* when `devices` is set).
@@ -2420,9 +2467,8 @@ def run_batch(graph: LogicalGraph | PackedArena, seeds, *,
     the double-buffered `run_chunks` pipeline (host prep for chunk k+1
     overlaps device compute for chunk k); the concatenated result is
     bit-identical to the monolithic call. ``on_chunk`` fires with each
-    `ChunkResult` as it lands; ``timing``, if given a dict, receives the
-    ``prep_s`` / ``device_s`` wall split plus per-request trace-cache
-    ``cache_hits`` / ``cache_misses``.
+    `ChunkResult` as it lands, carrying the chunk's prep / device /
+    fetch times.
     """
     plan = SeedBatchPlan(graph, seeds, duration_s=duration_s,
                          base_spec=base_spec, n_hosts=n_hosts, dt=dt,
@@ -2433,8 +2479,6 @@ def run_batch(graph: LogicalGraph | PackedArena, seeds, *,
                          phase_mode=phase_mode, upgrade=upgrade,
                          autoscale=autoscale)
     chunks = run_chunks(plan, seed_chunk, on_chunk)
-    if timing is not None:
-        _fill_timing(timing, chunks, plan)
     return concat_batches([c.batches for c in chunks])
 
 
@@ -2623,9 +2667,9 @@ class ConfigGridPlan:
     `cache_info`). `prep_chunk(lo, hi)` builds the host tensors for the
     seed slice ``[lo, hi)`` — each seed's timelines are built exactly
     once across all chunks, so `timeline_build_count()` matches the
-    monolithic call — and `run_chunk` runs one device pass, returning
-    the per-config `JaxBatchMetrics` list for that slice. Driven by
-    `run_chunks`."""
+    monolithic call — `dispatch` runs one device pass, and `fetch`
+    copies it to the host as the per-config `JaxBatchMetrics` list for
+    that slice. Driven by `run_chunks`."""
 
     def __init__(self, graph: LogicalGraph | PackedArena, configs,
                  seeds, *, duration_s: float,
@@ -2854,40 +2898,35 @@ class ConfigGridPlan:
                        "bfac": 1, "gate": 0, "ckage": 1, "rfac": 1})
         return (lo, hi, batch_state, xs, tls)
 
-    def run_chunk(self, prepped):
-        lo, hi, batch_state, xs, tls = prepped
-        n = hi - lo
-        low, mixes = self.low, self.mixes
+    def dispatch(self, prepped):
+        _, _, batch_state, xs, _ = prepped
         with jax.enable_x64(True):
-            final, ys = self.fn(self.pa, batch_state, xs)
-            sl = (slice(None),) * (1 if mixes is None else 2)
-            qps = np.asarray(ys["qps"])[sl + (slice(None, n),)]
-            backlog = np.asarray(ys["backlog"])[sl + (slice(None, n),)]
-            lag = np.asarray(ys["lag"])[sl + (slice(None, n),)]
-            emitted = np.asarray(final.emitted)[sl + (slice(None, n),)]
-            dropped = np.asarray(final.dropped)[sl + (slice(None, n),)]
-            ckpt_ep = np.asarray(
-                final.ckpt_epoch)[sl + (slice(None, n),)]
-            rb = np.asarray(final.rb_t)[sl + (slice(None, n),)]
-            thr = np.asarray(final.thrash_t)[sl + (slice(None, n),)]
-            nre = np.asarray(final.nact)[sl + (slice(None, n),)]
-            rsc = np.asarray(final.rsec)[sl + (slice(None, n),)]
+            return jax.block_until_ready(self.fn(self.pa, batch_state, xs))
+
+    def fetch(self, prepped, out) -> tuple[list, int]:
+        lo, hi, _, _, tls = prepped
+        low, mixes = self.low, self.mixes
+        h, nbytes = _fetch(out)
+        sl = (slice(None),) * (1 if mixes is None else 2)
+        h = {k: v[sl + (slice(None, hi - lo),)] for k, v in h.items()}
 
         def _metrics(c, pre=()):
             ix = pre + (c,)
             return JaxBatchMetrics(low.op_names, tls[0][0].ts,
-                                   lag[ix], qps[ix], backlog[ix],
-                                   emitted[ix], dropped[ix], tls[c],
-                                   ckpt_epoch=ckpt_ep[ix],
+                                   h["lag"][ix], h["qps"][ix],
+                                   h["backlog"][ix], h["emitted"][ix],
+                                   h["dropped"][ix], tls[c],
+                                   ckpt_epoch=h["ckpt_epoch"][ix],
                                    jobs=self.jobs,
-                                   rollback_t=rb[ix], thrash_t=thr[ix],
-                                   n_rescale=nre[ix],
-                                   resource_s=rsc[ix])
+                                   rollback_t=h["rb_t"][ix],
+                                   thrash_t=h["thrash_t"][ix],
+                                   n_rescale=h["nact"][ix],
+                                   resource_s=h["rsec"][ix])
 
         if mixes is None:
-            return [_metrics(c) for c in range(self.n_cfg)]
+            return [_metrics(c) for c in range(self.n_cfg)], nbytes
         return [[_metrics(c, (m,)) for c in range(self.n_cfg)]
-                for m in range(len(mixes))]
+                for m in range(len(mixes))], nbytes
 
 
 def concat_config_batches(parts):
@@ -2914,8 +2953,7 @@ def run_config_batch(graph: LogicalGraph | PackedArena, configs, seeds, *,
                      devices: int | str | None = None,
                      phase_mode: str = "auto",
                      seed_chunk: int | None = None,
-                     on_chunk=None,
-                     timing: dict | None = None):
+                     on_chunk=None):
     """Sweep a ``(C, S)`` grid of resiliency-config × chaos-seed
     scenarios in ONE doubly-vmapped `jit` call — the third vmap axis of
     the engine, over `FailoverConfig`/`CheckpointConfig` grids.
@@ -2937,9 +2975,8 @@ def run_config_batch(graph: LogicalGraph | PackedArena, configs, seeds, *,
     chunks (`timeline_build_count` matches the monolithic call). The
     concatenated grid is bit-identical to the one-pass grid, so
     chunking is purely a memory-ceiling / time-to-first-result knob.
-    ``on_chunk`` fires with each `ChunkResult` as it lands; ``timing``,
-    if given a dict, receives the ``prep_s`` / ``device_s`` wall split
-    plus per-request trace-cache ``cache_hits`` / ``cache_misses``.
+    ``on_chunk`` fires with each `ChunkResult` as it lands, carrying
+    the chunk's prep / device / fetch times.
 
     Returns one `JaxBatchMetrics` per config row — or, with `mixes`, a
     list over mixes of lists over configs.
@@ -2951,6 +2988,4 @@ def run_config_batch(graph: LogicalGraph | PackedArena, configs, seeds, *,
                           seed=seed, pad_seeds=pad_seeds,
                           devices=devices, phase_mode=phase_mode)
     chunks = run_chunks(plan, seed_chunk, on_chunk)
-    if timing is not None:
-        _fill_timing(timing, chunks, plan)
     return concat_config_batches([c.batches for c in chunks])
