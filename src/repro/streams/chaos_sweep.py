@@ -58,8 +58,8 @@ from repro.streams.engine import (AutoscaleConfig, CheckpointConfig,
                                   FailoverConfig, PackedArena,
                                   UpgradeConfig)
 from repro.streams.graph import LogicalGraph
-from repro.streams.jax_engine import (ConfigGridPlan, JaxBatchMetrics,
-                                      SeedBatchPlan, concat_batches,
+from repro.streams.jax_engine import (JaxBatchMetrics, SeedBatchPlan,
+                                      SummaryGridPlan, concat_batches,
                                       concat_config_batches,
                                       normalize_config, run_chunks)
 from repro.streams.spans import SpanLog
@@ -175,7 +175,6 @@ def _recovery_time(ts: np.ndarray, lag: np.ndarray, down_bk: np.ndarray,
 
 
 def summarize(batch: JaxBatchMetrics, seeds, *,
-              graph: LogicalGraph | None = None,
               slo_lag: float | None = None,
               wall_s: float = 0.0, graph_name: str = "",
               duration_s: float = 0.0) -> SweepResult:
@@ -184,20 +183,17 @@ def summarize(batch: JaxBatchMetrics, seeds, *,
     `slo_lag` is the source-lag SLO threshold (records). When None it is
     derived per scenario as 2× the pre-failure steady-state median lag
     (falling back to the whole-run median for failure-free scenarios).
-    `graph` identifies source ops so recovery can watch downstream
-    queues; without it every op's backlog counts as downstream.
+    Recovery watches the batch's downstream backlog (every op but the
+    sources), and the peak backlog is that of its per-tick total; both
+    series come with the batch, full-history or series-only alike.
     """
     ts = batch.t
-    src_names = ({o.name for o in graph.ops if o.is_source}
-                 if graph is not None else set())
-    down_cols = [j for j, n in enumerate(batch.op_names)
-                 if n not in src_names]
     summaries = []
     for i, seed in enumerate(seeds):
         lag = batch.source_lag[i]
         recs = batch.recoveries[i]
         t_fail = recs[0]["t"] if recs else None
-        down_bk = batch.backlog[i][:, down_cols].sum(axis=1)
+        down_bk = batch.down_backlog[i]
         if slo_lag is None:
             pre = lag[ts < t_fail] if t_fail is not None else lag
             steady = float(np.median(pre)) if len(pre) else 0.0
@@ -210,7 +206,7 @@ def summarize(batch: JaxBatchMetrics, seeds, *,
             n_failures=len(recs),
             recovery_time_s=(_recovery_time(ts, lag, down_bk, recs)
                              if recs else 0.0),
-            max_backlog=float(batch.backlog[i].sum(axis=1).max()),
+            max_backlog=float(batch.backlog_total[i].max()),
             max_lag=float(lag.max()),
             slo_threshold=thr,
             slo_violation_ticks=viol,
@@ -303,8 +299,7 @@ def _publish_chunk(on_chunk, index: int, cr, seeds, *, graph, slo_lag,
     chunk_seeds = seeds[cr.seed_lo:cr.seed_hi]
     with spans.span("sweep.summarize", chunk=index,
                     scenarios=len(batches) * len(chunk_seeds)) as sp:
-        results = [summarize(bm, chunk_seeds, graph=graph,
-                             slo_lag=slo_lag,
+        results = [summarize(bm, chunk_seeds, slo_lag=slo_lag,
                              wall_s=cr.device_s + cr.fetch_s,
                              graph_name=graph.name,
                              duration_s=duration_s) for bm in batches]
@@ -332,7 +327,21 @@ def _run_plan(make_plan, seeds, seed_chunk, on_chunk, spans: SpanLog, *,
     with spans.span("sweep.plan") as planned:
         plan = make_plan()
         planned.count(**plan.cache_info)
+    _check_down_cols(plan.low, graph)
     return plan, run_chunks(plan, seed_chunk, publish, spans), planned
+
+
+def _check_down_cols(low, graph: LogicalGraph) -> None:
+    """The batches' downstream backlog leaves out the lowering's source
+    columns; they must be exactly `graph`'s source ops, the columns a
+    summary of `graph` treats as upstream."""
+    src = {o.name for o in graph.ops if o.is_source}
+    down = [j for j, n in enumerate(low.op_names) if n not in src]
+    if down != np.setdiff1d(np.arange(len(low.op_names)),
+                            low.plan.src_cols).tolist():
+        raise AssertionError(
+            f"the lowering's source columns {list(low.plan.src_cols)} "
+            f"are not the source ops of graph {graph.name!r}")
 
 
 def sweep(graph: LogicalGraph | PackedArena, seeds, *,
@@ -388,13 +397,13 @@ def sweep(graph: LogicalGraph | PackedArena, seeds, *,
     with spans.span("sweep.assemble", scenarios=len(seeds)) as asm:
         wall = asm.start - planned.start
         batch = concat_batches([c.batches for c in chunks])
-        res = summarize(batch, seeds, graph=logical, slo_lag=slo_lag,
+        res = summarize(batch, seeds, slo_lag=slo_lag,
                         wall_s=wall, graph_name=logical.name,
                         duration_s=duration_s)
         if isinstance(graph, PackedArena) and batch.jobs:
             res.job_results = {
                 job.name: summarize(batch.job_view(job), seeds,
-                                    graph=job.graph, slo_lag=slo_lag,
+                                    slo_lag=slo_lag,
                                     wall_s=wall, graph_name=job.name,
                                     duration_s=duration_s)
                 for job in batch.jobs}
@@ -591,18 +600,23 @@ def sweep_configs(graph: LogicalGraph | PackedArena, configs, seeds, *,
     the prep / device-wait split + per-request trace-cache traffic
     either way, and ``wall_s`` runs from the plan's start to the last
     chunk's landing; ``spans`` receives the request's spans (module
-    docstring)."""
+    docstring).
+
+    Each device pass copies only what the summaries read
+    (`jax_engine.SummaryGridPlan`), so the rows' batches carry the
+    per-tick lag and backlog series and no per-op ``qps`` / ``backlog``
+    histories; `jax_engine.run_config_batch` returns those."""
     seeds = list(seeds)
     norm = [normalize_config(c) for c in configs]
     logical = graph.graph if isinstance(graph, PackedArena) else graph
     spans = SpanLog() if spans is None else spans
     plan, chunks, planned = _run_plan(
-        lambda: ConfigGridPlan(graph, norm, seeds, base_spec=base_spec,
-                               duration_s=duration_s, n_hosts=n_hosts,
-                               dt=dt, queue_cap=queue_cap,
-                               task_speed_override=task_speed_override,
-                               seed=seed, pad_seeds=pad_seeds,
-                               devices=devices, phase_mode=phase_mode),
+        lambda: SummaryGridPlan(graph, norm, seeds, base_spec=base_spec,
+                                duration_s=duration_s, n_hosts=n_hosts,
+                                dt=dt, queue_cap=queue_cap,
+                                task_speed_override=task_speed_override,
+                                seed=seed, pad_seeds=pad_seeds,
+                                devices=devices, phase_mode=phase_mode),
         seeds, seed_chunk, on_chunk, spans, graph=logical,
         slo_lag=slo_lag, duration_s=duration_s)
     with spans.span("sweep.assemble",
@@ -612,7 +626,7 @@ def sweep_configs(graph: LogicalGraph | PackedArena, configs, seeds, *,
         # each config row gets its share of the one-call wall time, so
         # a row's scenarios_per_s stays comparable with a standalone
         # sweep()
-        results = [summarize(bm, seeds, graph=logical, slo_lag=slo_lag,
+        results = [summarize(bm, seeds, slo_lag=slo_lag,
                              wall_s=wall / len(norm),
                              graph_name=logical.name,
                              duration_s=duration_s)

@@ -309,7 +309,9 @@ selection, trace-cache lookup — all seed-count-independent) plus
 slices, driven by `run_chunks`' double-buffered pipeline: host timeline
 prep for chunk k+1 runs on the caller thread while chunk k's device
 pass blocks on a one-slot executor lane (XLA releases the GIL, so prep
-and compute genuinely overlap), then copies its history to the host.
+and compute genuinely overlap), then copies its history to the host:
+per-op rows, or under `SummaryGridPlan` (the summary sweep's plan) only
+the per-tick lag and backlog series, reduced on the device first.
 Each step is a span of the request's `streams.spans.SpanLog`. The
 chunking contract:
 
@@ -1989,18 +1991,46 @@ class JaxEngineMetrics:
         self.resource_s = float(resource_s)
 
 
+def backlog_series(backlog: np.ndarray, src_cols) -> tuple[np.ndarray,
+                                                          np.ndarray]:
+    """The per-tick backlog series a summary reads, from a ``(..., T,
+    n_ops)`` per-op history on the host: the total over every op, and
+    the downstream backlog over every op that is not among `src_cols`
+    (the plan's source columns)."""
+    down = np.setdiff1d(np.arange(backlog.shape[-1]), src_cols)
+    return backlog.sum(axis=-1), backlog[..., down].sum(axis=-1)
+
+
+@jax.jit
+def device_backlog_series(backlog, down_mask):
+    """`backlog_series` on the device, over a ``(..., T, n_ops)``
+    history still there: `down_mask` is the ``(n_ops,)`` mask of
+    non-source columns. A grid pass's history then reaches the host as
+    two ``(..., T)`` series instead of per-op rows."""
+    return (backlog.sum(axis=-1),
+            jnp.where(down_mask, backlog, 0.0).sum(axis=-1))
+
+
 class JaxBatchMetrics:
     """Stacked metrics of a vmapped seed batch; `row(i)` is identical to
-    a standalone single-seed run (pinned in tests/test_jax_engine.py)."""
+    a standalone single-seed run (pinned in tests/test_jax_engine.py).
+
+    `backlog_total` and `down_backlog` are the ``(S, n_ticks)`` series
+    of `backlog_series`, which is all a summary reads of the backlog. A
+    series-only batch (`SummaryGridPlan`) carries them without the
+    per-op `qps` / `backlog` history, which are then None."""
 
     def __init__(self, op_names, t, lag, qps, backlog, emitted, dropped,
                  timelines, ckpt_epoch=None, jobs=None, rollback_t=None,
-                 thrash_t=None, n_rescale=None, resource_s=None):
+                 thrash_t=None, n_rescale=None, resource_s=None, *,
+                 backlog_total, down_backlog):
         self.op_names = list(op_names)
         self.t = t                     # (n_ticks,)
         self.source_lag = lag          # (S, n_ticks)
-        self.qps = qps                 # (S, n_ticks, n_ops)
-        self.backlog = backlog         # (S, n_ticks, n_ops)
+        self.qps = qps                 # (S, n_ticks, n_ops) or None
+        self.backlog = backlog         # (S, n_ticks, n_ops) or None
+        self.backlog_total = backlog_total    # (S, n_ticks)
+        self.down_backlog = down_backlog      # (S, n_ticks)
         emitted = np.asarray(emitted, float)
         dropped = np.asarray(dropped, float)
         if emitted.ndim == 1:          # legacy (S,) scalar-per-seed form
@@ -2031,7 +2061,15 @@ class JaxBatchMetrics:
     def __len__(self) -> int:
         return len(self.timelines)
 
+    def _require_history(self, what: str) -> None:
+        if self.backlog is None:
+            raise ValueError(
+                f"{what} needs per-op histories, and this batch carries "
+                "only the per-tick backlog series (a summary sweep's "
+                "copy); run_config_batch / run_batch return full rows")
+
     def row(self, i: int) -> JaxEngineMetrics:
+        self._require_history("row()")
         return JaxEngineMetrics(self.op_names, self.t, self.source_lag[i],
                                 self.qps[i], self.backlog[i],
                                 self.emitted_by_job[i],
@@ -2059,21 +2097,25 @@ class JaxBatchMetrics:
         over the job's own sources, per-job emitted/dropped segments, and
         recovery events filtered to the job — shaped exactly like a
         single-job batch so `chaos_sweep.summarize` works per job."""
+        self._require_history("job_view()")
         cols = np.asarray(job.op_cols)
         lag = self.backlog[:, :, np.asarray(job.src_cols)].sum(axis=-1)
+        backlog = self.backlog[:, :, cols]
+        total, down = backlog_series(
+            backlog, np.searchsorted(cols, job.src_cols))
         j = job.index
         tls = [dataclasses.replace(
                    tl, recoveries=[r for r in tl.recoveries
                                    if r.get("job", 0) == j])
                for tl in self.timelines]
         return JaxBatchMetrics(
-            job.op_names, self.t, lag, self.qps[:, :, cols],
-            self.backlog[:, :, cols],
+            job.op_names, self.t, lag, self.qps[:, :, cols], backlog,
             self.emitted_by_job[:, j:j + 1],
             self.dropped_by_job[:, j:j + 1], tls,
             ckpt_epoch=self.ckpt_epoch, rollback_t=self.rollback_t,
             thrash_t=self.thrash_t, n_rescale=self.n_rescale,
-            resource_s=self.resource_s)
+            resource_s=self.resource_s, backlog_total=total,
+            down_backlog=down)
 
 
 # ----------------------------------------------------------------------
@@ -2317,21 +2359,23 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
     return out
 
 
-#: final-state leaves a chunk copies back beside its (qps, backlog,
-#: lag) history
+#: final-state leaves a chunk copies back beside its history
 _FETCHED_FINAL = ("emitted", "dropped", "ckpt_epoch", "rb_t",
                   "thrash_t", "nact", "rsec")
 
 
-def _fetch(out) -> tuple[dict, int]:
-    """Copy one device pass's history and fetched final-state leaves to
-    the host; returns them by name with the bytes copied."""
-    final, ys = out
+def _fetch(final, history: dict) -> tuple[dict, int]:
+    """Copy one device pass's `history` leaves and fetched final-state
+    leaves to the host; returns them by name with the bytes copied."""
     with jax.enable_x64(True):
-        host = {k: np.asarray(ys[k]) for k in ("qps", "backlog", "lag")}
+        host = {k: np.asarray(v) for k, v in history.items()}
         host.update((k, np.asarray(getattr(final, k)))
                     for k in _FETCHED_FINAL)
     return host, sum(a.nbytes for a in host.values())
+
+
+#: the per-op history a full copy fetches
+_HISTORY = ("qps", "backlog", "lag")
 
 
 def concat_batches(parts: list[JaxBatchMetrics]) -> JaxBatchMetrics:
@@ -2358,7 +2402,9 @@ def concat_batches(parts: list[JaxBatchMetrics]) -> JaxBatchMetrics:
         [tl for p in parts for tl in p.timelines],
         ckpt_epoch=cat("ckpt_epoch"), jobs=first.jobs,
         rollback_t=cat("rollback_t"), thrash_t=cat("thrash_t"),
-        n_rescale=cat("n_rescale"), resource_s=cat("resource_s"))
+        n_rescale=cat("n_rescale"), resource_s=cat("resource_s"),
+        backlog_total=cat("backlog_total"),
+        down_backlog=cat("down_backlog"))
 
 
 class SeedBatchPlan:
@@ -2416,9 +2462,11 @@ class SeedBatchPlan:
 
     def fetch(self, prepped, out) -> tuple[JaxBatchMetrics, int]:
         lo, hi, _, _, tls = prepped
-        h, nbytes = _fetch(out)
+        final, ys = out
+        h, nbytes = _fetch(final, {k: ys[k] for k in _HISTORY})
         h = {k: v[:hi - lo] for k, v in h.items()}
         low = self.low
+        total, down = backlog_series(h["backlog"], low.plan.src_cols)
         return JaxBatchMetrics(low.op_names, tls[0].ts, h["lag"], h["qps"],
                                h["backlog"], h["emitted"], h["dropped"],
                                tls, ckpt_epoch=h["ckpt_epoch"],
@@ -2427,7 +2475,9 @@ class SeedBatchPlan:
                                rollback_t=h["rb_t"],
                                thrash_t=h["thrash_t"],
                                n_rescale=h["nact"],
-                               resource_s=h["rsec"]), nbytes
+                               resource_s=h["rsec"],
+                               backlog_total=total,
+                               down_backlog=down), nbytes
 
 
 def run_batch(graph: LogicalGraph | PackedArena, seeds, *,
@@ -2538,13 +2588,15 @@ def run_mix_batch(graph: LogicalGraph | PackedArena, mixes, seeds, *,
         n_rescale = np.asarray(final.nact)[:, :n_seeds]
         resource_s = np.asarray(final.rsec)[:, :n_seeds]
     jobs = low.arena.jobs if low.arena is not None else None
+    total, down = backlog_series(backlog, low.plan.src_cols)
     return [JaxBatchMetrics(low.op_names, tls[0].ts, lag[m], qps[m],
                             backlog[m], emitted[m], dropped[m], tls,
                             ckpt_epoch=ckpt_epoch[m], jobs=jobs,
                             rollback_t=rollback_t[m],
                             thrash_t=thrash_t[m],
                             n_rescale=n_rescale[m],
-                            resource_s=resource_s[m])
+                            resource_s=resource_s[m],
+                            backlog_total=total[m], down_backlog=down[m])
             for m in range(len(mixes))]
 
 
@@ -2903,30 +2955,61 @@ class ConfigGridPlan:
         with jax.enable_x64(True):
             return jax.block_until_ready(self.fn(self.pa, batch_state, xs))
 
+    def _history(self, ys) -> dict:
+        """The device history one pass copies to the host: per-op rows,
+        which `run_config_batch` returns to its caller."""
+        return {k: ys[k] for k in _HISTORY}
+
     def fetch(self, prepped, out) -> tuple[list, int]:
         lo, hi, _, _, tls = prepped
         low, mixes = self.low, self.mixes
-        h, nbytes = _fetch(out)
+        final, ys = out
+        h, nbytes = _fetch(final, self._history(ys))
         sl = (slice(None),) * (1 if mixes is None else 2)
         h = {k: v[sl + (slice(None, hi - lo),)] for k, v in h.items()}
+        if "backlog" in h:
+            h["backlog_total"], h["down_backlog"] = backlog_series(
+                h["backlog"], low.plan.src_cols)
 
         def _metrics(c, pre=()):
-            ix = pre + (c,)
-            return JaxBatchMetrics(low.op_names, tls[0][0].ts,
-                                   h["lag"][ix], h["qps"][ix],
-                                   h["backlog"][ix], h["emitted"][ix],
-                                   h["dropped"][ix], tls[c],
-                                   ckpt_epoch=h["ckpt_epoch"][ix],
+            r = {k: v[pre + (c,)] for k, v in h.items()}
+            return JaxBatchMetrics(low.op_names, tls[0][0].ts, r["lag"],
+                                   r.get("qps"), r.get("backlog"),
+                                   r["emitted"], r["dropped"], tls[c],
+                                   ckpt_epoch=r["ckpt_epoch"],
                                    jobs=self.jobs,
-                                   rollback_t=h["rb_t"][ix],
-                                   thrash_t=h["thrash_t"][ix],
-                                   n_rescale=h["nact"][ix],
-                                   resource_s=h["rsec"][ix])
+                                   rollback_t=r["rb_t"],
+                                   thrash_t=r["thrash_t"],
+                                   n_rescale=r["nact"],
+                                   resource_s=r["rsec"],
+                                   backlog_total=r["backlog_total"],
+                                   down_backlog=r["down_backlog"])
 
         if mixes is None:
             return [_metrics(c) for c in range(self.n_cfg)], nbytes
         return [[_metrics(c, (m,)) for c in range(self.n_cfg)]
                 for m in range(len(mixes))], nbytes
+
+
+class SummaryGridPlan(ConfigGridPlan):
+    """A `ConfigGridPlan` whose passes copy only what a summary reads:
+    the source lag, the per-tick backlog series (`device_backlog_series`,
+    reduced on the device after the pass) and the final-state leaves.
+    Its batches carry no per-op `qps` / `backlog` rows. It runs the same
+    compiled tick as `ConfigGridPlan`; only the copy differs. Driven by
+    `chaos_sweep.sweep_configs`."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.down_mask = np.isin(np.arange(len(self.low.op_names)),
+                                 self.low.plan.src_cols, invert=True)
+
+    def _history(self, ys) -> dict:
+        with jax.enable_x64(True):
+            total, down = device_backlog_series(ys["backlog"],
+                                                self.down_mask)
+        return {"lag": ys["lag"], "backlog_total": total,
+                "down_backlog": down}
 
 
 def concat_config_batches(parts):

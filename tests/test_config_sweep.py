@@ -15,15 +15,19 @@ Pillars:
   a `PackedArena`: disjoint-host packing with per-job configs equals K
   independent runs, each with its own config, in both engines.
 """
+import dataclasses
+import math
+
 import numpy as np
 import pytest
 
 from repro.core.chaos import ChaosEngine, ChaosSpec, refit_failover
 from repro.streams import nexmark
-from repro.streams.chaos_sweep import sweep_configs
+from repro.streams.chaos_sweep import (ScenarioSummary, _chunk_surfaces,
+                                       summarize, sweep_configs)
 from repro.streams.engine import (CheckpointConfig, FailoverConfig,
-                                  StreamEngine, pack_arena)
-from repro.streams.jax_engine import (JaxStreamEngine,
+                                  StreamEngine, UpgradeConfig, pack_arena)
+from repro.streams.jax_engine import (JaxStreamEngine, backlog_series,
                                       get_cached_config_fn,
                                       run_config_batch)
 
@@ -313,3 +317,103 @@ def test_refit_failover_rejects_ckpt_timelines():
     with pytest.raises(ValueError, match="checkpoint-free"):
         refit_failover(tl, task_host=task_host,
                        task_region=np.zeros(8, int))
+
+
+# ----------------------------------------------------------------------
+# sweep_configs copies per-tick backlog series, not per-op histories
+# ----------------------------------------------------------------------
+DRILL_SPEC = ChaosSpec(host_kill_prob_per_s=0.01, zk_down=((10.0, 12.0),))
+DRILL = [{"failover": FailoverConfig(mode="single_task", detect_s=1.0,
+                                     single_restart_s=2.0),
+          "ckpt": CheckpointConfig(interval_s=6.0),
+          "upgrade": UpgradeConfig(t_upgrade_s=8.0, wave_stagger_s=1.0,
+                                   canary_frac=f, rollback_threshold=thr,
+                                   canary_sel_scale=1.5)}
+         for f in (0.5, 1.0) for thr in (math.inf, 50.0)]
+DRILL_SEEDS = list(range(5))
+#: summary fields read off a backlog, lag or flow sum
+FLOWS = {"max_backlog", "max_lag", "slo_threshold", "dropped", "emitted"}
+SERIES_SURFACES = ("recovery_surface", "slo_surface", "lost_surface",
+                   "rollback_surface", "thrash_surface", "rescale_surface",
+                   "cost_surface")
+
+
+@pytest.fixture(scope="module")
+def drill():
+    """One small drill grid (a Q3 + Q11 fleet, two sources in the Q3
+    job) as the summary sweep runs it and as the full-history rows."""
+    fleet = nexmark.drill_fleet(n_jobs=2, parallelism=2, n_hosts=4)
+    kw = dict(base_spec=DRILL_SPEC, duration_s=40.0, seed_chunk=2)
+    cube = sweep_configs(fleet, DRILL, DRILL_SEEDS, **kw)
+    full = run_config_batch(fleet, DRILL, DRILL_SEEDS, **kw)
+    return fleet, cube, full
+
+
+def test_summary_sweep_matches_full_history_rows(drill):
+    fleet, cube, full = drill
+    ref = [summarize(bm, DRILL_SEEDS) for bm in full]
+    assert any(s.recovery_time_s > 0 for r in ref for s in r.summaries)
+    for got, want in zip(cube.results, ref):
+        for a, b in zip(got.summaries, want.summaries):
+            for f in dataclasses.fields(ScenarioSummary):
+                x, y = getattr(a, f.name), getattr(b, f.name)
+                if f.name in FLOWS:
+                    assert x == pytest.approx(y, rel=1e-12, abs=0), f.name
+                else:
+                    assert x == y, f.name
+    surf = _chunk_surfaces(full, ref)
+    for name in SERIES_SURFACES:
+        assert np.array_equal(getattr(cube, name), surf[name]), name
+    np.testing.assert_allclose(cube.backlog_surface,
+                               surf["backlog_surface"], rtol=1e-12, atol=0)
+    assert np.isfinite(cube.rollback_surface).any()
+
+
+def test_full_history_rows_keep_per_op_histories(drill):
+    fleet, cube, full = drill
+    n_ops = len(fleet.graph.ops)
+    for bm, res in zip(full, cube.results):
+        shape = (len(DRILL_SEEDS), len(bm.t), n_ops)
+        assert bm.qps.shape == bm.backlog.shape == shape
+        assert bm.row(0).qps and bm.row(0).backlog
+        total, down = backlog_series(bm.backlog, fleet.plan.src_cols)
+        assert np.array_equal(bm.backlog_total, total)
+        assert np.array_equal(bm.down_backlog, down)
+        np.testing.assert_allclose(res.batch.down_backlog, down,
+                                   rtol=1e-12, atol=0)
+        np.testing.assert_array_equal(res.batch.source_lag, bm.source_lag)
+
+
+def test_series_only_batch_refuses_per_op_rows(drill):
+    fleet, cube, _ = drill
+    bm = cube.results[0].batch
+    assert bm.qps is None and bm.backlog is None
+    assert bm.down_backlog.shape == bm.backlog_total.shape == \
+           bm.source_lag.shape
+    with pytest.raises(ValueError, match="per-op histories"):
+        bm.row(0)
+    with pytest.raises(ValueError, match="per-op histories"):
+        bm.job_view(fleet.jobs[0])
+
+
+def test_summary_copy_moves_under_one_percent_of_the_full_copy():
+    # 160 one-task Q12 jobs: 480 ops, 100 ticks
+    arena = nexmark.q12_arena(n_tasks=480, parallelism=1, n_hosts=16)
+    grid = GRID[:2]
+    kw = dict(base_spec=ChaosSpec(host_kill_prob_per_s=0.004),
+              duration_s=50.0, seed_chunk=2)
+    series, full = [], []
+    cube = sweep_configs(arena, grid, range(2), on_chunk=series.append,
+                         **kw)
+    run_config_batch(arena, grid, range(2), on_chunk=full.append, **kw)
+    (chunk,), (full_chunk,) = series, full
+    c, s, t = len(grid), 2, len(cube.results[0].batch.t)
+    n_ops, n_jobs = len(arena.graph.ops), len(arena.jobs)
+    ckpt = cube.results[0].batch.ckpt_epoch.itemsize
+    # lag, total and downstream backlog; per-job emitted and dropped;
+    # the checkpoint epoch and four f64 scalars per scenario
+    finals = c * s * (2 * n_jobs * 8 + ckpt + 4 * 8)
+    assert chunk.history_bytes == c * s * 3 * t * 8 + finals
+    assert full_chunk.history_bytes == \
+           c * s * (2 * n_ops + 1) * t * 8 + finals
+    assert chunk.history_bytes < 0.01 * full_chunk.history_bytes

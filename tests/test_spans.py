@@ -27,8 +27,8 @@ GRID = [FO, {"failover": FO, "ckpt": CheckpointConfig(interval_s=6.0)},
                        region_restart_s=4.0)]
 LEAVES = {"sweep.plan", "sweep.prep", "sweep.device", "sweep.fetch",
           "sweep.summarize", "sweep.assemble"}
-FETCHED = ("qps", "backlog", "source_lag", "emitted", "dropped",
-           "ckpt_epoch", "rollback_t", "thrash_t", "n_rescale",
+FETCHED = ("source_lag", "backlog_total", "down_backlog", "emitted",
+           "dropped", "ckpt_epoch", "rollback_t", "thrash_t", "n_rescale",
            "resource_s")
 
 

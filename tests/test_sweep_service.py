@@ -64,11 +64,15 @@ def test_chunked_drill_bit_parity(mono, chunk):
         a = np.asarray(getattr(mono_cube.grid, name))
         b = np.asarray(getattr(cube.grid, name))
         assert np.array_equal(a, b), f"{name} drifted at chunk={chunk}"
-    # raw per-config batch rows too, not just the derived surfaces
+    # the copied per-config series too, not just the derived surfaces
     for m_res, c_res in zip(mono_cube.grid.results, cube.grid.results):
         assert np.array_equal(m_res.batch.source_lag,
                               c_res.batch.source_lag)
-        assert np.array_equal(m_res.batch.qps, c_res.batch.qps)
+        for name in ("down_backlog", "backlog_total"):
+            a = getattr(m_res.batch, name)
+            assert a is not None
+            assert np.array_equal(a, getattr(c_res.batch, name)), \
+                f"{name} drifted at chunk={chunk}"
         assert np.array_equal(m_res.batch.ckpt_epoch,
                               c_res.batch.ckpt_epoch)
 

@@ -22,6 +22,7 @@ from repro.launch.serve import SweepService
 from repro.streams import nexmark
 from repro.streams.engine import CheckpointConfig, FailoverConfig
 from repro.streams.jax_engine import (PALLAS_TPU_REFUSAL, ConfigGridPlan,
+                                      device_backlog_series,
                                       run_config_batch, trace_cache_stats)
 
 SPEC = nexmark.ha_drill_spec(burst_t=10.0, brownout=(5.0, 20.0, 4.0),
@@ -81,6 +82,20 @@ def test_config_grid_compiles_for_v5e(one_chip, mode):
     n_ops = len(plan.low.op_names)
     assert mem.output_size_in_bytes >= 2 * 8 * (
         len(CONFIGS) * 4 * plan.n_ticks * n_ops)
+
+
+def test_backlog_series_compiles_for_v5e(one_chip):
+    """The summary copy's reduction at the q12 cell's chunk shapes:
+    (C, S, T, n_ops) = (12, 8, 360, 1248) f64 in, two (C, S, T) out."""
+    c, s, t, n_ops = 12, 8, 360, 1248
+    args = (jax.ShapeDtypeStruct((c, s, t, n_ops), np.float64,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((n_ops,), np.bool_, sharding=one_chip))
+    with jax.enable_x64(True):
+        compiled = device_backlog_series.lower(*args).compile()
+    # two f64 series, in the chip's tiled layout (T padded to 384)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert 2 * c * s * t * 8 <= out < 2 * c * s * 384 * 8 + 1024
 
 
 def test_roofline_peaks_keyed_by_device_kind(topo):
