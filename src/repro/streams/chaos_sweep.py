@@ -237,6 +237,8 @@ class SweepChunk:
     fetch_s: float                 # device→host copy (sweep.fetch)
     summarize_s: float             # summaries + surfaces (sweep.summarize)
     history_bytes: int             # bytes the copy fetched
+    route_entries: int             # entries the pass routed (sweep.device)
+    rollbacks: int                 # scenarios whose rollback fired
     summaries: list[list[ScenarioSummary]]   # [C][S_chunk]
     recovery_surface: np.ndarray   # (C, S_chunk)
     slo_surface: np.ndarray
@@ -304,11 +306,15 @@ def _publish_chunk(on_chunk, index: int, cr, seeds, *, graph, slo_lag,
                              graph_name=graph.name,
                              duration_s=duration_s) for bm in batches]
         surfaces = _chunk_surfaces(batches, results)
+        rollbacks = int(np.isfinite(surfaces["rollback_surface"]).sum())
+        sp.count(rollbacks=rollbacks)
     on_chunk(SweepChunk(index=index, seed_lo=cr.seed_lo,
                         seed_hi=cr.seed_hi, seeds=chunk_seeds,
                         prep_s=cr.prep_s, device_s=cr.device_s,
                         fetch_s=cr.fetch_s, summarize_s=sp.seconds,
                         history_bytes=cr.history_bytes,
+                        route_entries=cr.route_entries,
+                        rollbacks=rollbacks,
                         summaries=[r.summaries for r in results],
                         **surfaces))
 

@@ -2292,14 +2292,15 @@ class ChunkResult:
     plans; a per-config list — or mixes×configs nest — for grid plans),
     and its times from its spans: host prep (``prep_s``), the device
     wait alone (``device_s``), the device→host copy of the history and
-    final state (``fetch_s``), and the bytes that copy fetched
-    (``history_bytes``)."""
+    final state (``fetch_s``), the bytes that copy fetched
+    (``history_bytes``) and the destination entries the pass routed
+    (``route_entries``, the plan's `route_entries`)."""
 
     __slots__ = ("seed_lo", "seed_hi", "batches", "prep_s", "device_s",
-                 "fetch_s", "history_bytes")
+                 "fetch_s", "history_bytes", "route_entries")
 
     def __init__(self, seed_lo, seed_hi, batches, prep_s, device_s,
-                 fetch_s, history_bytes):
+                 fetch_s, history_bytes, route_entries):
         self.seed_lo = seed_lo
         self.seed_hi = seed_hi
         self.batches = batches
@@ -2307,6 +2308,7 @@ class ChunkResult:
         self.device_s = device_s
         self.fetch_s = fetch_s
         self.history_bytes = history_bytes
+        self.route_entries = route_entries
 
 
 def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
@@ -2322,8 +2324,8 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
     Each chunk records three spans into `spans` (a fresh `SpanLog` when
     None), and its `ChunkResult` times are theirs: ``sweep.prep`` on the
     caller thread, ``sweep.device`` (dispatch and the wait for the
-    result) and ``sweep.fetch`` (the copy to the host, with its bytes)
-    on the lane."""
+    result, with the pass's routed entries) and ``sweep.fetch`` (the
+    copy to the host, with its bytes) on the lane."""
     spans = SpanLog() if spans is None else spans
     n_seeds = plan.n_seeds
     size = n_seeds if not chunk_size else max(1, int(chunk_size))
@@ -2331,13 +2333,15 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
               for lo in range(0, n_seeds, size)]
 
     def _run(k, prepped, prep_s):
-        with spans.span("sweep.device", chunk=k) as dev:
+        entries = plan.route_entries(prepped)
+        with spans.span("sweep.device", chunk=k,
+                        route_entries=entries) as dev:
             out = plan.dispatch(prepped)
         with spans.span("sweep.fetch", chunk=k) as fetch:
             batches, nbytes = plan.fetch(prepped, out)
             fetch.count(bytes=nbytes)
         return ChunkResult(prepped[0], prepped[1], batches, prep_s,
-                           dev.seconds, fetch.seconds, nbytes)
+                           dev.seconds, fetch.seconds, nbytes, entries)
 
     out: list[ChunkResult] = []
 
@@ -2357,6 +2361,15 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
             fut = lane.submit(_run, k, prepped, prep.seconds)
         _land(fut)
     return out
+
+
+def _route_entries(low: "_Lowered", n_ticks: int, rows: int,
+                   batch_state: EngineState) -> int:
+    """Destination entries one device pass routes: Σ over the lowering's
+    tick phases of `D`, times ticks, times the pass's scenarios (`rows`
+    per seed, over the seed axis as dispatched, padding included)."""
+    per_tick = sum(int(ph.D) for ph in low.tensor.phases)
+    return per_tick * n_ticks * rows * int(batch_state.emitted.shape[0])
 
 
 #: final-state leaves a chunk copies back beside its history
@@ -2459,6 +2472,9 @@ class SeedBatchPlan:
         with jax.enable_x64(True):
             return jax.block_until_ready(
                 self.fn(self.low.arrays, batch_state, xs))
+
+    def route_entries(self, prepped) -> int:
+        return _route_entries(self.low, self.n_ticks, 1, prepped[2])
 
     def fetch(self, prepped, out) -> tuple[JaxBatchMetrics, int]:
         lo, hi, _, _, tls = prepped
@@ -2954,6 +2970,10 @@ class ConfigGridPlan:
         _, _, batch_state, xs, _ = prepped
         with jax.enable_x64(True):
             return jax.block_until_ready(self.fn(self.pa, batch_state, xs))
+
+    def route_entries(self, prepped) -> int:
+        rows = self.n_cfg * (1 if self.mixes is None else len(self.mixes))
+        return _route_entries(self.low, self.n_ticks, rows, prepped[2])
 
     def _history(self, ys) -> dict:
         """The device history one pass copies to the host: per-op rows,

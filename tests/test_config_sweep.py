@@ -27,9 +27,11 @@ from repro.streams.chaos_sweep import (ScenarioSummary, _chunk_surfaces,
                                        summarize, sweep_configs)
 from repro.streams.engine import (CheckpointConfig, FailoverConfig,
                                   StreamEngine, UpgradeConfig, pack_arena)
-from repro.streams.jax_engine import (JaxStreamEngine, backlog_series,
+from repro.streams.jax_engine import (ConfigGridPlan, JaxStreamEngine,
+                                      backlog_series,
                                       get_cached_config_fn,
                                       run_config_batch)
+from repro.streams.spans import SpanLog
 
 TOL = dict(rtol=1e-12, atol=1e-9)
 KILLS = ((20.0, 2),)
@@ -417,3 +419,29 @@ def test_summary_copy_moves_under_one_percent_of_the_full_copy():
     assert full_chunk.history_bytes == \
            c * s * (2 * n_ops + 1) * t * 8 + finals
     assert chunk.history_bytes < 0.01 * full_chunk.history_bytes
+
+
+def test_chunks_count_routed_entries_and_fired_rollbacks(drill):
+    """Each chunk counts the destination entries its pass routed (Σ over
+    the lowering's phases of D, times ticks, configs and the chunk's
+    seeds) on its ``sweep.device`` span, and the scenarios whose
+    auto-rollback fired on its ``sweep.summarize`` span."""
+    fleet = drill[0]
+    chunks, log = [], SpanLog()
+    sweep_configs(fleet, DRILL, DRILL_SEEDS, base_spec=DRILL_SPEC,
+                  duration_s=40.0, seed_chunk=2, on_chunk=chunks.append,
+                  spans=log)
+    low = ConfigGridPlan(fleet, DRILL, DRILL_SEEDS, base_spec=DRILL_SPEC,
+                         duration_s=40.0).low
+    per_tick = sum(ph.D for ph in low.tensor.phases)
+    assert len(low.tensor.phases) > 2 and per_tick > 0
+    # chunks of 2, 2 and 1 seeds pad to no wider bucket
+    assert [c.route_entries for c in chunks] == [
+        per_tick * 80 * len(DRILL) * (c.seed_hi - c.seed_lo)
+        for c in chunks]
+    assert [s.counts["route_entries"] for s in log.of("sweep.device")] \
+        == [c.route_entries for c in chunks]
+    fired = [int(np.isfinite(c.rollback_surface).sum()) for c in chunks]
+    assert [c.rollbacks for c in chunks] == fired and sum(fired) > 0
+    assert [s.counts["rollbacks"] for s in log.of("sweep.summarize")] \
+        == fired

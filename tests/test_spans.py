@@ -16,7 +16,8 @@ from repro.core.chaos import ChaosSpec
 from repro.launch.serve import SweepService
 from repro.streams import nexmark
 from repro.streams.engine import CheckpointConfig, FailoverConfig
-from repro.streams.jax_engine import TICK_STEPS, ConfigGridPlan
+from repro.streams.jax_engine import (TICK_STEPS, ConfigGridPlan,
+                                      SeedBatchPlan, run_chunks)
 from repro.streams.spans import SpanLog
 
 SPEC = ChaosSpec(host_kill_prob_per_s=0.01, zk_down=((10.0, 12.0),))
@@ -266,3 +267,18 @@ def test_spans_are_annotations_in_a_cpu_trace(tmp_path):
     assert 0 < dur <= found["sweep.request"][2]
     assert math.isclose(dur * 1e-9, log.of("sweep.fetch")[0].seconds,
                         rel_tol=0.5, abs_tol=5e-3)
+
+
+def test_device_span_counts_the_entries_of_the_padded_seed_axis():
+    # 3 seeds in one chunk dispatch as 4 (the next power of two): the
+    # pass routes the padded row too
+    plan = SeedBatchPlan(nexmark.q2(parallelism=2), range(3),
+                         base_spec=SPEC, duration_s=5.0, n_hosts=4,
+                         failover=FO)
+    log = SpanLog()
+    chunk, = run_chunks(plan, None, spans=log)
+    per_tick = sum(ph.D for ph in plan.low.tensor.phases)
+    assert per_tick > 0
+    assert chunk.route_entries == per_tick * plan.n_ticks * 4
+    dev, = log.of("sweep.device")
+    assert dev.counts == {"chunk": 0, "route_entries": chunk.route_entries}
