@@ -311,39 +311,147 @@ def ckpt_age_curve(ts, ok, n_jobs: int) -> np.ndarray:
     return ts[:, None] - last
 
 
+_MODE_CODE = {"none": 0, "region": 1, "single_task": 2, "hot_standby": 3}
+_MODE_NAME = {c: m for m, c in _MODE_CODE.items()}
+
+
+def failover_rows(hit: np.ndarray, job_of_task: np.ndarray | None = None
+                  ) -> tuple:
+    """Group one failover action's `hit` tasks into recovery rows:
+    ``(jobs, tasks, first)`` — the hit jobs in ascending order (None for
+    a single-job run, which has one row), each row's hit-task count, and
+    the task whose downtime the row reports: the job's first hit task,
+    or task 0 of a single-job run."""
+    if job_of_task is None:
+        return None, np.array([np.count_nonzero(hit)]), np.zeros(1, int)
+    idx = np.flatnonzero(hit)
+    jobs, first, counts = np.unique(job_of_task[idx], return_index=True,
+                                    return_counts=True)
+    return jobs, counts, idx[first]
+
+
+class RecoveryLog:
+    """The recovery events of one run as columns, one row per (failover
+    action, hit job) in the order the actions happened: `t` (kill time),
+    `mode` (`_MODE_CODE`), `tasks` (hit tasks), `downtime` (the row's
+    task's, see `failover_rows`) and `job` (None for a single-job run).
+
+    It reads as the list of event dicts of `EngineMetrics.recoveries`:
+    ``len``, indexing, iteration and ``==`` against such a list give
+    what the list gives, from dicts built on first use and kept. Code on
+    the served path reads the columns instead."""
+
+    __slots__ = ("t", "mode", "tasks", "downtime", "job", "_dicts")
+
+    def __init__(self, t=(), mode=(), tasks=(), downtime=(), job=None):
+        self.t = np.asarray(t, float)
+        self.mode = np.asarray(mode, np.int8)
+        self.tasks = np.asarray(tasks, int)
+        self.downtime = np.asarray(downtime, float)
+        self.job = None if job is None else np.asarray(job, int)
+        self._dicts = None
+
+    @classmethod
+    def of(cls, recs) -> "RecoveryLog":
+        """`recs` as a log: a log as it is, a list of event dicts as the
+        log of the same rows."""
+        if isinstance(recs, cls):
+            return recs
+        recs = list(recs)
+        return cls([r["t"] for r in recs],
+                   [_MODE_CODE[r["mode"]] for r in recs],
+                   [r["tasks"] for r in recs],
+                   [r["downtime"] for r in recs],
+                   [r["job"] for r in recs]
+                   if recs and "job" in recs[0] else None)
+
+    def dicts(self) -> list[dict]:
+        """The events as the list of dicts the log stands for."""
+        if self._dicts is None:
+            cols = zip(self.t.tolist(),
+                       [_MODE_NAME[c] for c in self.mode.tolist()],
+                       self.tasks.tolist(), self.downtime.tolist())
+            if self.job is None:
+                self._dicts = [{"t": t, "mode": m, "tasks": n,
+                                "downtime": d} for t, m, n, d in cols]
+            else:
+                self._dicts = [{"t": t, "mode": m, "tasks": n,
+                                "downtime": d, "job": j}
+                               for (t, m, n, d), j
+                               in zip(cols, self.job.tolist())]
+        return self._dicts
+
+    def for_job(self, j: int) -> "RecoveryLog":
+        """The rows of job `j` (every row of a single-job log is job 0's)."""
+        if self.job is None:
+            return self if j == 0 else RecoveryLog()
+        keep = self.job == j
+        return RecoveryLog(self.t[keep], self.mode[keep], self.tasks[keep],
+                           self.downtime[keep], self.job[keep])
+
+    def __len__(self) -> int:
+        return len(self.t)
+
+    def __getitem__(self, i):
+        return self.dicts()[i]
+
+    def __iter__(self):
+        return iter(self.dicts())
+
+    def __eq__(self, other):
+        if isinstance(other, RecoveryLog):
+            other = other.dicts()
+        if not isinstance(other, list):
+            return NotImplemented
+        return self.dicts() == other
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"RecoveryLog({self.dicts()!r})"
+
+
+class _RecoveryRows:
+    """A run's recovery rows as its kills append them; `log()` joins
+    them into one `RecoveryLog` when the timeline is made."""
+
+    def __init__(self, job_of_task: np.ndarray | None):
+        self.single = job_of_task is None
+        self.parts: list[tuple] = []
+
+    def add(self, t: float, code: int, jobs, tasks, downtime) -> None:
+        self.parts.append((t, code, jobs, tasks, downtime))
+
+    def log(self) -> RecoveryLog:
+        if not self.parts:
+            return RecoveryLog(job=None if self.single else ())
+        t, code, jobs, tasks, downtime = zip(*self.parts)
+        n = [len(x) for x in tasks]
+        return RecoveryLog(np.repeat(t, n), np.repeat(code, n),
+                           np.concatenate(tasks), np.concatenate(downtime),
+                           None if self.single else np.concatenate(jobs))
+
+
 def failover_recovery_entries(t: float, mode: str, hit: np.ndarray,
                               downtime,
                               job_of_task: np.ndarray | None = None
                               ) -> list[dict]:
-    """Recovery-event dicts for one failover action over `hit` tasks.
+    """Recovery-event dicts for one failover action over `hit` tasks,
+    the live `StreamEngine`'s form of the rows `failover_rows` groups.
 
     Single-job runs (``job_of_task=None``) keep the historical one-entry
     format. Packed multi-job arenas (`streams.engine.pack_arena`) emit one
     entry per affected job — ascending job id, with a ``"job"`` key — so a
     shared-host kill that downs tasks of several co-located jobs is
     attributable per job. `downtime` may be a scalar or a per-task vector
-    (per-job failover configs): each job's entry reports the downtime of
-    its own hit tasks, which per-job configs keep uniform within a job.
-    Used by both the live `StreamEngine` and the pregenerated timeline so
-    the two stay comparable with ``==``."""
+    (per-job failover configs). The pregenerated timelines record the
+    same rows in a `RecoveryLog`, so the two compare with ``==``."""
+    jobs, tasks, first = failover_rows(hit, job_of_task)
     dt_arr = np.asarray(downtime, dtype=float)
-    if job_of_task is None:
-        d = float(dt_arr.flat[0]) if dt_arr.ndim else float(dt_arr)
-        return [{"t": t, "mode": mode, "tasks": int(hit.sum()),
-                 "downtime": d}]
-
-    # one grouping pass over the hit tasks (in task order): each job's
-    # entry counts its hit tasks and reports its first hit task's downtime
-    jobs, first, counts = np.unique(job_of_task[hit], return_index=True,
-                                    return_counts=True)
-    d_hit = dt_arr[hit] if dt_arr.ndim else None
-    return [{"t": t, "mode": mode, "tasks": int(c),
-             "downtime": float(dt_arr) if d_hit is None else float(d_hit[i]),
-             "job": int(j)}
-            for j, i, c in zip(jobs, first, counts)]
-
-
-_MODE_CODE = {"none": 0, "region": 1, "single_task": 2, "hot_standby": 3}
+    d = dt_arr[first] if dt_arr.ndim else np.full(len(tasks), dt_arr)
+    return RecoveryLog(np.full(len(tasks), t),
+                       np.full(len(tasks), _MODE_CODE[mode]), tasks, d,
+                       jobs).dicts()
 
 
 def failover_mode_codes(failover_mode, n_tasks: int) -> np.ndarray:
@@ -365,40 +473,64 @@ def _per_task(v, n_tasks: int) -> np.ndarray:
     return np.broadcast_to(np.asarray(v, dtype=float), (n_tasks,))
 
 
-def _resolve_failover_tick(t, host, task_host, task_region, mode_codes,
-                           down_s, down_r, down, recoveries, job_of_task,
-                           down_h=None, extra=None):
+class _FailoverTargets:
+    """What a kill of each host downs under one failover-mode assignment:
+    per mode with victims on the host, in the order region-mode victims
+    expand to their regions, then single_task-mode victims restart
+    alone, then hot_standby victims switch to their standby replica —
+    ``(code, hit mask, jobs, tasks, first)`` with the rows of
+    `failover_rows`. It depends on the placement, the codes and the
+    host alone, so each host is worked out once, at its first kill."""
+
+    def __init__(self, task_host, task_region, mode_codes, job_of_task):
+        self.task_host = task_host
+        self.task_region = task_region
+        self.mode_codes = mode_codes
+        self.job_of_task = job_of_task
+        self._by_host: dict[int, list] = {}
+
+    def __call__(self, host: int) -> list:
+        got = self._by_host.get(host)
+        if got is None:
+            got = self._by_host[host] = self._resolve(host)
+        return got
+
+    def _resolve(self, host: int) -> list:
+        victims = self.task_host == host
+        out = []
+        for code in (1, 2, 3):
+            hit = victims & (self.mode_codes == code)
+            if not hit.any():
+                continue
+            if code == 1:
+                hit = np.isin(self.task_region, self.task_region[hit])
+            out.append((code, hit) + failover_rows(hit, self.job_of_task))
+        return out
+
+
+def _resolve_failover_tick(t, host, targets: _FailoverTargets,
+                           down_s, down_r, down, rows: _RecoveryRows, *,
+                           down_h, extra=None):
     """One host kill → failover response (shared by the pregenerated
     timeline, `refit_failover` and — semantically — the live engine's
-    `_fail_host`): region-mode victims expand to their regions, then
-    single_task-mode victims restart alone, then hot_standby victims
-    switch to their standby replica. Entries keep that order when one
-    shared-host kill hits jobs of several modes.
+    `_fail_host`): the hit tasks of each mode `targets` finds on the
+    host go down, and each hit job's row joins `rows`, in the order of
+    `_FailoverTargets`.
 
     `extra` is the per-task passive-restore surcharge at kill time —
     ``restore_base * brownout + ckpt_age * replay_rate + lazy_extra`` —
     added to region/single downtimes (restores re-read the checkpoint);
     hot_standby pays `down_h` (detect + switch + staleness replay) only,
     since the standby never touches checkpoint storage."""
-    victims = task_host == host
-    vr = victims & (mode_codes == 1)
-    if vr.any():
-        hit = np.isin(task_region, task_region[vr])
-        d = down_r if extra is None else down_r + extra
+    for code, hit, jobs, tasks, first in targets(host):
+        if code == 3:
+            d = down_h
+        else:
+            d = down_r if code == 1 else down_s
+            if extra is not None:
+                d = d + extra
         down[hit] = t + d[hit]
-        recoveries.extend(failover_recovery_entries(
-            t, "region", hit, d, job_of_task))
-    vs = victims & (mode_codes == 2)
-    if vs.any():
-        d = down_s if extra is None else down_s + extra
-        down[vs] = t + d[vs]
-        recoveries.extend(failover_recovery_entries(
-            t, "single_task", vs, d, job_of_task))
-    vh = victims & (mode_codes == 3)
-    if vh.any() and down_h is not None:
-        down[vh] = t + down_h[vh]
-        recoveries.extend(failover_recovery_entries(
-            t, "hot_standby", vh, down_h, job_of_task))
+        rows.add(t, code, jobs, tasks, d[first])
 
 
 def run_checkpoint_attempt(eng: ChaosEngine, alive: np.ndarray, *,
@@ -479,7 +611,7 @@ class ChaosTimeline:
     ckpt_attempts: int
     ckpt_success: int
     ckpt_failed: int
-    recoveries: list[dict]     # same dict layout as EngineMetrics.recoveries
+    recoveries: RecoveryLog    # reads as EngineMetrics.recoveries
     # per-job checkpoint counters — populated only when per-job
     # CheckpointConfigs drive the replay ((n_jobs, 3) attempts/success/
     # failed); None for a single shared coordinator
@@ -583,7 +715,9 @@ def build_chaos_timeline(
     ckpt_at = np.zeros(n_ticks, np.int16)
     ckpt_ok = np.zeros(n_ticks, np.int16)
     down = np.zeros(n_tasks)
-    recoveries: list[dict] = []
+    targets = _FailoverTargets(task_host, task_region, mode_codes,
+                               job_of_task)
+    recoveries = _RecoveryRows(job_of_task)
     attempts = success = failed = 0
     if per_job_ckpt:
         n_jobs = int(np.max(job_of_task)) + 1
@@ -615,10 +749,9 @@ def build_chaos_timeline(
                     # scheduled kills are unbounded by n_hosts; a kill of
                     # a hostless id is a no-op (the engine just revives)
                     kills[i, host] = True
-                _resolve_failover_tick(t, host, task_host, task_region,
-                                       mode_codes, down_s, down_r, down,
-                                       recoveries, job_of_task,
-                                       down_h=down_h, extra=extra)
+                _resolve_failover_tick(t, host, targets, down_s, down_r,
+                                       down, recoveries, down_h=down_h,
+                                       extra=extra)
                 eng.revive(host)   # replacement host, as in _fail_host
         if per_job_ckpt:
             for jc in jobs:
@@ -649,8 +782,8 @@ def build_chaos_timeline(
                 last_ok = t
         t = t + dt
     return ChaosTimeline(dt, n_ticks, ts, task_speed, kills, ckpt_at,
-                         ckpt_ok, attempts, success, failed, recoveries,
-                         ckpt_by_job=ckpt_by_job,
+                         ckpt_ok, attempts, success, failed,
+                         recoveries.log(), ckpt_by_job=ckpt_by_job,
                          ckpt_ok_by_job=ckpt_ok_job)
 
 
@@ -745,7 +878,9 @@ def refit_failover(tl: ChaosTimeline, *, task_host: np.ndarray,
     if (mode_codes == 1).any() and tl.kills.any() and task_region is None:
         raise ValueError("region failover refit requires task_region")
     down = np.zeros(n_tasks)
-    recoveries: list[dict] = []
+    targets = _FailoverTargets(task_host, task_region, mode_codes,
+                               job_of_task)
+    recoveries = _RecoveryRows(job_of_task)
     for i in np.nonzero(tl.kills.any(axis=1))[0]:
         t = float(tl.ts[i])
         extra = None
@@ -753,11 +888,10 @@ def refit_failover(tl: ChaosTimeline, *, task_host: np.ndarray,
             bf = brownout_factor_at(ramps, t)
             extra = restore_base * bf + t * replay + lazy_extra
         for host in np.nonzero(tl.kills[i])[0]:
-            _resolve_failover_tick(t, int(host), task_host, task_region,
-                                   mode_codes, down_s, down_r, down,
-                                   recoveries, job_of_task,
-                                   down_h=down_h, extra=extra)
-    return dataclasses.replace(tl, recoveries=recoveries)
+            _resolve_failover_tick(t, int(host), targets, down_s, down_r,
+                                   down, recoveries, down_h=down_h,
+                                   extra=extra)
+    return dataclasses.replace(tl, recoveries=recoveries.log())
 
 
 # ----------------------------------------------------------------------
@@ -877,6 +1011,9 @@ class GridTimelineBuilder:
         self.n_hosts = n_hosts
         self.n_tasks = len(self.task_host)
         self._streams: list[_SeedStream | None] = [None] * len(self.specs)
+        # per config row, each host's failover targets (every chunk's)
+        self._targets: list[_FailoverTargets | None] = [None] * len(
+            self.configs)
         self._counted = False
 
         # tick-start times via the same float accumulation as the replay
@@ -926,10 +1063,11 @@ class GridTimelineBuilder:
             # accounting a one-shot build_grid_timelines call records
             _TIMELINE_STATS["grid_replays"] += len(self.configs)
             self._counted = True
-        return [self._chunk_row(cfg, seed_lo, seed_hi)
-                for cfg in self.configs]
+        return [self._chunk_row(c, seed_lo, seed_hi)
+                for c in range(len(self.configs))]
 
-    def _chunk_row(self, cfg: dict, seed_lo: int, seed_hi: int) -> list:
+    def _chunk_row(self, c: int, seed_lo: int, seed_hi: int) -> list:
+        cfg = self.configs[c]
         n_tasks, n_ticks = self.n_tasks, self.n_ticks
         ts, dt, n_hosts = self.ts, self.dt, self.n_hosts
         task_host, task_region = self.task_host, self.task_region
@@ -938,8 +1076,12 @@ class GridTimelineBuilder:
         scheds = self.scheds[seed_lo:seed_hi]
         probs = self.probs[seed_lo:seed_hi]
         facs = self.facs[seed_lo:seed_hi]
-        mode_codes = failover_mode_codes(cfg.get("failover_mode",
-                                                 "region"), n_tasks)
+        if self._targets[c] is None:
+            self._targets[c] = _FailoverTargets(
+                task_host, task_region,
+                failover_mode_codes(cfg.get("failover_mode", "region"),
+                                    n_tasks), job_of_task)
+        targets = self._targets[c]
         down_s = (_per_task(cfg.get("detect_s", 1.0), n_tasks)
                   + _per_task(cfg.get("single_restart_s", 3.0), n_tasks))
         down_r = (_per_task(cfg.get("detect_s", 1.0), n_tasks)
@@ -965,7 +1107,7 @@ class GridTimelineBuilder:
         down = np.zeros((S, n_tasks))
         last_ok = np.zeros(S)
         kills = np.zeros((S, n_ticks, n_hosts), bool)
-        recs: list[list] = [[] for _ in range(S)]
+        recs = [_RecoveryRows(job_of_task) for _ in range(S)]
         ok_by_seed = np.zeros((S, n_ticks), np.int16)
 
         bounds = att + ([n_ticks - 1] if (not att or att[-1]
@@ -992,9 +1134,8 @@ class GridTimelineBuilder:
                         if host < n_hosts:
                             kills[s, i, host] = True
                         _resolve_failover_tick(
-                            tk, host, task_host, task_region,
-                            mode_codes, down_s, down_r, down[s], recs[s],
-                            job_of_task, down_h=down_h, extra=extra)
+                            tk, host, targets, down_s, down_r, down[s],
+                            recs[s], down_h=down_h, extra=extra)
             prev = b + 1
             if bi >= len(att):
                 continue
@@ -1057,7 +1198,7 @@ class GridTimelineBuilder:
             row.append(ChaosTimeline(
                 dt, n_ticks, ts, streams[s].task_speed, kills[s],
                 ckpt_at.copy(), ok_by_seed[s], n_att, succ,
-                n_att - succ, recs[s], ckpt_by_job=None))
+                n_att - succ, recs[s].log(), ckpt_by_job=None))
         return row
 
 
@@ -1200,7 +1341,9 @@ def build_perjob_chaos_timeline(
     ckpt_at = np.zeros(n_ticks, np.int16)
     ckpt_ok = np.zeros(n_ticks, np.int16)
     down = np.zeros(n_tasks)
-    recoveries: list[dict] = []
+    targets = _FailoverTargets(task_host, task_region, mode_codes,
+                               job_of_task)
+    recoveries = _RecoveryRows(job_of_task)
     attempts = success = failed = 0
     t = 0.0
     for i in range(n_ticks):
@@ -1233,9 +1376,9 @@ def build_perjob_chaos_timeline(
                         if pool < n_hosts:
                             kills[i, pool] = True
                         _resolve_failover_tick(
-                            t, pool, task_host, task_region, mode_codes,
-                            down_s, down_r, down, recoveries, job_of_task,
-                            down_h=down_h, extra=kill_extra())
+                            t, pool, targets, down_s, down_r, down,
+                            recoveries, down_h=down_h,
+                            extra=kill_extra())
                 eng.revive(lh)
         for jc in jobs_ck:
             if t + dt < jc.next_at:
@@ -1252,6 +1395,6 @@ def build_perjob_chaos_timeline(
                 last_ok[jc.job] = t
         t = t + dt
     return ChaosTimeline(dt, n_ticks, ts, task_speed, kills, ckpt_at,
-                         ckpt_ok, attempts, success, failed, recoveries,
-                         ckpt_by_job=ckpt_by_job,
+                         ckpt_ok, attempts, success, failed,
+                         recoveries.log(), ckpt_by_job=ckpt_by_job,
                          ckpt_ok_by_job=ckpt_ok_job)

@@ -53,7 +53,7 @@ import time
 
 import numpy as np
 
-from repro.core.chaos import ChaosSpec
+from repro.core.chaos import ChaosSpec, RecoveryLog
 from repro.streams.engine import (AutoscaleConfig, CheckpointConfig,
                                   FailoverConfig, PackedArena,
                                   UpgradeConfig)
@@ -142,7 +142,7 @@ class SweepResult:
 
 
 def _recovery_time(ts: np.ndarray, lag: np.ndarray, down_bk: np.ndarray,
-                   recs: list[dict]) -> float:
+                   recs) -> float:
     """Time from the first failure until the job is healthy again.
 
     Source lag in this sim is *retained* backlog (sources never re-emit
@@ -150,9 +150,16 @@ def _recovery_time(ts: np.ndarray, lag: np.ndarray, down_bk: np.ndarray,
     would read as never-recovered for any single-task drill. Healthy is
     therefore: the failover outage window has passed, the per-tick lag
     growth is back at its pre-failure level, and downstream queues have
-    drained. inf = still unhealthy at horizon end."""
-    t_fail = recs[0]["t"]
-    outage_end = max(r["t"] + r["downtime"] for r in recs)
+    drained. inf = still unhealthy at horizon end. `recs` is a
+    `RecoveryLog`, or a list of event dicts with ``t`` and
+    ``downtime``."""
+    if isinstance(recs, RecoveryLog):
+        t, down = recs.t, recs.downtime
+    else:
+        t = np.array([r["t"] for r in recs])
+        down = np.array([r["downtime"] for r in recs])
+    t_fail = float(t[0])
+    outage_end = float((t + down).max())
     pre = ts < t_fail
     dlag = np.diff(lag, prepend=lag[:1])
     # lag growth back at its pre-failure level must not read as growth
@@ -191,8 +198,8 @@ def summarize(batch: JaxBatchMetrics, seeds, *,
     summaries = []
     for i, seed in enumerate(seeds):
         lag = batch.source_lag[i]
-        recs = batch.recoveries[i]
-        t_fail = recs[0]["t"] if recs else None
+        recs = RecoveryLog.of(batch.recoveries[i])
+        t_fail = float(recs.t[0]) if len(recs) else None
         down_bk = batch.down_backlog[i]
         if slo_lag is None:
             pre = lag[ts < t_fail] if t_fail is not None else lag
@@ -205,7 +212,7 @@ def summarize(batch: JaxBatchMetrics, seeds, *,
             seed=int(getattr(seed, "seed", seed)),   # ChaosSpec or int
             n_failures=len(recs),
             recovery_time_s=(_recovery_time(ts, lag, down_bk, recs)
-                             if recs else 0.0),
+                             if len(recs) else 0.0),
             max_backlog=float(batch.backlog_total[i].max()),
             max_lag=float(lag.max()),
             slo_threshold=thr,

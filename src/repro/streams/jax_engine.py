@@ -2104,9 +2104,7 @@ class JaxBatchMetrics:
         total, down = backlog_series(
             backlog, np.searchsorted(cols, job.src_cols))
         j = job.index
-        tls = [dataclasses.replace(
-                   tl, recoveries=[r for r in tl.recoveries
-                                   if r.get("job", 0) == j])
+        tls = [dataclasses.replace(tl, recoveries=tl.recoveries.for_job(j))
                for tl in self.timelines]
         return JaxBatchMetrics(
             job.op_names, self.t, lag, self.qps[:, :, cols], backlog,
@@ -2323,9 +2321,10 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
 
     Each chunk records three spans into `spans` (a fresh `SpanLog` when
     None), and its `ChunkResult` times are theirs: ``sweep.prep`` on the
-    caller thread, ``sweep.device`` (dispatch and the wait for the
-    result, with the pass's routed entries) and ``sweep.fetch`` (the
-    copy to the host, with its bytes) on the lane."""
+    caller thread (with the rows of the chunk's recovery logs),
+    ``sweep.device`` (dispatch and the wait for the result, with the
+    pass's routed entries) and ``sweep.fetch`` (the copy to the host,
+    with its bytes) on the lane."""
     spans = SpanLog() if spans is None else spans
     n_seeds = plan.n_seeds
     size = n_seeds if not chunk_size else max(1, int(chunk_size))
@@ -2356,11 +2355,19 @@ def run_chunks(plan, chunk_size: int | None = None, on_chunk=None,
         for k, (lo, hi) in enumerate(bounds):
             with spans.span("sweep.prep", chunk=k) as prep:
                 prepped = plan.prep_chunk(lo, hi)
+                prep.count(recovery_rows=_recovery_rows(prepped[4]))
             if fut is not None:
                 _land(fut)          # chunk k lands while k+1 is prepped
             fut = lane.submit(_run, k, prepped, prep.seconds)
         _land(fut)
     return out
+
+
+def _recovery_rows(tls) -> int:
+    """Rows the recovery logs of a chunk's timelines hold: `tls` is a
+    list of timelines, or one such list per config."""
+    return sum(_recovery_rows(x) if isinstance(x, list)
+               else len(x.recoveries) for x in tls)
 
 
 def _route_entries(low: "_Lowered", n_ticks: int, rows: int,
