@@ -43,7 +43,7 @@ import threading
 import time
 
 REPO = pathlib.Path(__file__).resolve().parent
-sys.path[:0] = [str(REPO / "src"), str(REPO)]
+sys.path.insert(0, str(REPO / "src"))
 
 #: the full-size cell: 10k tasks, C=12 configs x S=32 seeds, 180 s
 FULL = dict(n_tasks=10_000, n_seeds=32, horizon_s=180.0, seed_chunk=8)
@@ -55,6 +55,29 @@ KILL_PROB_PER_S = 1e-4
 #: a config-level brownout tent over the drill's burst (t = 60 s)
 BROWNOUTS = ((), ((40.0, 120.0, 4.0),))
 RTOL = 1e-5
+STATE_BYTES = 8 << 30            # 8 GiB of keyed window state per job
+
+
+def _failovers() -> dict:
+    # single_task passive restore (γ=partial: records routed to the dead
+    # task are dropped → lost work) vs region passive with lazy-load
+    # ready stagger vs hot standby. The 5s region redeploy keeps that
+    # row's downtime dominated by the brownout-inflated restore +
+    # ckpt-age replay terms the cube sweeps.
+    from repro.core.replication import TimingModel
+    from repro.streams.engine import FailoverConfig
+
+    timing = TimingModel()
+    return {
+        "hot_standby": FailoverConfig.from_replication(
+            timing, mode="hot_standby"),
+        "passive": FailoverConfig.from_replication(
+            timing, mode="single_task", state_bytes=STATE_BYTES),
+        "passive_lazy": dataclasses.replace(
+            FailoverConfig.from_replication(timing, mode="region",
+                                            state_bytes=STATE_BYTES),
+            region_restart_s=5.0, lazyload_stagger_s=1.0),
+    }
 
 
 class CompileLog:
@@ -91,7 +114,6 @@ class CompileLog:
 
 def request(n_tasks: int, n_seeds: int, horizon_s: float):
     """(arena, seeds, driver kwargs) of the replication cube request."""
-    from benchmarks.bench_replication import _failovers
     from repro.streams import nexmark
 
     arena = nexmark.q12_arena(n_tasks=n_tasks)

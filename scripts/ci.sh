@@ -1,15 +1,13 @@
 #!/usr/bin/env bash
-# Tier-1 CI: test suite + quick benchmark smoke.
+# Tier-1 CI: test suite, plus one optional smoke run.
 #
-#   scripts/ci.sh                     # non-slow tests + quick benches
+#   scripts/ci.sh                     # non-slow tests
 #   scripts/ci.sh --full              # include the slow multi-device subprocess tests
 #   scripts/ci.sh --sweep-smoke       # also run a 16-seed chaos sweep (vmapped jit, CPU)
 #   scripts/ci.sh --colocation-smoke  # also run a 4-job 16-seed sharded co-location sweep
 #   scripts/ci.sh --config-smoke      # also run a small (seeds × configs) resiliency grid
 #   scripts/ci.sh --sparse-smoke      # also run a sharded config grid through the COMPACT
 #                                     # (sparse-phase) tick over a deep-pipeline arena
-#   scripts/ci.sh --pallas-smoke      # also run a 16-seed sweep through the fused PALLAS
-#                                     # tick (interpreter impl, native kernel-grid batch)
 #   scripts/ci.sh --ha-smoke          # also run the hybrid-replication-vs-checkpoint cube
 #                                     # (brownouts + MQ outage + region burst, compact tick,
 #                                     # non-zero exit on any timeline-rebuild fallback)
@@ -46,9 +44,6 @@ else
   python -m pytest -x -q -m "not slow"
 fi
 
-echo "== quick benchmark smoke =="
-PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" python benchmarks/run.py --quick
-
 if [[ "${1:-}" == "--sweep-smoke" ]]; then
   echo "== chaos-sweep smoke: 16 seeds, one vmapped jit call =="
   python examples/chaos_sweep.py --seeds 16 --duration 60
@@ -69,12 +64,6 @@ if [[ "${1:-}" == "--sparse-smoke" ]]; then
   REPRO_REQUIRE_PHASE_MODE=compact \
     python examples/sparse_sweep.py --jobs 18 --configs 2 --seeds 8 \
       --duration 60 --devices 2 --ckpt
-fi
-
-if [[ "${1:-}" == "--pallas-smoke" ]]; then
-  echo "== pallas smoke: fused-kernel tick, 16 seeds, interpreter impl =="
-  REPRO_REQUIRE_PHASE_MODE=pallas REPRO_KERNEL_IMPL=interpret \
-    python examples/pallas_sweep.py --jobs 6 --seeds 16 --duration 60
 fi
 
 if [[ "${1:-}" == "--ha-smoke" ]]; then

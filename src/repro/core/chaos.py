@@ -574,7 +574,7 @@ def run_checkpoint_attempt(eng: ChaosEngine, alive: np.ndarray, *,
 # host-replay accounting: every build_chaos_timeline call is one full
 # per-tick host replay. Config-grid sweeps must NOT scale this with the
 # grid (`build_grid_timelines` replays per seed, then refits per config
-# with vectorized draws) — benchmarks read the counter to prove it.
+# with vectorized draws) — tests read the counter to prove it.
 _TIMELINE_STATS = {"builds": 0, "grid_replays": 0}
 
 

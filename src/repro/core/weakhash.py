@@ -84,9 +84,11 @@ def weakhash_assign(keys: np.ndarray, n_tasks: int, n_groups: int,
     reproduces the batch assignment array exactly — one chunk IS the
     batch) and the sequential credit semantics (``chunk=1`` degenerates
     to one least-loaded pick per key, i.e. ``sequential=True``
-    key-for-key)."""
+    key-for-key, so it takes the sequential path)."""
     assert n_tasks % n_groups == 0, (n_tasks, n_groups)
     gsz = n_tasks // n_groups
+    if chunk == 1:
+        sequential = True
     if chunk is not None and not sequential:
         assert chunk > 0, chunk
         loads_c = (np.zeros(n_tasks, np.float64) if loads is None

@@ -25,8 +25,8 @@ logits (phase 1 revisits every tile). The optional demand
 to ONE pass: the penalty uses the previous batch's demand plus a running
 histogram of already-processed tiles instead of the exact whole-batch
 histogram — bit-identical to exact when nt == 1, an approximation
-otherwise whose routing-quality delta (load CV vs exact) is recorded in
-results/weakhash_carry_forward.json by benchmarks/bench_weakhash.py.
+otherwise whose routing-quality delta is its load CV against the exact
+kernel's.
 
 VPU-only (no MXU); token tiles are 8×128-aligned.
 """
@@ -129,9 +129,8 @@ def _carry_kernel(logits_ref, keys_ref, prior_ref, idx_ref, pos_ref,
     for nt > 1. With one tile (nt == 1) the running histogram IS the
     full batch histogram, so carry-forward with a zero prior reproduces
     the exact kernel bit-for-bit — the parity anchor in
-    tests/test_kernels.py. Quality impact (routing load CV vs exact) is
-    measured by benchmarks/bench_weakhash.py into
-    results/weakhash_carry_forward.json.
+    tests/test_kernels.py. Its quality cost is the routing load CV
+    against the exact kernel's.
     """
     t = pl.program_id(0)
 

@@ -40,21 +40,6 @@ CHIP_PEAKS = {
 }
 V5E = "TPU v5 lite"
 CHIPS = 256              # single pod
-VMEM_BYTES = 16 * 2**20  # usable VMEM per core (conservative)
-
-
-def choose_block_rows(row_bytes: float, fixed_bytes: float = 0.0,
-                      budget: int = VMEM_BYTES,
-                      max_rows: int = 256) -> int:
-    """Largest pow2 block row count whose VMEM working set
-    (``fixed_bytes + rows × row_bytes``) fits the budget — the generic
-    grid-block sizer for hand-fused kernels (`repro.kernels.tick_phase`
-    sizes its seed-axis blocks with it; the grid-invariant row tables
-    are the fixed residents)."""
-    rows = max_rows
-    while rows > 1 and fixed_bytes + rows * row_bytes > budget:
-        rows //= 2
-    return rows
 
 
 def chip_peaks(kind: str) -> dict:
@@ -71,9 +56,8 @@ def kernel_roofline(flops: float, hbm_bytes: float, kind: str) -> dict:
     """Roofline terms of one compiled function / kernel launch from its
     HLO cost analysis (`launch.hlo_stats.cost_stats`) on the chip
     `kind`: compute and memory seconds under its peaks, arithmetic
-    intensity vs the machine balance, and which side bounds it. Used by
-    benchmarks/bench_compile.py to report per-lowering FLOP/byte
-    alongside jaxpr eqn counts."""
+    intensity vs the machine balance, and which side bounds it: a
+    lowering's FLOP/byte set against the chip it runs on."""
     peaks = chip_peaks(kind)
     compute_s = flops / peaks["bf16_flops"]
     memory_s = hbm_bytes / peaks["hbm_bw"]

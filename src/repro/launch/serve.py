@@ -41,11 +41,8 @@ not re-trace. `SweepService` provides exactly that on top of
   trace annotation, so a trace names the host work between device
   passes. Every time in ``stats`` is read from them; ``queued_s``,
   ``ttfr_s`` and ``wall_s`` run from submission.
-* **Pallas downgrade.** ``phase_mode="pallas"`` + ``devices=`` has no
-  sharded lowering; instead of surfacing the boundary error the service
-  routes the request to a single-device *chunked* plan up front and
-  records the downgrade reason in ``stats["downgrade"]``. On a TPU the
-  pallas tick is refused at `submit` (`jax_engine.check_phase_mode`).
+* **Input check.** A ``phase_mode`` outside `engine.PHASE_MODES`
+  raises `ValueError` at `submit`, before anything is queued.
 * **Persistent compile cache.** Start-up enables JAX's persistent
   compilation cache (`core.hotupdate.enable_persistent_cache`), so a
   restarted service reads back the traces an earlier process compiled.
@@ -77,8 +74,8 @@ import time
 
 from repro.core.hotupdate import enable_persistent_cache
 from repro.streams import chaos_sweep
-from repro.streams.jax_engine import (check_phase_mode, scoped_cache_stats,
-                                      trace_cache_stats)
+from repro.streams.engine import PHASE_MODES
+from repro.streams.jax_engine import scoped_cache_stats, trace_cache_stats
 from repro.streams.spans import SpanLog
 
 #: request kind → driver. Every driver has signature
@@ -127,9 +124,8 @@ class SweepJob:
     (``ttfr_s``) and to the whole result (``wall_s``), both from
     submission, the summed prep / device-wait / history-copy split
     (``prep_s`` / ``device_s`` / ``fetch_s``), the final assembly
-    (``assemble_s``), the resolved tick lowering (``phase_mode``),
-    per-request trace-cache hits/misses and any pallas downgrade
-    reason."""
+    (``assemble_s``), the resolved tick lowering (``phase_mode``) and
+    per-request trace-cache hits/misses."""
 
     def __init__(self, job_id: int, request: SweepRequest):
         self.id = job_id
@@ -141,8 +137,7 @@ class SweepJob:
         self._result = None
         self.spans = SpanLog(request=job_id)
         self.stats: dict = {"state": "queued", "chunks": 0,
-                            "ttfr_s": None, "wall_s": None,
-                            "downgrade": None}
+                            "ttfr_s": None, "wall_s": None}
 
     # -- producer side (service worker) --------------------------------
     def _publish(self, chunk) -> None:
@@ -248,9 +243,12 @@ class SweepService:
             label=label))
 
     def submit_request(self, request: SweepRequest) -> SweepJob:
-        """Enqueue `request`. A phase mode the chip refuses raises here,
-        before anything is queued."""
-        check_phase_mode(request.kwargs.get("phase_mode", "auto"))
+        """Enqueue `request`. A phase mode outside `PHASE_MODES` raises
+        `ValueError` here, before anything is queued."""
+        mode = request.kwargs.get("phase_mode", "auto")
+        if mode not in PHASE_MODES:
+            raise ValueError(
+                f"phase mode must be {'|'.join(PHASE_MODES)}: {mode!r}")
         with self._lock:
             job = SweepJob(next(self._ids), request)
             self._jobs[job.id] = job
@@ -280,24 +278,7 @@ class SweepService:
     def _run(self, job: SweepJob) -> None:
         req = job.request
         kwargs = dict(req.kwargs)
-        seed_chunk = req.seed_chunk
         seeds = list(req.seeds)
-
-        # pallas + devices has no sharded lowering: downgrade to a
-        # single-device chunked plan up front (instead of surfacing
-        # `jax_engine._check_pallas_devices`'s boundary error) and
-        # record why — the chunking bounds per-pass memory, which is
-        # what devices= was presumably for
-        if (kwargs.get("devices") is not None
-                and kwargs.get("phase_mode") == "pallas"):
-            if seed_chunk is None:
-                seed_chunk = max(1, min(16, len(seeds)))
-            job.stats["downgrade"] = (
-                f"pallas phase mode has no devices= sharding (native "
-                f"seed batching); rerouted devices="
-                f"{kwargs['devices']!r} -> single-device chunked plan "
-                f"(seed_chunk={seed_chunk})")
-            kwargs["devices"] = None
 
         job.stats["state"] = "running"
         submitted = job.stats.pop("submitted_s")
@@ -321,7 +302,8 @@ class SweepService:
                 if req.kind == "sweep_configs":
                     args = (req.graph, kwargs.pop("configs"), seeds)
                 with scoped_cache_stats() as counts:
-                    result = KINDS[req.kind](*args, seed_chunk=seed_chunk,
+                    result = KINDS[req.kind](*args,
+                                             seed_chunk=req.seed_chunk,
                                              on_chunk=publish, spans=log,
                                              **kwargs)
             except BaseException as exc:          # noqa: BLE001

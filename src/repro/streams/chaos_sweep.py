@@ -104,7 +104,7 @@ class SweepResult:
     # per-request trace-cache traffic of this sweep's jit-fn lookups
     cache_hits: int = 0
     cache_misses: int = 0
-    # tick lowering the engine resolved ("dense" | "compact" | "pallas")
+    # tick lowering the engine resolved ("dense" | "compact")
     phase_mode: str = ""
 
     @property

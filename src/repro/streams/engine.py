@@ -336,9 +336,10 @@ class AutoscaleConfig:
     lowered: in-trace rollback of a failed resize to the previous
     parallelism (`DS2Scaler.notify_result` keeps that host-side); the
     breaker + load-shed path is the traced graceful-degradation story.
-    Queue capacities stay on the config axis (``qcap_scale``): the
-    pallas lowering packs qcap into static per-run kernel tables, so an
-    in-trace qcap mutation cannot reach the fused kernel."""
+    Queue capacities stay on the config axis (``qcap_scale``): a
+    rescale changes a task's service speed, not its input buffer, and
+    the numpy tick (`StreamEngine`) keeps each task's qcap fixed, so an
+    in-trace qcap mutation would break parity with it."""
     t0_s: float = 0.0
     interval_s: float = 5.0
     ewma_alpha: float = 0.35
@@ -1053,14 +1054,19 @@ def _phase_work_estimate(plan: RoutingPlan, cphase, edges, n_phases):
     return dense, compact
 
 
+#: the tick lowerings an entry point's ``phase_mode`` may name: the
+#: dense parity reference, the compact row-table tick, or the choice
+#: between them by `select_phase_mode`'s work estimate
+PHASE_MODES = ("dense", "compact", "auto")
+
+
 def select_phase_mode(plan: RoutingPlan, mode: str = "auto",
                       seed_width: int = 1) -> str:
     """Resolve a ``"auto"`` phase-lowering request: compact when the
     arena-sized segment reductions the sparse lowering eliminates
     clearly dominate its row-gather cost (deep pipelines / big
     multi-job arenas where each phase touches a small slice of the
-    arena), dense otherwise. ``"pallas"`` (the fused-kernel lowering)
-    is never auto-selected — request it explicitly.
+    arena), dense otherwise. ``mode`` must be one of `PHASE_MODES`.
 
     ``seed_width`` is the seed-axis batch width the tick will run
     under (S, or S·C for config grids). The work estimate scores one
@@ -1073,11 +1079,11 @@ def select_phase_mode(plan: RoutingPlan, mode: str = "auto",
     toward the asymptotic ~2.1x row-gather penalty — wide batches
     over small deep-pipeline graphs now pick compact, while shallow
     graphs (estimate ratio ≈ 2) stay dense at any width."""
-    if mode in ("dense", "compact", "pallas"):
-        return mode
-    if mode != "auto":
+    if mode not in PHASE_MODES:
         raise ValueError(
-            f"phase mode must be dense|compact|pallas|auto: {mode!r}")
+            f"phase mode must be {'|'.join(PHASE_MODES)}: {mode!r}")
+    if mode != "auto":
+        return mode
     w = max(int(seed_width), 1)
     if plan.n_tasks * w < 256:
         return "dense"
@@ -1094,13 +1100,12 @@ def lower_tensor_plan(plan: RoutingPlan,
     """Lower a `RoutingPlan` into the flat per-phase tensors consumed by
     the JAX segment-sum tick (`streams/jax_engine.py`).
 
-    ``mode`` is ``"dense"`` (arena-wide `PhaseTensors`, the parity
-    baseline), ``"compact"`` (pow2-bucketed `CompactPhase` index sets —
-    per-tick compute scales with the live edges per phase), ``"pallas"``
-    (the SAME `CompactPhase` tables, lowered through the fused per-phase
-    kernel `repro.kernels.tick_phase` by `jax_engine._build_pallas_run`)
-    or ``"auto"`` (`select_phase_mode` picks dense/compact by the work
-    estimate at the given ``seed_width``; pallas is explicit-only)."""
+    ``mode`` is ``"dense"`` (arena-wide `PhaseTensors`, the tests'
+    parity reference), ``"compact"`` (pow2-bucketed `CompactPhase`
+    index sets — per-tick compute scales with the live edges per phase;
+    the lowering the chip runs) or ``"auto"`` (`select_phase_mode`
+    picks one of the two by the work estimate at the given
+    ``seed_width``)."""
     import hashlib
 
     mode = select_phase_mode(plan, mode, seed_width)
@@ -1225,7 +1230,7 @@ def lower_tensor_plan(plan: RoutingPlan,
                       else np.zeros(0, np.int32)),
             G=n_groups_total, grp_of=cat["grp_of"],
             share=cat["share"], mass=cat["mass"])
-        if mode in ("compact", "pallas"):
+        if mode == "compact":
             phases.append(_compact_phase(ph, ops, cphase, f, mine,
                                          job_of_op))
         else:
@@ -1237,10 +1242,9 @@ def lower_tensor_plan(plan: RoutingPlan,
                  ph.is_backlog, ph.acc_static, ph.acc_block, ph.fwd_src,
                  ph.blk_of, ph.dst_in_blk.astype(np.int8), ph.bsrc_task,
                  ph.bsrc_blk, ph.grp_of)
-    if mode in ("compact", "pallas"):
+    if mode == "compact":
         # only the bucket signature keys the trace: the index contents
         # are traced parameters, so same-bucket plans share one trace
-        # (the mode tag keeps compact and pallas traces apart)
         key = (mode, n_tasks, n_ops, n_jobs, n_phases,
                tuple(p.sig for p in phases))
     else:

@@ -29,66 +29,45 @@ The jitted tick is O(1) in graph size. The pipeline has three stages:
    arena leaves the trace size unchanged.
 3. `_build_run` emits, per phase, a constant number of gathers +
    `segment_sum`/`segment_min`/`segment_max` passes over ALL of the
-   phase's edges at once (consume → route → accept → deposit), replacing
-   the old per-op/per-edge Python loop whose trace grew O(ops + edges).
-   The old unrolled tick survives as `build_unrolled_run` purely as the
-   benchmark baseline (benchmarks/bench_compile.py).
+   phase's edges at once (consume → route → accept → deposit), so the
+   trace does not grow with the op and edge count.
 
-Dense / compact / pallas lowering contract (``phase_mode``)
------------------------------------------------------------
-`lower_tensor_plan` has three flavors sharing the phase schedule; every
-engine/sweep entry point takes ``phase_mode`` ("dense" | "compact" |
-"pallas" | "auto", default auto via `engine.select_phase_mode`):
+Dense / compact lowering contract (``phase_mode``)
+--------------------------------------------------
+`lower_tensor_plan` has two flavors sharing the phase schedule; every
+engine/sweep entry point takes ``phase_mode`` (one of
+`engine.PHASE_MODES`: "dense" | "compact" | "auto", default auto via
+`engine.select_phase_mode`; any other value raises `ValueError` before
+anything is lowered or traced):
 
 * **dense** (`engine.PhaseTensors`, `_build_run`) — the parity
-  baseline. Per phase it multiplies arena-wide masks and runs
-  arena-sized segment reductions; the integer structure (index vectors,
-  partitioner masks, segment tables) is BAKED into the trace and
-  digested into `TensorPlan.key`, floats are traced. Work per tick is
-  O(n_phases × n_tasks) regardless of how few tasks a phase touches.
+  reference the tests hold compact to. Per phase it multiplies
+  arena-wide masks and runs arena-sized segment reductions; the
+  integer structure (index vectors, partitioner masks, segment tables)
+  is BAKED into the trace and digested into `TensorPlan.key`, floats
+  are traced. Work per tick is O(n_phases × n_tasks) regardless of how
+  few tasks a phase touches.
 * **compact** (`engine.CompactPhase`, `_build_compact_run`) — the
-  sparse-phase path. Every arena-sized segment reduction becomes a
-  row-table gather+reduce over just the phase's active tasks / source
-  ops / dst entries (rows pow2-padded with mask columns — the same
-  bucketing discipline as seed padding), and ALL index/mask tables ride
-  the params pytree as traced leaves: the trace key is only the bucket
-  shape signature, so same-bucket plans (e.g. same-shape graphs with
-  different partitioner kinds, placements or routing tables) share ONE
-  compiled trace. Consumption stays arena-wide elementwise
-  (bit-identical to dense); row reductions preserve each segment's
-  member order, so compact == dense at 1e-12 over full runs
-  (tests/test_sparse_phase.py). On deep pipelines (SS-style, 6 phases)
-  at 10k tasks the compact warm tick is 2–4x the dense one
-  (benchmarks/bench_sweep_scale.py → results/bench_sweep_scale.json).
-* **pallas** (the same `engine.CompactPhase` tables,
-  `_build_pallas_run` + `repro.kernels.tick_phase`) — the fused-kernel
-  path. The run is NATIVELY seed-batched: every state leaf carries a
-  leading ``(S,)`` scenario axis instead of an outer seed vmap, and
-  each routing phase executes as ONE fused ``pallas_call`` (task-state
-  gather → per-edge normalization → head-of-line row-min → per-group /
-  per-block row-sum → accept mask, sharing VMEM scratch across the
-  fused stages) with the seed axis as the Pallas grid dimension and
-  the pow2 row buckets as block shapes. Config/mix grid axes vmap over
-  the native run (one vmap level fewer than compact). Kernel dispatch
-  follows `repro.kernels.common.resolve_impl`: compiled Pallas on TPU,
-  the jnp reference lowering on CPU by default, and
-  ``REPRO_KERNEL_IMPL=interpret`` forces the actual kernel through the
-  Pallas interpreter (jit/scan/vmap-traceable — CI's pallas smoke runs
-  it). The trace cache keys on (bucket signature, resolved impl).
-  Parity with dense/compact holds at 1e-12 (tests/test_pallas_tick.py);
-  ``devices=`` sharding is not wired for this mode. On a TPU backend
-  the chip's compiler refuses the kernel, so `check_phase_mode` rejects
-  ``phase_mode="pallas"`` at every entry point before any trace.
+  sparse-phase path, and the lowering every chip cell runs. Every
+  arena-sized segment reduction becomes a row-table gather+reduce over
+  just the phase's active tasks / source ops / dst entries (rows
+  pow2-padded with mask columns — the same bucketing discipline as
+  seed padding), and ALL index/mask tables ride the params pytree as
+  traced leaves: the trace key is only the bucket shape signature, so
+  same-bucket plans (e.g. same-shape graphs with different partitioner
+  kinds, placements or routing tables) share ONE compiled trace.
+  Consumption stays arena-wide elementwise (bit-identical to dense);
+  row reductions preserve each segment's member order, so compact ==
+  dense at 1e-12 over full runs (tests/test_sparse_phase.py).
 
 "auto" picks compact exactly when the eliminated arena-wide reductions
 dominate the row-gather cost (deep packed arenas), scaled by the
 seed-axis width of the requesting sweep (`select_phase_mode`'s
 ``seed_width``: wide batches amortize the row-table overhead, so
 shallow-but-wide sweeps go compact too); small single-seed graphs stay
-dense, and pallas is never auto-selected. Setting
-``REPRO_REQUIRE_PHASE_MODE=compact`` (or ``dense`` / ``pallas``) turns
-a silent fallback into a hard error — scripts/ci.sh's smoke targets
-use it.
+dense. Setting ``REPRO_REQUIRE_PHASE_MODE=compact`` (or ``dense``)
+turns a silent fallback into a hard error — scripts/ci.sh's smoke
+targets use it.
 
 All resiliency floats are *traced leaves* of the params pytree, never
 compile-time constants: per-task failover vectors (detect / restart
@@ -220,12 +199,6 @@ trace). The contract:
   ``up_thresh``; rollback waves then restart only canary tasks
   (``up_rstag`` is +inf off-canary) and ``act`` reverts — no rng, no
   host round-trip, vmappable across (mixes × configs × seeds).
-* **Pallas caveat:** the fused kernel packs ``mode_single`` into its
-  static phase tables once per lowering, so a canary
-  ``d_mode_s``/``d_mode_r``/``d_mode_h`` delta cannot reach the
-  kernel's in-phase drop mask; keep canary mode deltas zero under
-  ``phase_mode="pallas"`` (selectivity/downtime/ckpt deltas and the
-  controller live outside the kernel and are fully supported).
 
 Rate-schedule + scale-event lowering contract (autoscaling)
 -----------------------------------------------------------
@@ -261,14 +234,11 @@ unscaled runs share one trace). The contract:
   the leaky direction-flip counter crosses ``as_tflip``, freezing the
   controller for the rest of the run (autoscaler-vs-failover
   oscillation surfaces as a finite ``thrash_t`` metric).
-* **Pallas caveat:** queue capacities (``qcap``) are packed into the
-  fused kernel's static phase tables once per lowering, so in-trace
-  rescales deliberately do NOT scale qcap in any mode (parity over
-  convenience); ``rfac``, the shed factor and the whole controller
-  live outside the kernel, so the pallas path needs no kernel-table
-  changes. Host-side rollback of failed resizes stays in
-  `core.autoscaler.DS2Scaler` — the traced twin models breaker +
-  shed instead.
+* **Queue capacities stay fixed.** In-trace rescales do NOT scale
+  ``qcap`` (parity with the numpy tick, which keeps each task's buffer;
+  see `engine.AutoscaleConfig`). Host-side rollback of failed resizes
+  stays in `core.autoscaler.DS2Scaler` — the traced twin models
+  breaker + shed instead.
 
 Compiled `run` functions are cached per *plan shape* (the `TensorPlan`
 digest + region count — never float parameters, which are traced), so
@@ -329,22 +299,17 @@ chunking contract:
 * **Shared keying.** The six process-global caches (`_FN_CACHE`,
   `_SHARD_CACHE`, `_CFG_SHARD_CACHE`, `_MIX_CACHE`, `_CFG_CACHE`,
   `_CFG_MIX_CACHE`) key on ``(TickDesc, variant)`` where `TickDesc` =
-  (`TensorPlan` digest — the bucket signature under compact/pallas —
-  and region count) and the variant adds shard count /
-  ``shared_kills`` / the resolved pallas kernel impl. Chunk size,
-  seed count, request identity and every float are absent from the
-  key, so concurrent requests over same-shaped plans hit ONE compiled
-  trace; only the pow2 seed-bucket of the *padded* chunk retraces.
+  (`TensorPlan` digest — the bucket signature under compact — and
+  region count) and the variant adds shard count / ``shared_kills``.
+  Chunk size, seed count, request identity and every float are absent
+  from the key, so concurrent requests over same-shaped plans hit ONE
+  compiled trace; only the pow2 seed-bucket of the *padded* chunk
+  retraces.
   All lookups funnel through `_cache_get` under one lock:
   `trace_cache_stats()` exposes process-wide hit/miss counters and
   `scoped_cache_stats` thread-local per-request ones (each plan
   records its own lookup in `cache_info`, surfaced per request by
   `launch.serve.SweepService`).
-* **Boundary errors.** ``devices=`` + ``phase_mode="pallas"`` is
-  rejected up front by `_check_pallas_devices` with the actionable
-  rewrite (devices=None + seed_chunk=, or compact mode) instead of a
-  deep `NotImplementedError`; `SweepService` performs that downgrade
-  automatically and records the reason.
 
 Everything runs in float64 to hold parity with the float64 numpy
 engine, under the thread-local ``jax.enable_x64(True)`` context and never
@@ -452,8 +417,8 @@ TICK_STEPS = ("tick_setup", "tick_consume", "tick_route", "tick_accept",
 
 
 def _build_compact_run(desc: TickDesc):
-    """Sparse-phase twin of `_build_run`: every arena-sized segment
-    reduction of the dense tick becomes a row-table gather+reduce over
+    """The chip's tick: `_build_run`'s dense tick with every arena-sized
+    segment reduction turned into a row-table gather+reduce over
     just the phase's active entries (`engine.CompactPhase`), and all
     index/mask tables are *traced* parameters (`pa["edges"][fi]`), so
     the trace key is only the pow2 bucket signature — same-bucket plans
@@ -754,228 +719,7 @@ def _finish_tick(pa, state, x, q, emitted, dropped, qps_acc,
                        "lag": lag}
 
 
-def _finish_tick_batched(pa, state, x, q, emitted, dropped, qps_acc,
-                         n_regions, n_ops, act, take_all):
-    """Seed-batched twin of `_finish_tick` for the native ``(S, ...)``
-    pallas run: same math, with the task axis transposed to leading for
-    the segment reductions (segment ops reduce over axis 0) and the
-    drill scalars (``rb_t`` / ``dacc``) carrying the ``(S,)`` axis."""
-    t = x["t"]
-    vict = x["kills"][:, pa["task_host"]]
-    ms_eff = pa["mode_single"] + act * pa["d_mode_s"]
-    mr_eff = pa["mode_region"] + act * pa["d_mode_r"]
-    mh_eff = pa["mode_hot"] + act * pa["d_mode_h"]
-    hit_s = (vict > 0.0).astype(q.dtype) * (ms_eff > 0.5)
-    reg_hit = jax.ops.segment_max((vict * (mr_eff > 0.5)).T,
-                                  pa["task_region"],
-                                  num_segments=n_regions)
-    hit_r = (reg_hit[pa["task_region"]].T > 0.0).astype(q.dtype)
-    hit_h = (vict > 0.0).astype(q.dtype) * (mh_eff > 0.5)
-    extra = ((pa["restore_base"] + act * pa["d_restore"])
-             * x["bfac"][:, pa["job_of_task"]]
-             + x["ckage"][:, pa["job_of_task"]]
-             * (1.0 + act * pa["d_ck"])
-             * (pa["replay_rate"] + act * pa["d_replay"])
-             + pa["lazy_extra"])
-    until_s = t + (pa["detect"] + pa["restart_single"]
-                   + act * pa["d_down_s"] + extra)
-    until_r = t + (pa["detect"] + pa["restart_region"]
-                   + act * pa["d_down_r"] + extra)
-    until_h = t + (pa["detect"] + pa["standby_switch"]
-                   + pa["standby_stale"] + act * pa["d_down_h"])
-    down_until = jnp.where(hit_r > 0.0, until_r,
-                           jnp.where(hit_s > 0.0, until_s,
-                                     jnp.where(hit_h > 0.0, until_h,
-                                               state.down_until)))
-    hit_any = jnp.maximum(jnp.maximum(hit_r, hit_s), hit_h)
-    q = jnp.where(hit_any > 0.0, 0.0, q)
-
-    ckpt_epoch = state.ckpt_epoch + x["ckpt"].astype(jnp.int32)
-
-    delta = q @ pa["up_wdelta"]                      # (S,)
-    g = (t >= pa["up_t0"]).astype(q.dtype)
-    dacc = state.dacc + g * pa["up_alpha"] * (delta - state.dacc)
-    fire = ((t >= pa["up_t0"]) & (dacc > pa["up_thresh"])
-            & jnp.isinf(state.rb_t))
-    rb_t = jnp.where(fire, t + pa["dt"], state.rb_t)
-    trig_up = ((t <= pa["up_start"])
-               & (pa["up_start"] < t + pa["dt"]))
-    up_until = jnp.maximum(
-        state.up_until,
-        jnp.where(trig_up, pa["up_start"] + pa["up_down"], 0.0))
-    rb_start = rb_t[:, None] + pa["up_rstag"]        # (S, T)
-    trig_rb = (t <= rb_start) & (rb_start < t + pa["dt"])
-    up_until = jnp.maximum(
-        up_until, jnp.where(trig_rb, rb_start + pa["up_down"], 0.0))
-
-    # in-trace DS2 autoscaler — `_finish_tick`'s controller with the
-    # scalars (`used` / `flip_acc` / `thrash_t` / `nact` / `rsec`)
-    # carrying the (S,) axis and task reductions over axis -1
-    dt_ = pa["dt"]
-    cap_now = pa["cap_base"] * state.speed
-    need = ((take_all + q * (dt_ / pa["as_drain"]))
-            / jnp.maximum(cap_now, 1e-9))
-    rew = state.rew + pa["as_alpha"] * (need - state.rew)
-    recent = (t - state.lact) <= pa["as_fw"]
-    failev = (hit_any > 0.0) & recent
-    crossed = (((t - state.lact) > pa["as_fw"])
-               & ((t - dt_ - state.lact) <= pa["as_fw"]))
-    failcnt = jnp.where(
-        failev, state.failcnt + 1.0,
-        jnp.where(crossed & (hit_any <= 0.0), 0.0, state.failcnt))
-    brk_fire = failcnt >= pa["as_bfail"]
-    brk_until = jnp.where(brk_fire, t + pa["as_brs"], state.brk_until)
-    failcnt = jnp.where(brk_fire, 0.0, failcnt)
-    boundary = (jnp.floor((t + dt_ - pa["as_t0"]) / pa["as_int"])
-                > jnp.floor((t - pa["as_t0"]) / pa["as_int"]))
-    want = jnp.clip(state.speed * rew / pa["as_tgt"],
-                    pa["as_lo"], pa["as_hi"])
-    rel = jnp.abs(want - state.speed) / jnp.maximum(state.speed, 1e-9)
-    as_fire = (boundary & (pa["as_on"] > 0.0) & (pa["as_mask"] > 0.0)
-               & (rel >= pa["as_hyst"])
-               & ((t - state.lact) >= pa["as_cool"])
-               & (t >= brk_until)
-               & (state.used[:, None] < pa["as_amax"])
-               & jnp.isinf(state.thrash_t)[:, None])
-    fire_f = as_fire.astype(q.dtype)
-    speed = jnp.where(as_fire, want, state.speed)
-    lact = jnp.where(as_fire, t, state.lact)
-    downt = pa["as_down"] + pa["as_move"] * jnp.abs(want - state.speed)
-    up_until = jnp.maximum(up_until,
-                           jnp.where(as_fire, t + downt, 0.0))
-    any_fire = (fire_f.sum(-1) > 0.0).astype(q.dtype)
-    used = state.used * pa["as_adec"] + any_fire
-    dirn = jnp.sign(want - state.speed)
-    flip = as_fire & (dirn * state.dirp < 0.0)
-    dirp = jnp.where(as_fire, dirn, state.dirp)
-    flip_acc = (state.flip_acc * pa["as_tdec"]
-                + flip.astype(q.dtype).sum(-1))
-    thrash_t = jnp.where((flip_acc >= pa["as_tflip"])
-                         & jnp.isinf(state.thrash_t),
-                         t + dt_, state.thrash_t)
-    nact = state.nact + fire_f.sum(-1)
-    rsec = state.rsec + speed.sum(-1) * dt_
-
-    backlog_row = jax.ops.segment_sum(q.T, pa["op_of_task"],
-                                      num_segments=n_ops).T
-    qps_row = qps_acc / pa["dt"]
-    lag = backlog_row @ pa["src_mask_ops"]
-    new_state = EngineState(q, down_until, speed, ckpt_epoch,
-                            emitted, dropped, up_until, rb_t, dacc,
-                            rew, lact, dirp, failcnt, brk_until, used,
-                            flip_acc, thrash_t, nact, rsec)
-    return new_state, {"qps": qps_row, "backlog": backlog_row,
-                       "lag": lag}
-
-
-def _build_pallas_run(desc: TickDesc, impl: str | None = None):
-    """Fused-kernel twin of `_build_compact_run`: the run is NATIVELY
-    seed-batched — every `EngineState` leaf carries a leading ``(S,)``
-    scenario axis, ``xs["kills"]`` arrives ``(S, T, H)``, and there is
-    no outer seed vmap — and each routing phase executes as ONE fused
-    `repro.kernels.tick_phase` launch (gather → normalize →
-    head-of-line row-min → group/block row-sum → accept, sharing VMEM
-    scratch across the stages) with the seed axis as the Pallas grid
-    dimension. Everything around the phase core (consumption, per-job
-    emit/drop segments, overflow requeue, deposits, `_finish_tick`) is
-    the compact tick's math batched over the leading axis, so
-    pallas == compact == dense at 1e-12.
-
-    ``impl`` resolves through `repro.kernels.common.resolve_impl`:
-    compiled Pallas on TPU, the jnp reference on CPU by default,
-    ``REPRO_KERNEL_IMPL=interpret`` forces the kernel through the
-    Pallas interpreter (CI's pallas smoke). The per-phase kernel tables
-    are packed ONCE per run, outside the `lax.scan` (dst-gathered
-    qcap/mode rows included), so the scan body carries no re-packing.
-    Returned ``ys`` rows are swapped back to the vmapped ``(S, T, ·)``
-    layout the batch entry points expect."""
-    from repro.kernels.tick_phase import pack_phase_tables, tick_phase
-
-    tp, n_regions = desc.tensor, desc.n_regions
-    n_ops, n_jobs = tp.n_ops, tp.n_jobs
-
-    def rsum(vals, idx, mask):
-        return (vals[:, idx] * mask).sum(-1)
-
-    def tick(pa, aux, state: EngineState, x):
-        t = x["t"]
-        q = state.queue
-        alive_f = ((state.down_until <= t)
-                   & (state.up_until <= t)).astype(q.dtype)
-        # drill activation / selectivity computed OUTSIDE the kernel —
-        # the fused phase core only sees alive_f/free/produced. The one
-        # pallas drill limitation: the kernel's drop mask reads the
-        # mode_single row PACKED once outside the scan, so a canary
-        # d_mode_s flip cannot reach it — keep canary failover modes
-        # equal to base modes (d_mode_s == 0) under the pallas path.
-        act = pa["up_cmask"] * ((t >= pa["up_start"] + pa["up_down"])
-                                & (t < state.rb_t[:, None]
-                                   + pa["up_rstag"])).astype(q.dtype)
-        free = jnp.maximum(pa["qcap"] - q, 0.0)
-        shed_t = jnp.where(t < state.brk_until, pa["as_shed"], 1.0)
-        sel_t = (pa["sel"][pa["op_of_task"]] + act * pa["d_sel"]) * shed_t
-        cap_t = pa["cap_base"] * state.speed * alive_f
-        emitted, dropped = state.emitted, state.dropped
-        produced = jnp.zeros_like(q)
-        qps_acc = jnp.zeros((q.shape[0], n_ops), q.dtype)
-        take_all = jnp.zeros_like(q)
-
-        gate_t = x["gate"][:, pa["job_of_task"]]  # MQ source gate (0/1)
-        rfac_t = x["rfac"][:, pa["job_of_task"]]  # traffic-rate factor
-        for fi, ph in enumerate(tp.phases):
-            eph = pa["edges"][fi]
-            if ph.consumes:
-                take = jnp.minimum(q, cap_t * eph["cons_mask"])
-                q = q - take
-                take_all = take_all + take
-                src_emit = (pa["src_row"] * alive_f * eph["cons_mask"]
-                            * gate_t * rfac_t)
-                produced = produced + (src_emit + take * sel_t)
-                if len(ph.e_jobs):
-                    emitted = emitted.at[:, eph["e_jobs"]].add(
-                        rsum(src_emit, eph["e_idx"], eph["e_mask"]))
-                qps_acc = qps_acc.at[:, eph["q_ops"]].add(
-                    rsum(take, eph["q_idx"], eph["q_mask"]))
-            if not ph.D:
-                continue
-            # the entire routing phase: ONE fused kernel launch
-            accepted, drop_d, ovf_e = tick_phase(
-                produced, alive_f, free, aux[fi],
-                has_blk=ph.B > 0, has_grp=ph.G > 0, impl=impl)
-            dropped = dropped.at[:, eph["dj_jobs"]].add(
-                rsum(drop_d, eph["dj_idx"], eph["dj_mask"]))
-            ovf_slot = jax.ops.segment_sum(
-                ovf_e.T, eph["slot_of_edge"],
-                num_segments=len(ph.slot_ops)).T
-            ovf_op = jnp.zeros((q.shape[0], n_ops),
-                               q.dtype).at[:, eph["slot_ops"]].add(
-                                   ovf_slot)
-            q = q + (ovf_op / pa["par_of_op"])[:, pa["op_of_task"]]
-            dst = eph["dst_task"]
-            q = q.at[:, dst].add(accepted)
-            free = jnp.maximum(free.at[:, dst].add(-accepted), 0.0)
-
-        return _finish_tick_batched(pa, state, x, q, emitted, dropped,
-                                    qps_acc, n_regions, n_ops, act,
-                                    take_all)
-
-    def run(pa, state, xs):
-        aux = [pack_phase_tables(pa["edges"][fi], pa["qcap"],
-                                 pa["mode_single"]) if ph.D else None
-               for fi, ph in enumerate(tp.phases)]
-        xs_t = dict(xs, **{k: jnp.swapaxes(xs[k], 0, 1)
-                           for k in ("kills", "bfac", "gate", "ckage",
-                                     "rfac")})
-        final, ys = lax.scan(lambda st, x: tick(pa, aux, st, x), state,
-                             xs_t)
-        return final, {k: jnp.swapaxes(v, 0, 1) for k, v in ys.items()}
-
-    return run
-
-
 def _build_run(desc: TickDesc):
-    if desc.tensor.mode == "pallas":
-        return _build_pallas_run(desc)
     if desc.tensor.mode == "compact":
         return _build_compact_run(desc)
     tp, n_regions = desc.tensor, desc.n_regions
@@ -1117,172 +861,6 @@ def _build_run(desc: TickDesc):
 
 
 # ----------------------------------------------------------------------
-# legacy unrolled tick (pre-tensorized; benchmark baseline ONLY)
-# ----------------------------------------------------------------------
-class _OpDesc(NamedTuple):
-    lo: int
-    hi: int
-    is_source: bool
-
-
-class _EdgeDesc(NamedTuple):
-    kind: str
-    static: bool
-    src_op: int
-    src_par: int
-    dst_lo: int
-    dst_hi: int
-    n_blocks: int
-    n_groups: int
-    any_unblocked: bool
-
-
-def _route(ed: _EdgeDesc, ea: dict, produced, free_down, alive_d):
-    kind = ed.kind
-    if kind == "forward":
-        return produced * alive_d
-    if kind in ("rescale", "group_rescale"):
-        prod_blk = jax.ops.segment_sum(produced, ea["blk_of_src"],
-                                       num_segments=ed.n_blocks)
-        alive_blk = jax.ops.segment_sum(alive_d * ea["dst_in_blk"],
-                                        ea["blk_idx"],
-                                        num_segments=ed.n_blocks)
-        has = alive_blk > 0.0
-        rate_blk = jnp.where(has, prod_blk / jnp.where(has, alive_blk, 1.0),
-                             0.0)
-        arriving = rate_blk[ea["blk_idx"]] * alive_d
-        if ed.any_unblocked:
-            arriving = jnp.where(ea["dst_in_blk"] > 0.0, arriving, 0.0)
-        return arriving
-    total = produced.sum()
-    if kind == "rebalance":
-        val = alive_d
-    elif kind == "hash":
-        return total * ea["share"]
-    elif kind == "weakhash":
-        cap = jnp.maximum(free_down, 1e-9) * alive_d
-        capsum = jax.ops.segment_sum(cap, ea["grp_of_dst"],
-                                     num_segments=ed.n_groups)
-        alive_eps = alive_d + 1e-9
-        capsum_fb = jax.ops.segment_sum(alive_eps, ea["grp_of_dst"],
-                                        num_segments=ed.n_groups)
-        fall = capsum <= 0.0
-        cap = jnp.where(fall[ea["grp_of_dst"]], alive_eps, cap) * alive_d
-        capsum = jnp.where(fall, capsum_fb, capsum)
-        val = cap * ea["mass_of_dst"] / capsum[ea["grp_of_dst"]]
-    elif kind == "backlog":
-        open_ = (free_down > ea["dst_qcap"] * 0.25).astype(alive_d.dtype)
-        val = jnp.maximum(free_down, 1e-9) * alive_d * jnp.maximum(open_,
-                                                                   0.05)
-    else:
-        raise ValueError(kind)
-    rs = val.sum()
-    return jnp.where(rs > 0.0, val * (total / rs), jnp.zeros_like(val))
-
-
-def _hol_ratio(arriving, room):
-    live = arriving > 1e-9
-    return jnp.where(live, room / jnp.maximum(arriving, 1e-300), jnp.inf)
-
-
-def _accept(ed: _EdgeDesc, ea: dict, arriving, room):
-    if ed.static:
-        lam = jnp.minimum(_hol_ratio(arriving, room).min(), 1.0)
-        return arriving * lam
-    if ed.kind == "group_rescale":
-        ratio = _hol_ratio(arriving, room)
-        lam_g = jnp.minimum(
-            jax.ops.segment_min(ratio, ea["blk_idx"],
-                                num_segments=ed.n_blocks), 1.0)
-        return arriving * lam_g[ea["blk_idx"]]
-    return jnp.minimum(arriving, room)
-
-
-def build_unrolled_run(legacy_desc):
-    """The pre-tensorized tick: one Python-level loop over ops and edges
-    per tick, `.at[sl]` scatter per op, one `_route`/`_accept` call per
-    edge — trace size O(ops + edges). Kept verbatim as the old-vs-new
-    baseline for benchmarks/bench_compile.py; the production path is
-    `_build_run`. Consumes `_Lowered.legacy()` descriptors."""
-    (op_descs, edge_descs, edges_of_op, src_cols, n_tasks, n_hosts,
-     n_regions, failover_mode, job_of_op, n_jobs) = legacy_desc
-    single_task = failover_mode == "single_task"
-
-    def tick(pa, state: EngineState, x):
-        t = x["t"]
-        q = state.queue
-        alive_f = (state.down_until <= t).astype(q.dtype)
-        free = jnp.maximum(pa["qcap"] - q, 0.0)
-        emitted, dropped = state.emitted, state.dropped
-        qps_cols = []
-        backlog_zero = jnp.zeros((), q.dtype)
-
-        for oi, od in enumerate(op_descs):
-            sl = slice(od.lo, od.hi)
-            if od.is_source:
-                produced = pa["src_row"][sl] * alive_f[sl]
-                emitted = emitted.at[job_of_op[oi]].add(produced.sum())
-                qps_cols.append(backlog_zero)
-            else:
-                cap = pa["cap_base"][sl] * state.speed[sl] * alive_f[sl]
-                take = jnp.minimum(q[sl], cap)
-                q = q.at[sl].add(-take)
-                produced = take * pa["sel"][oi]
-                qps_cols.append(take.sum() / pa["dt"])
-            for ei in edges_of_op[oi]:
-                ed, ea = edge_descs[ei], pa["edges"][ei]
-                dsl = slice(ed.dst_lo, ed.dst_hi)
-                arriving = _route(ed, ea, produced, free[dsl], alive_f[dsl])
-                if single_task:
-                    dead = alive_f[dsl] <= 0.0
-                    dropped = dropped.at[job_of_op[oi]].add(
-                        jnp.where(dead, arriving, 0.0).sum())
-                    arriving = jnp.where(dead, 0.0, arriving)
-                accepted = _accept(ed, ea, arriving, free[dsl])
-                overflow = (arriving - accepted).sum()
-                q = q.at[sl].add(overflow / max(ed.src_par, 1))
-                q = q.at[dsl].add(accepted)
-                free = free.at[dsl].set(
-                    jnp.maximum(free[dsl] - accepted, 0.0))
-
-        down_until = state.down_until
-        if failover_mode != "none":
-            vict = x["kills"][pa["task_host"]]
-            if failover_mode == "single_task":
-                hit = vict > 0.0
-                until = t + pa["detect"] + pa["restart_single"]
-            else:
-                reg_hit = jax.ops.segment_max(vict, pa["task_region"],
-                                              num_segments=n_regions)
-                hit = reg_hit[pa["task_region"]] > 0.0
-                until = t + pa["detect"] + pa["restart_region"]
-            down_until = jnp.where(hit, until, down_until)
-            q = jnp.where(hit, 0.0, q)
-
-        ckpt_epoch = state.ckpt_epoch + x["ckpt"].astype(jnp.int32)
-
-        backlog_row = jnp.stack([q[od.lo:od.hi].sum() for od in op_descs])
-        qps_row = jnp.stack(qps_cols)
-        lag = jnp.stack([backlog_row[j] for j in src_cols]).sum()
-        # legacy baseline predates deployment drills and the in-trace
-        # autoscaler: pass those leaves through untouched
-        new_state = EngineState(q, down_until, state.speed, ckpt_epoch,
-                                emitted, dropped, state.up_until,
-                                state.rb_t, state.dacc, state.rew,
-                                state.lact, state.dirp, state.failcnt,
-                                state.brk_until, state.used,
-                                state.flip_acc, state.thrash_t,
-                                state.nact, state.rsec)
-        return new_state, {"qps": qps_row, "backlog": backlog_row,
-                           "lag": lag}
-
-    def run(pa, state, xs):
-        return lax.scan(lambda st, x: tick(pa, st, x), state, xs)
-
-    return run
-
-
-# ----------------------------------------------------------------------
 # per-plan-shape trace caches
 # ----------------------------------------------------------------------
 _FN_CACHE: dict = {}
@@ -1385,49 +963,6 @@ _PA_CFG_AXES = {"qcap": 0, "src_row": None, "cap_base": None, "sel": 0,
                 **dict.fromkeys(AUTOSCALE_KEYS, 0)}
 
 
-PALLAS_TPU_REFUSAL = (
-    "phase_mode='pallas' is refused on a TPU: the chip's compiler "
-    "(Mosaic) rejects the fused tick kernel's gather at "
-    "kernels/tick_phase/kernel.py (alive[:, dst]: 'Shape mismatch in "
-    "input, indices and output'; ROADMAP.md, Speed 2). Use "
-    "phase_mode='compact' or 'auto'.")
-
-
-def check_phase_mode(phase_mode: str) -> None:
-    """Entry-point guard, run before any lowering or trace: on a TPU
-    backend the fused Pallas tick cannot compile, so ``"pallas"`` fails
-    here with `PALLAS_TPU_REFUSAL` instead of giving way to the jnp
-    reference or failing mid-request at its first trace."""
-    if phase_mode == "pallas" and jax.default_backend() == "tpu":
-        raise NotImplementedError(PALLAS_TPU_REFUSAL)
-
-
-def _tick_impl() -> str:
-    """Resolved fused-kernel impl for pallas-mode traces. It is part of
-    every pallas cache key: flipping ``REPRO_KERNEL_IMPL`` changes the
-    lowering (compiled kernel / interpreter / jnp reference), so a
-    cached trace must never outlive the impl it was built with."""
-    from repro.kernels.common import resolve_impl
-    return resolve_impl(None)
-
-
-def _lift_single(run_batched):
-    """Single-seed façade over a natively seed-batched run: expand every
-    state leaf (and the kill tensor) to a width-1 batch, strip the axis
-    from the results — same call contract as the dense/compact single
-    fns."""
-    def run1(pa, state, xs):
-        st = EngineState(*(jnp.asarray(l)[None]
-                           for l in state))
-        xs1 = dict(xs, **{k: jnp.asarray(xs[k])[None]
-                          for k in ("kills", "bfac", "gate", "ckage",
-                                    "rfac")})
-        final, ys = run_batched(pa, st, xs1)
-        return (EngineState(*(l[0] for l in final)),
-                {k: v[0] for k, v in ys.items()})
-    return run1
-
-
 def get_cached_run_fns(desc: TickDesc):
     """(jitted run, jitted vmapped run) for a static plan descriptor.
 
@@ -1435,20 +970,7 @@ def get_cached_run_fns(desc: TickDesc):
     float parameters (rates, selectivities, restart times, queue caps,
     failover mode masks, …) are traced arguments, so sweeping them never
     re-traces. The state argument is donated: arena state buffers are
-    consumed in place every call.
-
-    Pallas-mode descs key on (desc, resolved kernel impl) and return
-    (single-seed façade, the native seed-batched run) — the batch fn has
-    the exact layout of the vmapped dense/compact one."""
-    if desc.tensor.mode == "pallas":
-        impl = _tick_impl()
-
-        def _build_pl():
-            runb = _build_pallas_run(desc, impl)
-            return (jax.jit(_lift_single(runb)),
-                    jax.jit(runb, donate_argnums=(1,)))
-        return _cache_get(_FN_CACHE, (desc, impl), _build_pl)
-
+    consumed in place every call."""
     def _build():
         run = _build_run(desc)
         return (jax.jit(run, donate_argnums=(1,)),
@@ -1461,11 +983,6 @@ def get_sharded_run_fn(desc: TickDesc, n_shards: int):
     """Device-sharded batch run fn (flat seed axis, a multiple of
     `n_shards`) through `repro.dist.sharding.sharded_seed_fn`. Cached
     per (plan shape, shard count)."""
-    if desc.tensor.mode == "pallas":
-        raise NotImplementedError(
-            "devices= sharding is not wired for the pallas phase mode "
-            "(the native seed-batched run owns the seed axis); run "
-            "unsharded or use phase_mode='compact'")
     return _cache_get(
         _SHARD_CACHE, (desc, n_shards),
         lambda: sharded_seed_fn(_build_run(desc), xs_axes=_XS_AXES,
@@ -1476,14 +993,6 @@ def get_cached_mix_fn(desc: TickDesc):
     """Doubly-vmapped run fn: outer axis over job-mix configs (per-task
     source-rate rows), inner axis over chaos seeds — one trace sweeps an
     (M, S) grid of mix × scenario in a single device call."""
-    if desc.tensor.mode == "pallas":
-        # the native run already owns the seed axis: ONE vmap level
-        # (over mixes) instead of two
-        impl = _tick_impl()
-        return _cache_get(
-            _MIX_CACHE, (desc, impl),
-            lambda: jax.jit(jax.vmap(_build_pallas_run(desc, impl),
-                                     in_axes=(_PA_MIX_AXES, None, None))))
     return _cache_get(
         _MIX_CACHE, desc,
         lambda: jax.jit(
@@ -1512,20 +1021,6 @@ def get_cached_config_fn(desc: TickDesc, shared_kills: bool = False):
     config × scenario in one device call, one trace per grid shape.
     `shared_kills` selects the broadcast-kills variant (see
     `_cfg_xs_axes`)."""
-    if desc.tensor.mode == "pallas":
-        impl = _tick_impl()
-
-        def _build_pl():
-            # seed axis is native; the config vmap broadcasts the
-            # (S, ...) state and rides the same xs layout (the pallas
-            # run reads kills as (S, T, H), so the per-config kills
-            # axis is the same axis 0 the vmapped path uses)
-            return jax.jit(
-                jax.vmap(_build_pallas_run(desc, impl),
-                         in_axes=(_PA_CFG_AXES, None,
-                                  _cfg_xs_axes(shared_kills))))
-        return _cache_get(_CFG_CACHE, (desc, shared_kills, impl),
-                          _build_pl)
     return _cache_get(
         _CFG_CACHE, (desc, shared_kills),
         lambda: jax.jit(
@@ -1542,11 +1037,6 @@ def get_sharded_config_fn(desc: TickDesc, n_shards: int,
     devices through `repro.dist.sharding.sharded_grid_fn`, the config
     axis rides inside each shard. Cached per (plan shape, shard count,
     kills layout)."""
-    if desc.tensor.mode == "pallas":
-        raise NotImplementedError(
-            "devices= sharding is not wired for the pallas phase mode "
-            "(the native seed-batched run owns the seed axis); run "
-            "unsharded or use phase_mode='compact'")
     def _build():
         seed_axes = {"t": None, "kills": 0 if shared_kills else 1,
                      "ckpt": None, "bfac": 1, "gate": 0, "ckage": 1,
@@ -1565,18 +1055,6 @@ def get_cached_config_mix_fn(desc: TickDesc, shared_kills: bool = False):
     axes)."""
     mix_top = dict.fromkeys(_PA_CFG_AXES, None)
     mix_top["src_row"] = 0
-    if desc.tensor.mode == "pallas":
-        impl = _tick_impl()
-
-        def _build_pl():
-            runb = _build_pallas_run(desc, impl)
-            return jax.jit(
-                jax.vmap(
-                    jax.vmap(runb, in_axes=(_PA_CFG_AXES, None,
-                                            _cfg_xs_axes(shared_kills))),
-                    in_axes=(mix_top, None, None)))
-        return _cache_get(_CFG_MIX_CACHE, (desc, shared_kills, impl),
-                          _build_pl)
 
     def _build():
         run = _build_run(desc)
@@ -1600,7 +1078,6 @@ class _Lowered:
                  upgrade: UpgradeConfig | None = None,
                  upgrade_spec=None,
                  autoscale: AutoscaleConfig | None = None):
-        check_phase_mode(phase_mode)
         self.arena = graph if isinstance(graph, PackedArena) else None
         if self.arena is not None:
             graph = self.arena.graph
@@ -1732,10 +1209,9 @@ class _Lowered:
             "src_mask_ops": np.asarray(self.tensor.src_mask_ops, float),
             # per-phase traced routing parameters: share/mass tables in
             # dense mode, the full pow2-bucketed index/mask sets in
-            # compact/pallas mode (the trace key carries only the
-            # bucket sizes)
+            # compact mode (the trace key carries only the bucket sizes)
             "edges": [ph.traced()
-                      if self.tensor.mode in ("compact", "pallas")
+                      if self.tensor.mode == "compact"
                       else {"share": ph.share, "mass": ph.mass}
                       for ph in self.tensor.phases],
             **(drill if drill is not None else self._drill),
@@ -1902,56 +1378,6 @@ class _Lowered:
               "ckpt": tl.ckpt_at, "bfac": bfac, "gate": gate,
               "ckage": ckage, "rfac": rfac}
         return state, xs, tl
-
-    # ------------------------------------------------------------------
-    def legacy(self):
-        """(desc, arrays) of the pre-tensorized unrolled tick — only for
-        the old-vs-new compile benchmark (`build_unrolled_run`). Requires
-        a uniform (non-per-job) failover config."""
-        modes = np.unique(self.fo_codes)
-        if len(modes) != 1:
-            raise ValueError("legacy unrolled tick supports uniform "
-                             "failover configs only")
-        mode = {0: "none", 1: "region", 2: "single_task"}[int(modes[0])]
-        plan = self.plan
-        op_descs, edge_descs, edge_arrays, edges_of_op = [], [], [], []
-        for p in plan.ops:
-            op_descs.append(_OpDesc(p.lo, p.hi, p.is_source))
-        for oi, p in enumerate(plan.ops):
-            mine = []
-            for ep in p.out_edges:
-                mine.append(len(edge_descs))
-                n_groups = (len(ep.grp_starts)
-                            if ep.grp_starts is not None else 0)
-                edge_descs.append(_EdgeDesc(
-                    ep.kind, ep.static, oi, p.par, ep.dst.lo, ep.dst.hi,
-                    ep.n_blocks, n_groups, ep.any_unblocked))
-                ea: dict = {}
-                if ep.kind == "hash":
-                    ea["share"] = ep.share
-                elif ep.kind == "weakhash":
-                    ea["grp_of_dst"] = ep.grp_of_dst.astype(np.int32)
-                    ea["mass_of_dst"] = ep.mass_of_dst
-                elif ep.kind == "backlog":
-                    ea["dst_qcap"] = np.float64(ep.dst_qcap)
-                if ep.kind in ("rescale", "group_rescale"):
-                    ea["blk_of_src"] = ep.blk_of_src.astype(np.int32)
-                    ea["blk_idx"] = ep.blk_idx.astype(np.int32)
-                    ea["dst_in_blk"] = ep.dst_in_blk.astype(np.float64)
-                edge_arrays.append(ea)
-            edges_of_op.append(tuple(mine))
-        desc = (tuple(op_descs), tuple(edge_descs), tuple(edges_of_op),
-                tuple(int(j) for j in plan.src_cols), plan.n_tasks,
-                self.n_hosts, self.n_regions, mode,
-                tuple(int(j) for j in self.job_of_op), self.n_jobs)
-        arrays = dict(self.arrays)
-        arrays.pop("mode_single")
-        arrays.pop("mode_region")
-        arrays["detect"] = np.float64(self.fo_detect[0])
-        arrays["restart_region"] = np.float64(self.fo_rr[0])
-        arrays["restart_single"] = np.float64(self.fo_rs[0])
-        arrays["edges"] = edge_arrays
-        return desc, arrays
 
 
 # ----------------------------------------------------------------------
@@ -2269,21 +1695,6 @@ def _as_specs(seeds, base_spec) -> list:
             if isinstance(s, (int, np.integer)) else s for s in seeds]
 
 
-def _check_pallas_devices(low: "_Lowered", devices, entry: str) -> None:
-    """Boundary guard: pallas runs are natively seed-batched (the fused
-    kernel owns the seed axis as its grid dimension), so `devices=`
-    sharding has no lowering. Raise the actionable spelling here instead
-    of letting `get_sharded_*` NotImplementedError deep in the run."""
-    if devices is not None and low.tensor.mode == "pallas":
-        raise NotImplementedError(
-            f"{entry}: devices={devices!r} does not compose with "
-            "phase_mode='pallas' (the fused kernel natively owns the "
-            "seed axis; there is no sharded lowering). Rerun with "
-            "devices=None — pass seed_chunk= to bound per-pass device "
-            "memory instead — or use phase_mode='compact' for "
-            "device-sharded grids.")
-
-
 class ChunkResult:
     """One seed-chunk's worth of a chunked run: the half-open seed range
     ``[seed_lo, seed_hi)``, its metrics (`JaxBatchMetrics` for seed
@@ -2455,7 +1866,6 @@ class SeedBatchPlan:
             failover=failover, ckpt=ckpt, seed=seed,
             phase_mode=phase_mode, seed_width=len(specs),
             upgrade=upgrade, upgrade_spec=specs[0], autoscale=autoscale)
-        _check_pallas_devices(low, devices, "run_batch")
         self.n_ticks = int(round(duration_s / low.dt))
         self._override = task_speed_override
         self.pad_seeds = pad_seeds
@@ -2769,7 +2179,6 @@ class ConfigGridPlan:
             failover=norm[0]["failover"], ckpt=norm[0]["ckpt"],
             seed=seed, phase_mode=phase_mode,
             seed_width=len(specs) * len(norm))
-        _check_pallas_devices(low, devices, "run_config_batch")
         self.n_ticks = n_ticks = int(round(duration_s / low.dt))
         self.n_seeds, self.n_cfg = len(specs), len(norm)
         self._override = task_speed_override

@@ -1,7 +1,7 @@
 """Workloads from the paper's Table III: Nexmark Q2, Q12, Data
 Synchronization (DS), Sample Stitching (SS) — as logical graphs for the
 engine plus record-level vectorized operator kernels (jnp) used by the
-correctness tests and the micro benchmarks.
+correctness tests.
 """
 from __future__ import annotations
 
@@ -26,8 +26,7 @@ def ha_drill_spec(seed: int = 0, *, burst_t: float = 60.0,
     storage brownout ramp stretching checkpoint uploads and passive
     restores around it, and an MQ/coordinator outage window gating the
     sources — all deterministic (no extra rng draws), so the same seed
-    replays identically across the numpy, dense, compact and pallas
-    engines."""
+    replays identically across the numpy, dense and compact engines."""
     return ChaosSpec(seed=seed,
                      host_kill_prob_per_s=host_kill_prob_per_s,
                      burst_at=((burst_t, burst_region),),
@@ -50,7 +49,7 @@ def traffic_drill_spec(seed: int = 0, *,
     window, so rescale-during-recovery and autoscaler-vs-failover
     interactions actually exercise. All rate dynamics are deterministic
     curves (zero extra rng draws): the same seed replays identically
-    across the numpy, dense, compact and pallas engines."""
+    across the numpy, dense and compact engines."""
     burst = ((float(burst_t), burst_region),) if burst_t is not None \
         else ()
     return ChaosSpec(seed=seed,
@@ -208,8 +207,8 @@ def q12_arena(n_tasks: int = 10_000, parallelism: int = 8,
     At the default ``n_tasks=10_000`` that is 416 windowed-state jobs /
     1248 ops / 832 edges in one `RoutingPlan`: the workload whose
     per-op/per-edge unrolled jit trace was unbuildable, and which the
-    tensorized phase-scheduled tick compiles in constant trace size
-    (benchmarks/bench_compile.py). Returns a `PackedArena`; both engines
+    tensorized phase-scheduled tick compiles in constant trace size.
+    Returns a `PackedArena`; both engines
     and every sweep axis (seeds × mixes × configs) accept it directly.
     """
     from repro.streams.engine import pack_arena
@@ -233,8 +232,8 @@ def ss_arena(n_tasks: int = 10_000, parallelism: int = 8,
     two-in-edge join): its packed arena schedules SIX tick phases, each
     touching only 1–2 ops of every job — the workload class where the
     compact (sparse-phase) lowering's per-phase active index sets beat
-    the dense arena-wide tick (`engine.lower_tensor_plan(mode=...)`,
-    benchmarks/bench_sweep_scale.py). Returns a `PackedArena`.
+    the dense arena-wide tick (`engine.lower_tensor_plan(mode=...)`).
+    Returns a `PackedArena`.
     """
     from repro.streams.engine import pack_arena
 
@@ -274,20 +273,20 @@ def drill_fleet(n_jobs: int = 8, parallelism: int = 4,
 def mega_arena(n_tasks: int = 100_000, workload: str = "q12",
                parallelism: int = 8, n_hosts: int = 256, dt: float = 0.5,
                queue_cap: float = 256.0, host_map: str = "shared"):
-    """100k-task-scale mega-arena — the fused-Pallas-tick target size
-    (10× `q12_arena` / `ss_arena`). ``workload`` picks the job template:
+    """100k-task-scale mega-arena (10× `q12_arena` / `ss_arena`), the
+    size at which the whole arena may no longer fit one chip.
+    ``workload`` picks the job template:
 
     * ``"q12"``  — K = n_tasks // (3·parallelism) windowed-count jobs
       (≈ 4166 jobs / 12498 ops at the default 100k).
     * ``"ss"``   — K = n_tasks // (7·parallelism) deep stitching
-      pipelines (six tick phases, the compact/pallas showcase).
+      pipelines (six tick phases, the compact lowering's showcase).
     * ``"mixed"``— alternating q12 + ss jobs until the task budget is
       spent, exercising ragged pow2 row buckets across phases.
 
     All jobs share one host pool, so one `ChaosSpec` kill stream fans
-    out across every job — a (configs × seeds) grid over this arena in
-    ``phase_mode="pallas"`` covers ≥1e6 job-scenarios in a single
-    device pass (benchmarks/bench_tick_kernel.py). Returns a
+    out across every job — a (configs × seeds) grid over this arena
+    covers ≥1e6 job-scenarios in a single device pass. Returns a
     `PackedArena`.
     """
     from repro.streams.engine import pack_arena

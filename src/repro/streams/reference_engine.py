@@ -2,8 +2,7 @@
 
 This is the seed implementation of `streams.engine.StreamEngine`, kept
 verbatim as the semantic oracle: `tests/test_engine_vectorized.py` pins the
-vectorized engine's metrics against it, and `benchmarks/bench_engine.py`
-measures the speedup ratio against it. Do not optimize this file — its whole
+vectorized engine's metrics against it. Do not optimize this file — its whole
 point is to stay the slow, obviously-correct baseline.
 """
 from __future__ import annotations

@@ -16,16 +16,24 @@ Pillars:
   arenas), dense for small/shallow graphs, and the
   ``REPRO_REQUIRE_PHASE_MODE`` guard refuses silent fallbacks.
 """
+import dataclasses
+import math
+
 import jax
 import numpy as np
 import pytest
 
 from repro.core.chaos import ChaosSpec
+from repro.core.replication import TimingModel
+from repro.core.startup import StartupConfig
 from repro.streams import nexmark
-from repro.streams.engine import (FailoverConfig, build_plan, pack_arena,
+from repro.streams.chaos_sweep import deployment_drill, replication_tradeoff
+from repro.streams.engine import (FailoverConfig, UpgradeConfig, build_plan,
+                                  pack_arena,
                                   select_phase_mode)
 from repro.streams.jax_engine import (JaxStreamEngine, _FN_CACHE,
-                                      _Lowered, get_cached_run_fns)
+                                      _Lowered, get_cached_run_fns,
+                                      run_batch)
 
 TOL = dict(rtol=1e-12, atol=1e-9)
 
@@ -199,3 +207,113 @@ def test_compact_packed_arena_job_metrics():
                                **TOL)
     np.testing.assert_allclose(md.dropped_by_job, mc.dropped_by_job,
                                **TOL)
+
+
+def test_phase_mode_seed_width_selection():
+    """The seed-width argument widens the compact region (wide sweeps
+    amortize row-table overhead)."""
+    plan = build_plan(nexmark.ss(parallelism=8), 0.5, 256.0)
+    assert select_phase_mode(plan, seed_width=1) == "dense"
+    assert select_phase_mode(plan, seed_width=64) == "compact"
+    # tiny graphs stay dense at any width via the absolute floor
+    tiny = build_plan(nexmark.q2(parallelism=2), 0.5, 256.0)
+    assert select_phase_mode(tiny, seed_width=1) == "dense"
+
+
+def test_run_batch_compact_matches_dense():
+    """run_batch's vmapped seed batch over a deep packed arena: compact
+    equals dense seed for seed at 1e-12, per-job segments included."""
+    arena = nexmark.ss_arena(n_tasks=168, parallelism=4, n_hosts=8)
+    spec = ChaosSpec(host_kill_prob_per_s=0.02, straggler_frac=0.2)
+    bd = run_batch(arena, range(5), duration_s=60, base_spec=spec,
+                   phase_mode="dense")
+    bc = run_batch(arena, range(5), duration_s=60, base_spec=spec,
+                   phase_mode="compact")
+    np.testing.assert_allclose(bd.source_lag, bc.source_lag, **TOL)
+    np.testing.assert_allclose(bd.qps, bc.qps, **TOL)
+    np.testing.assert_allclose(bd.backlog, bc.backlog, **TOL)
+    np.testing.assert_allclose(bd.emitted_by_job, bc.emitted_by_job,
+                               **TOL)
+    np.testing.assert_allclose(bd.dropped_by_job, bc.dropped_by_job,
+                               **TOL)
+
+
+def _replication_cube(phase_mode):
+    """The replication cell's request shape at test size: three failover
+    modes × two checkpoint intervals × two brownouts over a 10-job Q12
+    arena."""
+    timing = TimingModel()
+    failovers = {
+        "hot_standby": FailoverConfig.from_replication(
+            timing, mode="hot_standby"),
+        "passive": FailoverConfig.from_replication(
+            timing, mode="single_task", state_bytes=8 << 30),
+        "passive_lazy": dataclasses.replace(
+            FailoverConfig.from_replication(timing, mode="region",
+                                            state_bytes=8 << 30),
+            region_restart_s=5.0, lazyload_stagger_s=1.0)}
+    return replication_tradeoff(
+        nexmark.q12_arena(n_tasks=240, n_hosts=16), range(6),
+        base_spec=nexmark.ha_drill_spec(
+            burst_t=15.0, brownout=(10.0, 30.0, 6.0),
+            mq_outage=(35.0, 40.0), host_kill_prob_per_s=0.002),
+        duration_s=45.0, failovers=failovers, ckpt_intervals=(10.0, 30.0),
+        brownouts=((), ((10.0, 30.0, 4.0),)), seed_chunk=4,
+        phase_mode=phase_mode).grid
+
+
+def _drill_cube(phase_mode):
+    """The drill cell's request shape at test size: three upgrade
+    policies × two canary fractions × rollback off/on over a 6-job
+    Q3/Q11 fleet."""
+    up = dict(t_upgrade_s=8.0, wave_stagger_s=1.0, canary_sel_scale=1.5,
+              rollback_window_s=4.0)
+    return deployment_drill(
+        nexmark.drill_fleet(n_jobs=6, parallelism=2, n_hosts=8), range(6),
+        base_spec=ChaosSpec(host_kill_prob_per_s=0.004,
+                            zk_down=((20.0, 24.0),),
+                            hdfs_down=((22.0, 28.0),)),
+        duration_s=40.0,
+        policies={"hot": UpgradeConfig(hot=True, **up),
+                  "cold": UpgradeConfig(hot=False, **up),
+                  "cold+accel": UpgradeConfig(hot=False,
+                                              startup=StartupConfig(),
+                                              **up)},
+        canary_fracs=(0.25, 0.5), rollback_thresholds=(math.inf, 100.0),
+        failover=FailoverConfig(mode="single_task", detect_s=1.0,
+                                single_restart_s=2.0),
+        seed_chunk=4, phase_mode=phase_mode).grid
+
+
+_DISCRETE = ("seed", "n_failures", "recovery_time_s", "slo_violation_ticks",
+             "slo_violation_frac", "ckpt_attempts", "ckpt_success")
+_FLOWS = ("max_backlog", "max_lag", "slo_threshold", "dropped", "emitted")
+
+
+@pytest.mark.parametrize("fleet", ["q12_replication", "q3q11_drill"])
+def test_served_grid_compact_matches_dense(fleet):
+    """The served summary path (`sweep_configs` over a `SummaryGridPlan`,
+    chunked) gives the same summaries and surfaces under both lowerings:
+    discrete fields equal, flows at 1e-12."""
+    cube = {"q12_replication": _replication_cube,
+            "q3q11_drill": _drill_cube}[fleet]
+    gd, gc = cube("dense"), cube("compact")
+    assert (gd.phase_mode, gc.phase_mode) == ("dense", "compact")
+    # the cube sees failures that recover within the horizon
+    rec = gc.recovery_surface
+    assert (np.isfinite(rec) & (rec > 0)).any()
+    assert gd.labels == gc.labels
+    for rd, rc in zip(gd.results, gc.results):
+        for sd, sc in zip(rd.summaries, rc.summaries):
+            for f in _DISCRETE:
+                assert getattr(sd, f) == getattr(sc, f), f
+            for f in _FLOWS:
+                assert getattr(sc, f) == pytest.approx(getattr(sd, f),
+                                                       rel=1e-12), f
+    for name in ("recovery_surface", "slo_surface", "rollback_surface",
+                 "thrash_surface", "rescale_surface"):
+        np.testing.assert_array_equal(getattr(gd, name), getattr(gc, name),
+                                      err_msg=name)
+    for name in ("backlog_surface", "lost_surface", "cost_surface"):
+        np.testing.assert_allclose(getattr(gc, name), getattr(gd, name),
+                                   rtol=1e-12, atol=0, err_msg=name)

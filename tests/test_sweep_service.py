@@ -1,6 +1,6 @@
 """Sweep-as-a-service: chunked-execution bit-parity, the shared jit
 cache across concurrent requests, incremental chunk publishing, the
-prep/device timing split, and the pallas+devices boundary/downgrade."""
+prep/device timing split."""
 import math
 import threading
 
@@ -10,11 +10,13 @@ import pytest
 from repro.core.chaos import ChaosSpec, timeline_build_count
 from repro.launch.serve import SweepRequest, SweepService
 from repro.streams import nexmark
-from repro.streams.chaos_sweep import SweepChunk, deployment_drill, sweep
+from repro.streams.chaos_sweep import (SweepChunk, deployment_drill, sweep,
+                                       sweep_configs)
 from repro.streams.engine import (CheckpointConfig, FailoverConfig,
                                   UpgradeConfig)
-from repro.streams.jax_engine import (_Lowered, get_cached_config_fn,
-                                      run_batch, run_config_batch,
+from repro.streams.jax_engine import (JaxStreamEngine, _Lowered,
+                                      get_cached_config_fn, run_batch,
+                                      run_config_batch, run_mix_batch,
                                       trace_cache_stats)
 
 SEEDS = list(range(13))                 # deliberately non-pow2
@@ -209,36 +211,6 @@ def test_service_error_propagation():
         SweepRequest("nope", None, [])
 
 
-# ----------------------------------------------------------------------
-# pallas + devices: actionable boundary error, service auto-downgrade
-# ----------------------------------------------------------------------
-def test_pallas_devices_boundary_error():
-    g = nexmark.q2(parallelism=2)
-    with pytest.raises(NotImplementedError) as ei:
-        run_config_batch(g, [FO], range(2), base_spec=SPEC,
-                         duration_s=10.0, n_hosts=4,
-                         phase_mode="pallas", devices=2)
-    msg = str(ei.value)
-    assert "devices=None" in msg and "seed_chunk" in msg
-    assert "compact" in msg
-    with pytest.raises(NotImplementedError, match="seed_chunk"):
-        run_batch(g, range(2), base_spec=SPEC, duration_s=10.0,
-                  n_hosts=4, phase_mode="pallas", devices=2)
-
-
-def test_service_downgrades_pallas_devices():
-    with SweepService(workers=1) as svc:
-        job = svc.submit("sweep", nexmark.q2(parallelism=2), range(3),
-                         base_spec=SPEC, duration_s=10.0, failover=FO,
-                         n_hosts=4, phase_mode="pallas", devices=2)
-        res = job.result(600.0)
-    assert len(res.summaries) == 3
-    reason = job.stats["downgrade"]
-    assert reason is not None
-    assert "devices=2" in reason and "seed_chunk" in reason
-    assert job.stats["state"] == "done"
-
-
 def test_trace_cache_stats_shape():
     s = trace_cache_stats()
     assert set(s) == {"hits", "misses"}
@@ -332,3 +304,34 @@ def test_persistent_cache_dir(monkeypatch, tmp_path, env_dir):
         jax.config.update("jax_compilation_cache_dir", prev[0])
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           prev[1])
+
+
+# ----------------------------------------------------------------------
+# a phase mode outside dense|compact|auto fails before any trace
+# ----------------------------------------------------------------------
+_Q2 = nexmark.q2(parallelism=2)
+_KW = dict(base_spec=SPEC, duration_s=10.0, n_hosts=4, phase_mode="pallas")
+_ENTRIES = {
+    "JaxStreamEngine": lambda svc: JaxStreamEngine(
+        _Q2, chaos=SPEC, n_hosts=4, phase_mode="pallas"),
+    "run_batch": lambda svc: run_batch(_Q2, range(2), **_KW),
+    "run_mix_batch": lambda svc: run_mix_batch(_Q2, [[1.0]], range(2),
+                                               **_KW),
+    "run_config_batch": lambda svc: run_config_batch(_Q2, [FO], range(2),
+                                                     **_KW),
+    "sweep": lambda svc: sweep(_Q2, range(2), **_KW),
+    "sweep_configs": lambda svc: sweep_configs(_Q2, [FO], range(2), **_KW),
+    "submit_sweep": lambda svc: svc.submit("sweep", _Q2, range(2), **_KW),
+    "submit_sweep_configs": lambda svc: svc.submit(
+        "sweep_configs", _Q2, range(2), configs=[FO], **_KW),
+}
+
+
+@pytest.mark.parametrize("entry", list(_ENTRIES))
+def test_removed_phase_mode_refused_before_trace(entry):
+    with SweepService(workers=1) as svc:
+        before = trace_cache_stats()
+        with pytest.raises(ValueError, match="dense|compact|auto"):
+            _ENTRIES[entry](svc)
+        assert trace_cache_stats() == before
+        assert svc.jobs() == []

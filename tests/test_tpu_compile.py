@@ -2,9 +2,8 @@
 
 The config-grid run function of the sweep service's main path is
 compiled for one described v5e chip in the dense and the compact tick
-lowering, and the fused Pallas tick's refusal is pinned: the chip's
-compiler still rejects the kernel, and on a TPU backend
-``phase_mode="pallas"`` fails at the entry point before any trace.
+lowering, and a TPU service refuses the removed ``phase_mode="pallas"``
+at `submit`, before any job or trace.
 
 The topology is described only inside a fixture: one process at a time
 may load the TPU library, so describing it at import would break the
@@ -21,9 +20,8 @@ from repro.core.chaos import ChaosSpec
 from repro.launch.serve import SweepService
 from repro.streams import nexmark
 from repro.streams.engine import CheckpointConfig, FailoverConfig
-from repro.streams.jax_engine import (PALLAS_TPU_REFUSAL, ConfigGridPlan,
-                                      device_backlog_series,
-                                      run_config_batch, trace_cache_stats)
+from repro.streams.jax_engine import (ConfigGridPlan, device_backlog_series,
+                                      trace_cache_stats)
 
 SPEC = nexmark.ha_drill_spec(burst_t=10.0, brownout=(5.0, 20.0, 4.0),
                              mq_outage=(22.0, 25.0),
@@ -112,28 +110,15 @@ def test_roofline_peaks_keyed_by_device_kind(topo):
         kernel_roofline(1.0, 1.0, "TPU v4")
 
 
-def test_pallas_tick_refused_on_tpu(one_chip, monkeypatch):
+def test_pallas_tick_refused_on_tpu(monkeypatch):
+    """The fused Pallas tick is gone: on a TPU backend the service
+    rejects ``phase_mode="pallas"`` as bad input at `submit`, before a
+    job is queued or a trace is looked up."""
     arena = nexmark.q12_arena(n_tasks=240, n_hosts=16)
-    # the chip's compiler still refuses the fused kernel (its gather);
-    # once it compiles, the entry-point refusal below should go
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
-    plan = ConfigGridPlan(arena, CONFIGS, range(4), duration_s=30.0,
-                          base_spec=SPEC, phase_mode="pallas")
-    with pytest.raises(Exception, match="Shape mismatch"):
-        _compile(plan, one_chip)
-    monkeypatch.delenv("REPRO_KERNEL_IMPL")
-
-    # so on a TPU backend the pallas mode fails at the entry point,
-    # before any lowering or trace, and never falls back to ``ref``
     with SweepService(workers=1) as svc:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         before = trace_cache_stats()
-        with pytest.raises(NotImplementedError) as err:
-            run_config_batch(arena, CONFIGS, range(4), duration_s=30.0,
-                             base_spec=SPEC, phase_mode="pallas")
-        assert str(err.value) == PALLAS_TPU_REFUSAL
-        assert "Speed 2" in PALLAS_TPU_REFUSAL
-        with pytest.raises(NotImplementedError, match="Speed 2"):
+        with pytest.raises(ValueError, match="dense|compact|auto"):
             svc.submit("sweep_configs", arena, range(4),
                        configs=CONFIGS, duration_s=30.0,
                        base_spec=ChaosSpec(), phase_mode="pallas")

@@ -6,7 +6,7 @@ Pins the traffic contract across all engine lowerings:
 * a schedule that evaluates to a constant 1.0 rate factor (zero-amplitude
   diurnal, unit-peak flash) is a bit-exact no-op — rate curves multiply
   emission and must never perturb the draw streams;
-* numpy == jax (1e-5) and dense == compact == pallas (1e-12) under the
+* numpy == jax (1e-5) and dense == compact (1e-12) under the
   full `traffic_drill_spec` drill: diurnal + flash crowd + a host burst
   INSIDE the flash hold window + the in-trace DS2 controller rescaling
   while failover recovery is still replaying;
@@ -126,28 +126,27 @@ def test_numpy_matches_jax_rescale_during_recovery():
                                    np.asarray(m_np.backlog[n]), atol=1e-5)
 
 
-def test_dense_compact_pallas_agree_under_drill():
+def test_dense_compact_agree_under_drill():
     g = nexmark.q3()
     spec = _drill()
     runs = {}
-    for mode in ("dense", "compact", "pallas"):
+    for mode in ("dense", "compact"):
         runs[mode] = JaxStreamEngine(g, chaos=spec, failover=FO,
                                      autoscale=DS2,
                                      phase_mode=mode).run(150.0)
     ref = runs["compact"]
     assert ref.n_rescale > 0
-    for mode in ("dense", "pallas"):
-        m = runs[mode]
-        assert m.n_rescale == ref.n_rescale
-        assert m.thrash_t == ref.thrash_t
-        assert m.resource_s == pytest.approx(ref.resource_s, abs=1e-9)
-        np.testing.assert_allclose(np.asarray(m.source_lag),
-                                   np.asarray(ref.source_lag),
+    m = runs["dense"]
+    assert m.n_rescale == ref.n_rescale
+    assert m.thrash_t == ref.thrash_t
+    assert m.resource_s == pytest.approx(ref.resource_s, abs=1e-9)
+    np.testing.assert_allclose(np.asarray(m.source_lag),
+                               np.asarray(ref.source_lag),
+                               rtol=0, atol=1e-12)
+    for n in ref.backlog:
+        np.testing.assert_allclose(np.asarray(m.backlog[n]),
+                                   np.asarray(ref.backlog[n]),
                                    rtol=0, atol=1e-12)
-        for n in ref.backlog:
-            np.testing.assert_allclose(np.asarray(m.backlog[n]),
-                                       np.asarray(ref.backlog[n]),
-                                       rtol=0, atol=1e-12)
 
 
 def test_autoscaler_tracks_flash_crowd():
